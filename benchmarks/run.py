@@ -103,6 +103,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.repeat < 1:
         ap.error("--repeat must be >= 1")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     selected = _selector(args.only)
 
     from repro.obs.metrics import get_registry

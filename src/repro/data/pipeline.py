@@ -391,7 +391,7 @@ class SignatureStream:
     hashing) or a ``repro.core.oph.OPH`` scheme (single-pass
     one-permutation hashing), executed through the
     ``repro.kernels.SignatureEngine`` (``backend`` selects interpret /
-    compiled TPU / gpu-fallback execution).  With ``packed=True`` chunks
+    compiled TPU / jnp reference execution).  With ``packed=True`` chunks
     are ``PackedSignatures`` -- the k*b-bit wire format, packed inside
     the kernel jit, so only packed words cross the host boundary.
     ``stats`` aggregates load/kernel accounting like ``preprocess_shards``

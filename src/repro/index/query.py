@@ -233,10 +233,10 @@ def _topk_merge(best_s, best_i, sc, ids):
 
 @functools.partial(jax.jit, static_argnames=(
     "n", "block", "k", "b", "code_bits", "sentinel", "backend",
-    "blk_q", "blk_n", "blk_k", "D"))
+    "blk_q", "blk_n", "D"))
 def _exact_scan(qwords, corpus, best_s, best_i, id_start, q_sizes, doc_sizes,
                 *, n, block, k, b, code_bits, sentinel, backend,
-                blk_q, blk_n, blk_k, D):
+                blk_q, blk_n, D):
     """ONE traced computation: fori_loop over ``corpus``'s blocks with the
     running top-k carried inside the jit.
 
@@ -255,7 +255,7 @@ def _exact_scan(qwords, corpus, best_s, best_i, id_start, q_sizes, doc_sizes,
         ids = id_start + t * block + jnp.arange(block, dtype=jnp.int32)
         out = _packed_match_run(qwords, cblk, k=k, code_bits=code_bits,
                                 sentinel=sentinel, backend=backend,
-                                blk_q=blk_q, blk_n=blk_n, blk_k=blk_k)
+                                blk_q=blk_q, blk_n=blk_n)
         matches, both_empty = out if sentinel else (out, None)
         if doc_sizes is not None:
             dsz = jnp.take(doc_sizes,
@@ -271,7 +271,7 @@ def _exact_scan(qwords, corpus, best_s, best_i, id_start, q_sizes, doc_sizes,
 
 
 def exact_scan_ids(qwords, corpus, ids, q_sizes, doc_sizes, *, block, k, b,
-                   code_bits, sentinel, backend, blk_q, blk_n, blk_k, D,
+                   code_bits, sentinel, backend, blk_q, blk_n, D,
                    topk):
     """Blocked exact scan over a corpus slice carrying *explicit* global
     doc ids (-1 marks a padding row) -- the per-device body of the mesh
@@ -297,7 +297,7 @@ def exact_scan_ids(qwords, corpus, ids, q_sizes, doc_sizes, *, block, k, b,
         idblk = jax.lax.dynamic_slice_in_dim(ids, t * block, block, axis=0)
         out = _packed_match_run(qwords, cblk, k=k, code_bits=code_bits,
                                 sentinel=sentinel, backend=backend,
-                                blk_q=blk_q, blk_n=blk_n, blk_k=blk_k)
+                                blk_q=blk_q, blk_n=blk_n)
         matches, both_empty = out if sentinel else (out, None)
         if doc_sizes is not None:
             dsz = jax.lax.dynamic_slice_in_dim(doc_sizes, t * block, block,
@@ -313,7 +313,7 @@ def exact_scan_ids(qwords, corpus, ids, q_sizes, doc_sizes, *, block, k, b,
 
 
 def lsh_rerank_ids(qwords, corpus, ids, cand, member, q_sizes, doc_sizes, *,
-                   k, b, code_bits, sentinel, backend, blk_q, blk_n, blk_k,
+                   k, b, code_bits, sentinel, backend, blk_q, blk_n,
                    D, topk):
     """Candidate gather + kernel rerank over a corpus slice carrying
     explicit global doc ids -- the per-device body of the mesh LSH
@@ -334,7 +334,7 @@ def lsh_rerank_ids(qwords, corpus, ids, cand, member, q_sizes, doc_sizes, *,
     cwords = jnp.take(corpus, cand, axis=0)
     out = _packed_match_run(qwords, cwords, k=k, code_bits=code_bits,
                             sentinel=sentinel, backend=backend,
-                            blk_q=blk_q, blk_n=blk_n, blk_k=blk_k)
+                            blk_q=blk_q, blk_n=blk_n)
     matches, both_empty = out if sentinel else (out, None)
     if doc_sizes is not None:
         dsz = jnp.take(doc_sizes, cand)
@@ -522,7 +522,7 @@ class IndexSearcher(_BatchedAdmission):
         return dict(n=meta.n, block=self.corpus_block, k=meta.k, b=meta.b,
                     code_bits=meta.code_bits, sentinel=meta.sentinel,
                     backend=self._be, blk_q=self._kb["blk_q"],
-                    blk_n=self._kb["blk_n"], blk_k=self._kb["blk_k"])
+                    blk_n=self._kb["blk_n"])
 
     def _exact_fused(self, qwords, topk: int, q_sizes):
         """One traced computation: the whole blocked scan + top-k merge.

@@ -66,7 +66,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.data.sigshard import read_sig_meta
 from repro.index.banding import band_keys_packed
 from repro.index.builder import (MANIFEST_NAME, SigIndex, append_index,
@@ -571,8 +570,7 @@ class ShardedIndex(_BatchedAdmission):
                 "statics": dict(k=meta0.k, b=meta0.b,
                                 code_bits=meta0.code_bits,
                                 sentinel=meta0.sentinel, backend=s0._be,
-                                blk_q=s0._kb["blk_q"], blk_n=s0._kb["blk_n"],
-                                blk_k=s0._kb["blk_k"]),
+                                blk_q=s0._kb["blk_q"], blk_n=s0._kb["blk_n"]),
             }
             state.cache["mesh_exact"] = layout
             return layout
@@ -604,9 +602,9 @@ class ShardedIndex(_BatchedAdmission):
                 return bs[None], bi[None]
             in_specs = (P(None, None), P("data", None), P("data"))
 
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                                out_specs=(P("data"), P("data")),
-                               check_rep=False))
+                               check_vma=False))
         self._mesh_fns[key] = fn
         return fn
 
@@ -683,9 +681,9 @@ class ShardedIndex(_BatchedAdmission):
             in_specs = (P(None, None), P("data", None), P("data"),
                         P("data", None), P("data", None, None))
 
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                                out_specs=(P("data"), P("data")),
-                               check_rep=False))
+                               check_vma=False))
         self._mesh_fns[key] = fn
         return fn
 

@@ -9,13 +9,13 @@
                  k bins (k x less hash work than minhash.py); fused
                  (b+1)-bit sentinel coding for the packed wire format.
   sigbag.py   -- Eq.(5) signature embedding-bag as one-hot MXU matmuls.
-  hamming.py  -- packed-signature match counting for retrieval: b-bit
-                 codes extracted in-register from the wire words,
+  hamming.py  -- packed-signature match counting for retrieval: XOR of
+                 the wire words, zero code fields counted in-register,
                  sentinel-EMPTY aware (the repro.index scoring hot path).
   pack.py     -- the packed b-bit wire format (PackSpec, device pack /
                  unpack epilogues, in-kernel pack_block).
   engine.py   -- SignaturePlan / SignatureEngine: backend registry
-                 (interpret / tpu / gpu / ref), JSON block-size tuning
+                 (interpret / tpu / ref), JSON block-size tuning
                  table, padding/tiling, scheme dispatch, PackedSignatures.
   ops.py      -- legacy re-exports of the public wrappers.
   ref.py      -- pure-jnp oracles for allclose validation.

@@ -7,12 +7,11 @@ This module is the ONE seam between hashing schemes and hardware:
     format.  Everything static; the arrays live in the hash family.
   * ``Backend`` / ``BACKENDS`` -- the execution registry.  ``interpret``
     runs the Pallas kernels in interpret mode (CPU / CI), ``tpu`` runs
-    them compiled, ``gpu`` is the pallas-triton entry that falls back to
-    the jnp reference until the triton lowering lands, ``ref`` forces the
-    pure-jnp oracles.  ``auto`` resolves per ``jax.default_backend()``.
+    them compiled by Mosaic, ``ref`` forces the pure-jnp oracles.
+    ``auto`` resolves per ``jax.default_backend()``.
     This replaces the scattered ``interpret=not _on_tpu()`` flags.
   * ``TuningTable``    -- JSON-persisted block-size table keyed on
-    (backend, scheme, k, nnz-bucket), the hook for the ROADMAP TPU/GPU tuning
+    (backend, scheme, k, nnz-bucket), the hook for the ROADMAP TPU tuning
     items; ships with seed defaults in ``tuning_table.json``.
   * ``SignatureEngine`` -- owns padding/tiling and scheme dispatch
     (a registry keyed on (scheme, family) -- no isinstance chains), and
@@ -80,9 +79,6 @@ register_backend(Backend("interpret", True, True,
                          "Pallas interpret mode (CPU hosts, CI)"))
 register_backend(Backend("tpu", True, False,
                          "compiled Pallas TPU (Mosaic)"))
-register_backend(Backend("gpu", False, False,
-                         "pallas-triton lowering pending (ROADMAP); "
-                         "falls back to the jnp reference"))
 register_backend(Backend("ref", False, False,
                          "pure-jnp oracles (kernels/ref.py)"))
 
@@ -91,7 +87,7 @@ def resolve_backend(name: Optional[str] = None) -> Backend:
     """Map a backend name (or None/"auto") to a registered Backend."""
     if name is None or name == "auto":
         plat = jax.default_backend()
-        name = plat if plat in ("tpu", "gpu") else "interpret"
+        name = "tpu" if plat == "tpu" else "interpret"
     try:
         return BACKENDS[name]
     except KeyError:
@@ -103,11 +99,12 @@ def resolve_backend(name: Optional[str] = None) -> Backend:
 # Block-size tuning table
 # ---------------------------------------------------------------------------
 
-MINHASH_BLOCKS = {"blk_n": 8, "blk_t": 128, "blk_k": 128}
-OPH_BLOCKS = {"blk_n": 8, "blk_t": 128, "blk_k": 0}     # blk_k 0 = all-lane
-# retrieval scoring (kernels/hamming.py): query x corpus output tile +
-# codes per reduction step; table entries keyed on the packed word count
-HAMMING_BLOCKS = {"blk_q": 8, "blk_n": 128, "blk_k": 128}
+# blk_n examples ride the 128-lane axis, so it is a multiple of 128 on TPU
+MINHASH_BLOCKS = {"blk_n": 128, "blk_t": 128, "blk_k": 128}
+OPH_BLOCKS = {"blk_n": 128, "blk_t": 128, "blk_k": 0}   # blk_k 0 = all bins
+# retrieval scoring (kernels/hamming.py): query x corpus output tile (the
+# word axis is always whole rows); table entries keyed on the word count
+HAMMING_BLOCKS = {"blk_q": 8, "blk_n": 128}
 
 
 def nnz_bucket(nnz: int) -> int:
@@ -249,9 +246,9 @@ class SignaturePlan:
     densify: Optional[str] = None   # OPH only
     variant: str = "high"           # 2U only
     backend: str = "interpret"      # resolved Backend name
-    blk_n: int = 8
+    blk_n: int = 128
     blk_t: int = 128
-    blk_k: int = 128                # OPH: 0 = all bins in one lane block
+    blk_k: int = 128                # OPH: 0 = all bins in one block
     packed: bool = False
 
     @property
@@ -319,9 +316,9 @@ def _minhash2u_run(indices, counts, a1, a2, *, s, b, variant, backend,
     a1p = _pad_axis(a1, blk_k, 0)
     a2p = _pad_axis(a2, blk_k, 0, value=1)
     if packed and can_pack_in_kernel(a1p.shape[0], k, b, blk_k):
-        _, words = minhash2u_pallas(idx, cts, a1p, a2p, s=s, b=b, blk_n=blk_n,
-                                    blk_t=blk_t, blk_k=blk_k, variant=variant,
-                                    pack=True, interpret=be.interpret)
+        words = minhash2u_pallas(idx, cts, a1p, a2p, s=s, b=b, blk_n=blk_n,
+                                 blk_t=blk_t, blk_k=blk_k, variant=variant,
+                                 pack=True, interpret=be.interpret)
         return words[:n]
     out = minhash2u_pallas(idx, cts, a1p, a2p, s=s, b=b, blk_n=blk_n,
                            blk_t=blk_t, blk_k=blk_k, variant=variant,
@@ -344,9 +341,9 @@ def _minhash4u_run(indices, counts, a, *, s, b, backend, blk_n, blk_t, blk_k,
     cts = _pad_axis(counts, blk_n, 0)
     ap = _pad_axis(a, blk_k, 1, value=1)
     if packed and can_pack_in_kernel(ap.shape[1], k, b, blk_k):
-        _, words = minhash4u_pallas(idx, cts, ap, s=s, b=b, blk_n=blk_n,
-                                    blk_t=blk_t, blk_k=blk_k, pack=True,
-                                    interpret=be.interpret)
+        words = minhash4u_pallas(idx, cts, ap, s=s, b=b, blk_n=blk_n,
+                                 blk_t=blk_t, blk_k=blk_k, pack=True,
+                                 interpret=be.interpret)
         return words[:n]
     out = minhash4u_pallas(idx, cts, ap, s=s, b=b, blk_n=blk_n, blk_t=blk_t,
                            blk_k=blk_k, interpret=be.interpret)[:n, :k]
@@ -429,7 +426,8 @@ def _legacy_backend(use_pallas: bool, backend: Optional[str]) -> str:
 def minhash2u(indices: jax.Array, counts: jax.Array, a1: jax.Array,
               a2: jax.Array, *, s: int, b: int = 0, variant: str = "high",
               use_pallas: bool = True, backend: Optional[str] = None,
-              blk_n: int = 8, blk_t: int = 128, blk_k: int = 128) -> jax.Array:
+              blk_n: int = 128, blk_t: int = 128,
+              blk_k: int = 128) -> jax.Array:
     """Batched 2U minhash signatures. counts: (n,) or (n,1) int32."""
     return _minhash2u_run(indices, counts, a1, a2, s=s, b=b, variant=variant,
                           backend=_legacy_backend(use_pallas, backend),
@@ -438,8 +436,8 @@ def minhash2u(indices: jax.Array, counts: jax.Array, a1: jax.Array,
 
 def minhash4u(indices: jax.Array, counts: jax.Array, a: jax.Array, *, s: int,
               b: int = 0, use_pallas: bool = True,
-              backend: Optional[str] = None, blk_n: int = 8, blk_t: int = 128,
-              blk_k: int = 128) -> jax.Array:
+              backend: Optional[str] = None, blk_n: int = 128,
+              blk_t: int = 128, blk_k: int = 128) -> jax.Array:
     """Batched 4U minhash signatures (Mersenne BitMod path)."""
     return _minhash4u_run(indices, counts, a, s=s, b=b,
                           backend=_legacy_backend(use_pallas, backend),
@@ -449,7 +447,7 @@ def minhash4u(indices: jax.Array, counts: jax.Array, a: jax.Array, *, s: int,
 def oph2u(indices: jax.Array, counts: jax.Array, a1: jax.Array,
           a2: jax.Array, *, s: int, k: int, densify: str = "rotation",
           b: int = 0, variant: str = "high", use_pallas: bool = True,
-          backend: Optional[str] = None, blk_n: int = 8, blk_t: int = 128,
+          backend: Optional[str] = None, blk_n: int = 128, blk_t: int = 128,
           blk_k: int = 0) -> jax.Array:
     """Batched 2U OPH signatures: ONE hash pass -> (n, k) bin minima.
 
@@ -472,7 +470,7 @@ def oph2u(indices: jax.Array, counts: jax.Array, a1: jax.Array,
 def oph4u(indices: jax.Array, counts: jax.Array, a: jax.Array, *, s: int,
           k: int, densify: str = "rotation", b: int = 0,
           use_pallas: bool = True, backend: Optional[str] = None,
-          blk_n: int = 8, blk_t: int = 128, blk_k: int = 0) -> jax.Array:
+          blk_n: int = 128, blk_t: int = 128, blk_k: int = 0) -> jax.Array:
     """Batched 4U OPH signatures (Mersenne BitMod path); see ``oph2u``."""
     n, _ = indices.shape
     counts = counts.reshape(-1, 1).astype(jnp.int32)
@@ -678,7 +676,7 @@ def tune(engine, batch, candidates, iters: int = 3,
          table: Optional[TuningTable] = None,
          backend: Optional[str] = None) -> dict:
     """Time candidate block dicts and record the winner in the tuning
-    table (the ROADMAP TPU/GPU tuning loop).
+    table (the ROADMAP TPU tuning loop).
 
     Two schemes:
       * ``engine`` is a ``SignatureEngine`` and ``batch`` a

@@ -10,15 +10,20 @@ is the entire scoring hot path of ``repro.index``.
 Both operands arrive in the packed wire format (``kernels/pack.py``:
 k codes of ``code_bits`` each, little-endian bitstream in uint32 words
 -- (b+1)-bit codes with EMPTY = 2^b for sentinel OPH).  The kernel never
-round-trips through an unpacked (n, k) matrix in HBM: each grid step
-DMA's a word tile, extracts its codes in-register, and accumulates match
-counts into the revisited (BLK_Q, BLK_N) output block.
+unpacks: it XORs each query's packed row against a (BLK_N, W) corpus
+tile -- documents on sublanes, the whole packed row of W words on lanes
+-- and a code matches iff its bit-field of the XOR is zero.  The fields
+are read with per-word shifts from a small (S, W) table of code offsets
+(``_code_slots``), so there is no lane gather; a code that straddles two
+words (code widths that do not divide 32, e.g. 9-bit sentinel codes)
+takes its high bits from the next word via a one-lane rotate.  The
+per-word zero counts are summed across lanes on the MXU (a ones-vector
+matmul), which lands each query's counts as one (1, BLK_N) row of the
+(BLK_Q, BLK_N) output block.
 
-Grid = (Q/BLK_Q, N/BLK_N, k_pad/BLK_K) with the last axis accumulating
-(the same "parallel, parallel, arbitrary" reduction pattern as the
-signature kernels).  BLK_K must be a multiple of 32 so every code block
-starts on a word boundary and its words form a clean BlockSpec tile of
-BLK_K*code_bits/32 lanes.
+Grid = (N/BLK_N, Q/BLK_Q), both parallel; the corpus tile stays in VMEM
+while every query block is scored against it, so each corpus word is read
+from HBM once per call.
 
 For sentinel OPH the kernel also counts jointly-EMPTY positions, so the
 caller can apply the Li-Owen-Zhang normalization
@@ -26,7 +31,7 @@ N_match / (k - N_jointly_empty) without ever unpacking.
 
 Backend selection / block sizes come from the ``SignatureEngine``
 registry (``repro.kernels.engine``): the public wrapper ``packed_match``
-resolves a Backend (interpret / tpu run this kernel; gpu / ref run the
+resolves a Backend (interpret / tpu run this kernel; ref runs the
 ``kernels/ref.py`` oracle) and looks up ``TuningTable`` entries under
 scheme ``"hamming"`` keyed on the packed word count.
 """
@@ -38,6 +43,7 @@ from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.bbit import packed_words
@@ -47,112 +53,118 @@ from repro.kernels.pack import PackSpec
 _U32 = jnp.uint32
 
 
-def _extract_codes(words, code_bits: int, blk_k: int):
-    """(rows, BW) word tile -> (rows, BLK_K) uint32 codes, in-register.
+def _code_slots(k: int, code_bits: int) -> np.ndarray:
+    """(S, W) int32: bit offset in word w of the s-th code starting there.
 
-    The tile starts on a word boundary (BLK_K % 32 == 0 guarantees every
-    code block does), so local code i occupies bits
-    [i*code_bits, (i+1)*code_bits) of the tile's bitstream.  Same
-    two-shift word-straddle arithmetic as ``repro.core.bbit.unpack_codes``
-    (no undefined shift-by-32), traced here inside the kernel.
+    S = ceil(32 / code_bits) is the most codes that can start in one
+    word; -1 marks an empty slot (including everything past code k).
+    Same bitstream geometry as ``repro.core.bbit.pack_codes``.
     """
-    bw = words.shape[-1]
-    i = jnp.arange(blk_k, dtype=jnp.uint32)
-    bit0 = i * _U32(code_bits)
-    wlo = (bit0 >> 5).astype(jnp.int32)
-    sh = bit0 & _U32(31)
-    lo = jnp.take(words, wlo, axis=1) >> sh
-    hi = (jnp.take(words, jnp.minimum(wlo + 1, bw - 1), axis=1)
-          << (_U32(31) - sh)) << _U32(1)
-    out = lo | hi
-    if code_bits < 32:
-        out = out & _U32((1 << code_bits) - 1)
-    return out
+    n_words = packed_words(k, code_bits)
+    slots = np.full((-(-32 // code_bits), n_words), -1, np.int32)
+    bit0 = np.arange(k, dtype=np.int64) * code_bits
+    word, off = bit0 >> 5, bit0 & 31
+    first = np.searchsorted(word, np.arange(n_words))
+    slots[np.arange(k) - first[word], word] = off
+    return slots
 
 
-def _hamming_kernel(q_ref, c_ref, match_ref, *empty_refs, k: int,
-                    code_bits: int, blk_k: int, sentinel: bool):
-    t_step = pl.program_id(2)
-    n_t = pl.num_programs(2)
+def _hamming_kernel(slots_ref, q_ref, c_ref, match_ref, *empty_refs,
+                    code_bits: int, sentinel: bool, blk_q: int):
+    straddle = 32 % code_bits != 0
+    mask = _U32((1 << code_bits) - 1)
+    slots = slots_ref[...]                                 # (S, W)
+    n_words = slots.shape[1]
+    ones = jnp.ones((8, n_words), jnp.bfloat16)
 
-    @pl.when(t_step == 0)
-    def _init():
-        match_ref[...] = jnp.zeros_like(match_ref)
+    def fields(x):
+        """Per slot: (code field of every word of ``x``, slot is real)."""
+        nxt = jnp.roll(x, -1, axis=1) if straddle else None
+        for sl in range(slots.shape[0]):
+            off = slots[sl:sl + 1, :]
+            sh = jnp.maximum(off, 0).astype(_U32)
+            f = x >> sh
+            if straddle:        # high bits from the next word; no shift by 32
+                f = f | ((nxt << (_U32(31) - sh)) << _U32(1))
+            yield f & mask, off >= 0
+
+    def row_sum(z):
+        """(BLK_N, W) small counts -> (1, BLK_N) int32 sums over words."""
+        r = jax.lax.dot_general(ones, z.astype(jnp.bfloat16),
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        return r[0:1].astype(jnp.int32)
+
+    def one_query(i, carry):
+        q = q_ref[pl.ds(i, 1), :]                          # (1, W)
+        x = c_ref[...] ^ q                                 # (BLK_N, W)
+        q_empty = ([f == _U32(1 << (code_bits - 1)) for f, _ in fields(q)]
+                   if sentinel else None)
+        match = both = 0
+        for sl, (f, real) in enumerate(fields(x)):
+            z = (f == 0) & real
+            if sentinel:
+                both = both + (z & q_empty[sl]).astype(jnp.int32)
+                z = z & ~q_empty[sl]
+            match = match + z.astype(jnp.int32)
+        match_ref[pl.ds(i, 1), :] = row_sum(match)
         if sentinel:
-            empty_refs[0][...] = jnp.zeros_like(empty_refs[0])
+            empty_refs[0][pl.ds(i, 1), :] = row_sum(both)
+        return carry
 
-    qc = _extract_codes(q_ref[...], code_bits, blk_k)      # (BLK_Q, BLK_K)
-    cc = _extract_codes(c_ref[...], code_bits, blk_k)      # (BLK_N, BLK_K)
-    # global code index: padding codes past k never count
-    valid = (jax.lax.broadcasted_iota(jnp.int32, (1, 1, blk_k), 2)
-             + t_step * blk_k) < k
-    eq = (qc[:, None, :] == cc[None, :, :]) & valid
-    if sentinel:
-        ec = _U32(1 << (code_bits - 1))                    # EMPTY = 2^b
-        both = ((qc == ec)[:, None, :] & (cc == ec)[None, :, :]) & valid
-        eq = eq & ~both
-        empty_refs[0][...] = (empty_refs[0][...]
-                              + jnp.sum(both.astype(jnp.int32), axis=2))
-    match_ref[...] = match_ref[...] + jnp.sum(eq.astype(jnp.int32), axis=2)
+    jax.lax.fori_loop(0, blk_q, one_query, 0)
 
 
 def packed_match_pallas(qwords: jax.Array, cwords: jax.Array, *, k: int,
                         code_bits: int, sentinel: bool = False,
-                        blk_q: int = 8, blk_n: int = 128, blk_k: int = 128,
-                        interpret: bool = True):
+                        blk_q: int = 8, blk_n: int = 128, interpret: bool):
     """Match counts between packed query and corpus signatures.
 
     Args:
       qwords: (Q, W) uint32 packed query signatures.
       cwords: (N, W) uint32 packed corpus signatures (same wire format).
       k, code_bits, sentinel: the wire format (``PackSpec``).
-      blk_q, blk_n: output tile; blk_k: codes per reduction step
-        (must be a multiple of 32 so word tiles align).
+      blk_q, blk_n: output tile (the word axis is always whole rows).
+      interpret: run the Pallas interpreter (CPU) instead of Mosaic.
 
-    Q, N and W must tile (pad in the caller: zero words decode to code 0
-    but the in-kernel ``valid`` mask keeps codes past k out of every
-    count; padded *rows* produce garbage counts the caller slices off).
+    Q and N must tile (pad in the caller: padded *rows* produce garbage
+    counts the caller slices off; bits past code k never count).
 
     Returns (Q, N) int32 match counts; for ``sentinel=True`` a tuple
     ``(matches, both_empty)`` where matches already excludes jointly-EMPTY
     positions (the Li-Owen-Zhang numerator) and both_empty counts them
     (the denominator correction).
     """
-    if blk_k % 32:
-        raise ValueError(f"blk_k must be a multiple of 32 so code blocks "
-                         f"align to word boundaries, got {blk_k}")
     q, w = qwords.shape
     n, wc = cwords.shape
-    if wc != w:
-        raise ValueError(f"query words {w} != corpus words {wc}")
-    bw = blk_k * code_bits // 32
-    if q % blk_q or n % blk_n or w % bw:
-        raise ValueError(f"shapes must tile: Q={q}%{blk_q}, N={n}%{blk_n}, "
-                         f"W={w}%{bw} (= blk_k*code_bits/32)")
-    grid = (q // blk_q, n // blk_n, w // bw)
-    q_spec = pl.BlockSpec((blk_q, bw), lambda i, j, t: (i, t))
-    c_spec = pl.BlockSpec((blk_n, bw), lambda i, j, t: (j, t))
-    out_spec = pl.BlockSpec((blk_q, blk_n), lambda i, j, t: (i, j))
+    if wc != w or w != packed_words(k, code_bits):
+        raise ValueError(f"query words {w} / corpus words {wc} != "
+                         f"{packed_words(k, code_bits)} for k={k}, "
+                         f"code_bits={code_bits}")
+    if q % blk_q or n % blk_n:
+        raise ValueError(f"shapes must tile: Q={q}%{blk_q}, N={n}%{blk_n}")
+    slots = jnp.asarray(_code_slots(k, code_bits))
+    out_spec = pl.BlockSpec((blk_q, blk_n), lambda j, i: (i, j))
     out_shape = jax.ShapeDtypeStruct((q, n), jnp.int32)
-    kern = functools.partial(_hamming_kernel, k=k, code_bits=code_bits,
-                             blk_k=blk_k, sentinel=sentinel)
-    out = pl.pallas_call(
+    kern = functools.partial(_hamming_kernel, code_bits=code_bits,
+                             sentinel=sentinel, blk_q=blk_q)
+    return pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[q_spec, c_spec],
+        grid=(n // blk_n, q // blk_q),
+        in_specs=[pl.BlockSpec(slots.shape, lambda j, i: (0, 0)),
+                  pl.BlockSpec((blk_q, w), lambda j, i: (i, 0)),
+                  pl.BlockSpec((blk_n, w), lambda j, i: (j, 0))],
         out_specs=[out_spec, out_spec] if sentinel else out_spec,
         out_shape=[out_shape, out_shape] if sentinel else out_shape,
         interpret=interpret,
-        **_compiler_params(interpret),
-    )(qwords, cwords)
-    return out
+        **_compiler_params("parallel", "parallel"),
+    )(slots, qwords, cwords)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "code_bits", "sentinel",
-                                             "backend", "blk_q", "blk_n",
-                                             "blk_k"))
+                                             "backend", "blk_q", "blk_n"))
 def _packed_match_run(qwords, cwords, *, k, code_bits, sentinel, backend,
-                      blk_q, blk_n, blk_k):
+                      blk_q, blk_n):
     from repro.kernels import ref as kref
     from repro.kernels.engine import BACKENDS, _pad_axis
     q, n = qwords.shape[0], cwords.shape[0]
@@ -160,12 +172,11 @@ def _packed_match_run(qwords, cwords, *, k, code_bits, sentinel, backend,
     if not be.use_pallas:
         return kref.packed_match_ref(qwords, cwords, k=k,
                                      code_bits=code_bits, sentinel=sentinel)
-    bw = blk_k * code_bits // 32
-    qp = _pad_axis(_pad_axis(qwords, blk_q, 0), bw, 1)
-    cp = _pad_axis(_pad_axis(cwords, blk_n, 0), bw, 1)
+    qp = _pad_axis(qwords, blk_q, 0)
+    cp = _pad_axis(cwords, blk_n, 0)
     out = packed_match_pallas(qp, cp, k=k, code_bits=code_bits,
                               sentinel=sentinel, blk_q=blk_q, blk_n=blk_n,
-                              blk_k=blk_k, interpret=be.interpret)
+                              interpret=be.interpret)
     if sentinel:
         return out[0][:q, :n], out[1][:q, :n]
     return out[:q, :n]
@@ -178,7 +189,7 @@ def packed_match(qwords: jax.Array, cwords: jax.Array, spec: PackSpec, *,
 
     ``spec`` is the shared wire format; ``backend`` resolves through the
     ``SignatureEngine`` registry ("auto" per hardware; interpret/tpu run
-    the Pallas kernel, gpu/ref the jnp oracle).  Block sizes come from
+    the Pallas kernel, ref the jnp oracle).  Block sizes come from
     explicit ``blocks`` > ``TuningTable`` entry (scheme ``"hamming"``,
     keyed on the packed word count) > ``HAMMING_BLOCKS`` defaults.
 
@@ -201,4 +212,4 @@ def packed_match(qwords: jax.Array, cwords: jax.Array, spec: PackSpec, *,
     return _packed_match_run(qwords, cwords, k=spec.k,
                              code_bits=spec.code_bits, sentinel=spec.sentinel,
                              backend=be.name, blk_q=blocks["blk_q"],
-                             blk_n=blocks["blk_n"], blk_k=blocks["blk_k"])
+                             blk_n=blocks["blk_n"])

@@ -6,14 +6,16 @@ Mapping of the paper's GPU design onto TPU v5e:
 
   paper (CUDA, Tesla C2050)            this kernel (Pallas, TPU)
   -----------------------------------  -----------------------------------
-  chunk of 10K sets copied to GPU mem  (BLK_N, BLK_T) index tiles DMA'd
+  chunk of 10K sets copied to GPU mem  (BLK_T, BLK_N) index tiles DMA'd
                                        HBM -> VMEM via BlockSpec
-  SIMD threads over (element, hash j)  VPU lanes over a (BLK_N, BLK_T,
-                                       BLK_K) tile; k is the 128-lane axis
-  per-set running minima in registers  running-min accumulator in the
-                                       revisited output block (grid's
-                                       innermost "arbitrary" dim iterates
-                                       nnz chunks)
+  SIMD threads over (element, hash j)  VPU lanes over BLK_N examples,
+                                       sublanes over BLK_T nonzeros; one
+                                       hash function j at a time, its
+                                       coefficients scalars in SMEM
+  per-set running minima in registers  running-min accumulator in a VMEM
+                                       scratch tile, one row per hash
+                                       function (grid's innermost
+                                       "arbitrary" dim iterates nnz chunks)
   avoid % via 2^32 overflow (Eq. 10)   identical uint32 wraparound +
                                        multiply-shift
   avoid % via BitMod, p = 2^31-1       identical shift/mask/cond-subtract,
@@ -21,215 +23,201 @@ Mapping of the paper's GPU design onto TPU v5e:
                                        by 16-bit-limb long multiplication
                                        (TPU has no 64-bit integer unit)
 
-Grid = (n/BLK_N, k/BLK_K, nnz/BLK_T); the last axis accumulates, so the
-output (n, k) block is revisited -- the standard Pallas reduction pattern
-("parallel", "parallel", "arbitrary").
+Layout.  The wrapper hands the kernel the indices transposed, (nnz, n):
+examples on the 128-lane axis, nonzeros on sublanes.  For hash j the
+(BLK_T, BLK_N) tile hashes in full vregs and its minimum over sublanes is
+one (1, BLK_N) row -- no lane-to-sublane relayout.  Outputs come out as
+(k, n) and the wrapper transposes them back.
 
-Padding is communicated via per-row nonzero counts: lane t of row i is
-valid iff ``t < counts[i]``; invalid lanes hash to 0xFFFFFFFF so they never
-win the min.  If ``b > 0`` the lowest-b-bit extraction (the *b-bit* step)
-is fused into the final grid iteration; with ``pack=True`` that same final
-step additionally bit-packs the (BLK_N, BLK_K) b-bit tile into
-(BLK_N, BLK_K*b/32) uint32 words (``repro.kernels.pack.pack_block``), so
-signatures leave the kernel in the paper's k*b-bit wire format.
+Grid = (n/BLK_N, k/BLK_K, nnz/BLK_T); the last axis accumulates
+("parallel", "parallel", "arbitrary").  Mosaic has no unsigned min, so the
+running min is kept in int32 after the order-preserving bias
+``x ^ 0x80000000``.
+
+Padding is communicated via per-example nonzero counts: sublane t of
+example i is valid iff ``t < counts[i]``; invalid sublanes hash to
+0xFFFFFFFF so they never win the min.  If ``b > 0`` the lowest-b-bit
+extraction (the *b-bit* step) is fused into the final grid iteration; with
+``pack=True`` that step instead emits the bit-packed words
+(``repro.kernels.pack.pack_block``), so signatures leave the kernel in the
+paper's k*b-bit wire format.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.hashing import add64, mod_mersenne31, umul32_wide
-from repro.kernels.pack import pack_block
+from repro.core.hashing import add64, hash2u_apply, mod_mersenne31, umul32_wide
+from repro.kernels.pack import pack_block, pack_row
 
 _U32 = jnp.uint32
-# numpy scalar (not a traced jax array) so kernels don't capture constants
-_PAD = np.uint32(0xFFFFFFFF)
+# numpy scalars (not traced jax arrays) so kernels don't capture constants
+_BIAS = np.uint32(0x80000000)
+_IMAX = np.int32(0x7FFFFFFF)          # biased 0xFFFFFFFF: never wins a min
+
+
+def _biased(h: jax.Array) -> jax.Array:
+    """uint32 -> int32 with the same order (Mosaic has no unsigned min)."""
+    return jax.lax.bitcast_convert_type(h ^ _BIAS, jnp.int32)
+
+
+def _unbiased(v: jax.Array) -> jax.Array:
+    """Inverse of ``biased``."""
+    return jax.lax.bitcast_convert_type(v, _U32) ^ _BIAS
+
+
+def _valid_rows(counts_ref, shape, t0) -> jax.Array:
+    """(BLK_T, BLK_N) mask: nonzero slot t0 + t of example i is real."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0) + t0 < counts_ref[...]
+
+
+def _init_acc(acc_ref) -> None:
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        acc_ref[...] = jnp.full(acc_ref.shape, _IMAX, jnp.int32)
+
+
+def _min_into(acc_ref, row, vals) -> None:
+    """acc[row] = min(acc[row], min over sublanes of ``vals``)."""
+    m = jnp.min(vals, axis=0, keepdims=True)
+    acc_ref[pl.ds(row, 1), :] = jnp.minimum(acc_ref[pl.ds(row, 1), :], m)
 
 
 # ---------------------------------------------------------------------------
-# Kernel bodies
+# 4U hash on a (BLK_T, BLK_N) tile with scalar coefficients (2U is
+# ``repro.core.hashing.hash2u_apply`` as is)
 # ---------------------------------------------------------------------------
 
-def _minhash2u_kernel(counts_ref, idx_ref, a1_ref, a2_ref, out_ref,
-                      *packed_refs, s: int, b: int, blk_t: int, variant: str,
-                      pack: bool = False):
-    t_step = pl.program_id(2)
-    n_t = pl.num_programs(2)
-
-    @pl.when(t_step == 0)
-    def _init():
-        out_ref[...] = jnp.full_like(out_ref, _PAD)
-
-    idx = idx_ref[...]                                    # (BLK_N, BLK_T) i32
-    counts = counts_ref[...]                              # (BLK_N, 1) i32
-    col = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 1) + t_step * blk_t
-    valid = col < counts                                  # (BLK_N, BLK_T)
-
-    a1 = a1_ref[...]                                      # (1, BLK_K) u32
-    a2 = a2_ref[...]
-    # (BLK_N, BLK_T, BLK_K): the SIMD tile. uint32 mul wraps mod 2^32.
-    h = a1[0][None, None, :] + a2[0][None, None, :] * idx.astype(_U32)[..., None]
-    if s < 32:
-        if variant == "high":
-            h = h >> _U32(32 - s)
-        else:
-            h = h & _U32((1 << s) - 1)
-    h = jnp.where(valid[..., None], h, _PAD)
-    blk_min = jnp.min(h, axis=1)                          # (BLK_N, BLK_K)
-    out_ref[...] = jnp.minimum(out_ref[...], blk_min)
-
-    if b > 0:
-        @pl.when(t_step == n_t - 1)
-        def _extract_bbits():
-            z = out_ref[...] & _U32((1 << b) - 1)
-            out_ref[...] = z
-            if pack:
-                packed_refs[0][...] = pack_block(z, b)
-
-
-def _minhash4u_kernel(counts_ref, idx_ref, a_ref, out_ref, *packed_refs,
-                      s: int, b: int, blk_t: int, pack: bool = False):
-    t_step = pl.program_id(2)
-    n_t = pl.num_programs(2)
-
-    @pl.when(t_step == 0)
-    def _init():
-        out_ref[...] = jnp.full_like(out_ref, _PAD)
-
-    idx = idx_ref[...]
-    counts = counts_ref[...]
-    col = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 1) + t_step * blk_t
-    valid = col < counts
-
-    a = a_ref[...]                                        # (4, BLK_K) u32
-    t = idx.astype(_U32)[..., None]                       # (BLK_N, BLK_T, 1)
+def _hash4u(t, a1, a2, a3, a4, *, s: int):
     # Horner: acc = ((a4 t + a3) t + a2) t + a1, each step mod p via BitMod.
-    acc = jnp.broadcast_to(a[3][None, None, :], t.shape[:2] + (a.shape[1],))
-    for i in (2, 1, 0):
+    acc = jnp.full(t.shape, a4, _U32)
+    for coef in (a3, a2, a1):
         hi, lo = umul32_wide(acc, t)                      # acc*t < 2^62
-        hi, lo = add64(hi, lo, jnp.broadcast_to(a[i][None, None, :], lo.shape))
+        hi, lo = add64(hi, lo, jnp.full(lo.shape, coef, _U32))
         acc = mod_mersenne31(hi, lo)
-    if s < 31:
-        acc = acc & _U32((1 << s) - 1)
-    h = jnp.where(valid[..., None], acc, _PAD)
-    blk_min = jnp.min(h, axis=1)
-    out_ref[...] = jnp.minimum(out_ref[...], blk_min)
+    return acc & _U32((1 << s) - 1) if s < 31 else acc
 
-    if b > 0:
-        @pl.when(t_step == n_t - 1)
-        def _extract_bbits():
-            z = out_ref[...] & _U32((1 << b) - 1)
-            out_ref[...] = z
-            if pack:
-                packed_refs[0][...] = pack_block(z, b)
+
+# ---------------------------------------------------------------------------
+# Kernel body
+# ---------------------------------------------------------------------------
+
+def _minhash_kernel(counts_ref, idx_ref, coef_ref, out_ref, acc_ref, *,
+                    hash_fn, b: int, blk_t: int, blk_k: int, pack: bool):
+    _init_acc(acc_ref)
+    # grid indices are read outside the loop body: the interpreter only
+    # binds them at the kernel's top level
+    j0 = pl.program_id(1) * blk_k
+    t0 = pl.program_id(2) * blk_t
+    n_coef = coef_ref.shape[0]
+
+    def one_hash(j, carry):
+        t = idx_ref[...].astype(_U32)                     # (BLK_T, BLK_N)
+        h = hash_fn(t, *[coef_ref[c, j0 + j] for c in range(n_coef)])
+        valid = _valid_rows(counts_ref, t.shape, t0)
+        row = pack_row(j, blk_k, b) if pack else j
+        _min_into(acc_ref, row, jnp.where(valid, _biased(h), _IMAX))
+        return carry
+
+    jax.lax.fori_loop(0, blk_k, one_hash, 0)
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _emit():
+        sig = _unbiased(acc_ref[...])
+        if b > 0:
+            sig = sig & _U32((1 << b) - 1)
+        out_ref[...] = pack_block(sig, b) if pack else sig
 
 
 # ---------------------------------------------------------------------------
 # pallas_call builders
 # ---------------------------------------------------------------------------
 
-def _common_grid_specs(n, nnz, k, blk_n, blk_t, blk_k):
+def _compiler_params(*semantics: str) -> dict:
+    """TPU compiler params declaring which grid axes are reductions."""
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=semantics)}
+
+
+def _row_major_specs(n, nnz, k, blk_n, blk_t, blk_k):
+    """Grid and the (counts, indices, out) specs of the transposed layout:
+    indices (nnz, n), counts (1, n), out (k, n)."""
     if n % blk_n or nnz % blk_t or k % blk_k:
         raise ValueError(
             f"shapes must tile: n={n}%{blk_n}, nnz={nnz}%{blk_t}, k={k}%{blk_k}")
     grid = (n // blk_n, k // blk_k, nnz // blk_t)
-    counts_spec = pl.BlockSpec((blk_n, 1), lambda i, j, t: (i, 0))
-    idx_spec = pl.BlockSpec((blk_n, blk_t), lambda i, j, t: (i, t))
-    out_spec = pl.BlockSpec((blk_n, blk_k), lambda i, j, t: (i, j))
+    counts_spec = pl.BlockSpec((1, blk_n), lambda i, j, t: (0, i))
+    idx_spec = pl.BlockSpec((blk_t, blk_n), lambda i, j, t: (t, i))
+    out_spec = pl.BlockSpec((blk_k, blk_n), lambda i, j, t: (j, i))
     return grid, counts_spec, idx_spec, out_spec
 
 
-def _compiler_params(interpret: bool):
-    if interpret:
-        return {}
-    try:  # TPU-only: declare the reduction dim non-parallel
-        from jax.experimental.pallas import tpu as pltpu
-        for name in ("CompilerParams", "TPUCompilerParams"):
-            cls = getattr(pltpu, name, None)
-            if cls is not None:
-                return {"compiler_params": cls(
-                    dimension_semantics=("parallel", "parallel", "arbitrary"))}
-    except ImportError:
-        pass
-    return {}
-
-
-def _pack_out(n, k, b, blk_n, blk_k, out_spec, pack):
-    """(out_specs, out_shapes) with the optional packed-words output."""
-    out_specs = [out_spec]
-    out_shapes = [jax.ShapeDtypeStruct((n, k), jnp.uint32)]
+def _minhash_call(indices, counts, coef, hash_fn, *, b, blk_n, blk_t, blk_k,
+                  pack, interpret):
+    n, nnz = indices.shape
+    k = coef.shape[1]
+    grid, counts_spec, idx_spec, out_spec = _row_major_specs(
+        n, nnz, k, blk_n, blk_t, blk_k)
+    out_rows = k
     if pack:
         if b <= 0 or 32 % b or (blk_k * b) % 32:
             raise ValueError(f"fused pack needs b | 32 and blk_k*b % 32 == 0, "
                              f"got b={b}, blk_k={blk_k}")
-        out_specs.append(
-            pl.BlockSpec((blk_n, blk_k * b // 32), lambda i, j, t: (i, j)))
-        out_shapes.append(jax.ShapeDtypeStruct((n, k * b // 32), jnp.uint32))
-    return out_specs, out_shapes
+        out_rows = k * b // 32
+        out_spec = pl.BlockSpec((blk_k * b // 32, blk_n),
+                                lambda i, j, t: (j, i))
+    kern = functools.partial(_minhash_kernel, hash_fn=hash_fn, b=b,
+                             blk_t=blk_t, blk_k=blk_k, pack=pack)
+    out = pl.pallas_call(
+        kern,
+        grid=grid,
+        in_specs=[counts_spec, idx_spec,
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct((out_rows, n), _U32),
+        scratch_shapes=[pltpu.VMEM((blk_k, blk_n), jnp.int32)],
+        interpret=interpret,
+        **_compiler_params("parallel", "parallel", "arbitrary"),
+    )(counts.reshape(1, n), indices.T, coef)
+    return out.T
 
 
 def minhash2u_pallas(indices: jax.Array, counts: jax.Array, a1: jax.Array,
                      a2: jax.Array, *, s: int, b: int = 0,
-                     blk_n: int = 8, blk_t: int = 128, blk_k: int = 128,
+                     blk_n: int = 128, blk_t: int = 128, blk_k: int = 128,
                      variant: str = "high", pack: bool = False,
-                     interpret: bool = True):
+                     interpret: bool):
     """2U minhash signatures: (n, nnz) indices -> (n, k) uint32 minima.
 
     Args:
-      indices: (n, max_nnz) int32, padded.
-      counts:  (n, 1) int32 valid-lane counts per row.
+      indices: (n, max_nnz) int32, padded; n, nnz and k must tile.
+      counts:  (n, 1) int32 valid-slot counts per example.
       a1, a2:  (k,) uint32 multiply-shift coefficients (a2 odd).
       s:       D = 2^s.
       b:       if > 0, fuse lowest-b-bit extraction into the last step.
-      pack:    also emit the bit-packed (n, k*b/32) words from the final
-               grid step; returns ``(sig, packed)``.
+      pack:    emit the bit-packed (n, k*b/32) words from the final grid
+               step instead of the (n, k) signatures.
+      interpret: run the Pallas interpreter (CPU) instead of Mosaic.
     """
-    n, nnz = indices.shape
-    k = a1.shape[0]
-    grid, counts_spec, idx_spec, out_spec = _common_grid_specs(
-        n, nnz, k, blk_n, blk_t, blk_k)
-    coeff_spec = pl.BlockSpec((1, blk_k), lambda i, j, t: (0, j))
-    out_specs, out_shapes = _pack_out(n, k, b, blk_n, blk_k, out_spec, pack)
-    kern = functools.partial(_minhash2u_kernel, s=s, b=b, blk_t=blk_t,
-                             variant=variant, pack=pack)
-    out = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[counts_spec, idx_spec, coeff_spec, coeff_spec],
-        out_specs=out_specs if pack else out_specs[0],
-        out_shape=out_shapes if pack else out_shapes[0],
-        interpret=interpret,
-        **_compiler_params(interpret),
-    )(counts, indices, a1[None, :], a2[None, :])
-    return out
+    hash_fn = functools.partial(hash2u_apply, s=s, variant=variant)
+    return _minhash_call(indices, counts, jnp.stack([a1, a2]), hash_fn, b=b,
+                         blk_n=blk_n, blk_t=blk_t, blk_k=blk_k, pack=pack,
+                         interpret=interpret)
 
 
 def minhash4u_pallas(indices: jax.Array, counts: jax.Array, a: jax.Array, *,
-                     s: int, b: int = 0, blk_n: int = 8, blk_t: int = 128,
+                     s: int, b: int = 0, blk_n: int = 128, blk_t: int = 128,
                      blk_k: int = 128, pack: bool = False,
-                     interpret: bool = True):
-    """4U minhash signatures with in-kernel Mersenne BitMod (§3.4)."""
-    n, nnz = indices.shape
-    k = a.shape[1]
-    grid, counts_spec, idx_spec, out_spec = _common_grid_specs(
-        n, nnz, k, blk_n, blk_t, blk_k)
-    coeff_spec = pl.BlockSpec((4, blk_k), lambda i, j, t: (0, j))
-    out_specs, out_shapes = _pack_out(n, k, b, blk_n, blk_k, out_spec, pack)
-    kern = functools.partial(_minhash4u_kernel, s=s, b=b, blk_t=blk_t,
-                             pack=pack)
-    out = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[counts_spec, idx_spec, coeff_spec],
-        out_specs=out_specs if pack else out_specs[0],
-        out_shape=out_shapes if pack else out_shapes[0],
-        interpret=interpret,
-        **_compiler_params(interpret),
-    )(counts, indices, a)
-    return out
+                     interpret: bool):
+    """4U minhash signatures with in-kernel Mersenne BitMod (§3.4);
+    a: (4, k) uint32.  Same layout and options as ``minhash2u_pallas``."""
+    hash_fn = functools.partial(_hash4u, s=s)
+    return _minhash_call(indices, counts, a, hash_fn, b=b, blk_n=blk_n,
+                         blk_t=blk_t, blk_k=blk_k, pack=pack,
+                         interpret=interpret)

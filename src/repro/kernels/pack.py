@@ -14,10 +14,10 @@ engine, the cache shards and the learning layer all agree:
   * ``pack_device`` / ``unpack_device`` -- jnp pack/unpack epilogues,
     meant to be traced *inside* the same jit as the kernel (pack) or the
     SGD step (unpack) so only packed words ever cross the host boundary.
-  * ``pack_block`` -- the in-kernel packing epilogue: packs a
-    (BLK_N, BLK_K) b-bit tile into (BLK_N, BLK_K*b/32) words in the
-    kernel's final grid step (used by ``kernels/minhash.py`` when the
-    signature length is lane-aligned).
+  * ``pack_row`` / ``pack_block`` -- the in-kernel packing epilogue of
+    ``kernels/minhash.py``: the kernel stores code j of a k-block in row
+    ``pack_row(j)`` of a (BLK_K, rows) tile, and ``pack_block`` turns that
+    tile into (BLK_K*b/32, rows) words with whole-tile shifts only.
 
 Bit layout (shared with ``repro.core.bbit.pack_codes``): code j occupies
 bits [j*code_bits, (j+1)*code_bits) of the row's little-endian bitstream.
@@ -30,8 +30,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.core.bbit import (pack_codes, pack_signatures, packed_words,
-                             unpack_codes)
+from repro.core.bbit import pack_codes, packed_words, unpack_codes
 from repro.core.oph import EMPTY
 
 
@@ -98,19 +97,39 @@ def unpack_device(packed: jax.Array, spec: PackSpec) -> jax.Array:
 
 def can_pack_in_kernel(k_pad: int, k: int, b: int, blk_k: int) -> bool:
     """True when the kernel's final grid step can emit packed words
-    directly: lane-aligned codes (b | 32), no sliced padding lanes, and
-    whole words per k-block."""
-    return (0 < b <= 16 and 32 % b == 0 and k_pad == k
-            and (blk_k * b) % 32 == 0)
+    directly: lane-aligned codes (b | 32), no sliced padding codes, and a
+    packed block of whole words whose height is a multiple of the 8-row
+    sublane tile or the whole packed signature."""
+    if not (0 < b <= 16 and 32 % b == 0 and k_pad == k
+            and (blk_k * b) % 32 == 0):
+        return False
+    return (blk_k * b // 32) % 8 == 0 or blk_k == k
+
+
+def pack_row(j, blk_k: int, b: int):
+    """Tile row that holds code j (0 <= j < blk_k) of a k-block.
+
+    Code j belongs to word j // (32/b) at field j % (32/b).  Storing it
+    at row ``field * words + word`` makes every field a contiguous run of
+    ``words`` rows, so ``pack_block`` needs no strided or lane access.
+    Works on Python ints and traced int32 scalars alike.
+    """
+    per_word = 32 // b
+    return (j % per_word) * (blk_k // per_word) + j // per_word
 
 
 def pack_block(tile: jax.Array, b: int) -> jax.Array:
-    """In-kernel epilogue: (BLK_N, BLK_K) b-bit tile -> packed words.
+    """In-kernel epilogue: (BLK_K, rows) b-bit tile in ``pack_row`` order
+    -> (BLK_K*b/32, rows) uint32 words, word-major per row.
 
-    Requires b | 32 and BLK_K*b % 32 == 0 (``can_pack_in_kernel``), under
-    which the lane-aligned ``repro.core.bbit.pack_signatures`` layout
-    coincides bit-for-bit with the ``pack_codes`` bitstream, so host-side
-    unpacking is one shared code path regardless of where the packing
-    ran.  (Plain reshape/shift/sum -- traces fine inside Pallas.)
+    Requires ``can_pack_in_kernel``.  Word w of a row is
+    ``sum_f code[w*(32/b) + f] << (f*b)``, the ``repro.core.bbit.pack_codes``
+    bitstream for b | 32, so host-side unpacking is one shared code path
+    regardless of where the packing ran.
     """
-    return pack_signatures(tile, b)
+    per_word = 32 // b
+    words = tile.shape[0] // per_word
+    out = tile[:words]
+    for f in range(1, per_word):
+        out = out | (tile[f * words:(f + 1) * words] << jnp.uint32(f * b))
+    return out

@@ -55,6 +55,9 @@ def serve_index(args) -> None:
                              choose_band_config, load_index, load_sharded)
     from repro.train.online import make_family
 
+    if args.mesh > len(jax.devices()):
+        raise SystemExit(f"--mesh {args.mesh} needs {args.mesh} devices, "
+                         f"JAX sees {len(jax.devices())}")
     k, b, s = args.k, args.b, 16
     spec = DatasetSpec("serve_index", n=args.docs, D=1 << s,
                        avg_nnz=64, n_prototypes=8, overlap=0.8, seed=0)
@@ -83,7 +86,7 @@ def serve_index(args) -> None:
             mesh = None
             if args.mesh:
                 from repro.launch.mesh import make_debug_mesh
-                n_dev = min(args.mesh, len(jax.devices()))
+                n_dev = args.mesh
                 mesh = make_debug_mesh(n_dev, axes=("data",))
             searcher = load_sharded(shard_dir, mesh=mesh,
                                     max_device_bytes=args.device_window)
@@ -307,6 +310,8 @@ def build_parser() -> argparse.ArgumentParser:
 def main():
     ap = build_parser()
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.index:
         serve_index(args)

@@ -20,7 +20,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models.layers import swiglu
 from repro.sharding.rules import constrain
 
@@ -305,7 +304,7 @@ def moe_ffn_ep(params: dict, x: jax.Array, cfg: MoEConfig, mesh) -> jax.Array:
             y_loc = jax.lax.dynamic_slice_in_dim(y_loc, j * d_loc, d_loc, 1)
         return y_loc
 
-    y = shard_map(
+    y = jax.shard_map(
         block_a2a if a2a else block_psum, mesh=mesh,
         in_specs=(tok_spec, P(), wg_spec, wg_spec, wd_spec),
         out_specs=tok_spec, check_vma=False,

@@ -63,18 +63,15 @@ def compressed_psum_int8(g: jax.Array, key: jax.Array, axis_name: str
 def make_compressed_allreduce(mesh, axis_name: str = "dp", spec=None):
     """Build the shard_map-wrapped int8 mean-allreduce.
 
-    Returns ``f(g, key) -> mean(g)`` ready to ``jax.jit``; uses the
-    ``repro.compat.shard_map`` shim so the same call works across jax
-    versions (``jax.shard_map`` vs ``jax.experimental.shard_map``).
+    Returns ``f(g, key) -> mean(g)`` ready to ``jax.jit``.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     spec = P() if spec is None else spec
 
     def f(g, key):
         return compressed_psum_int8(g, key, axis_name)
 
-    return shard_map(f, mesh=mesh, in_specs=(spec, P()), out_specs=spec)
+    return jax.shard_map(f, mesh=mesh, in_specs=(spec, P()), out_specs=spec)
 
 
 def topk_compress(g: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:

@@ -102,7 +102,7 @@ def test_packed_match_bit_exact_vs_unpacked_reference(scheme, fam, densify,
     else:
         got_m = out
     assert np.array_equal(np.asarray(got_m), want_m)
-    # the gpu/ref backends (jnp oracle) agree too
+    # the ref backend (jnp oracle) agrees too
     out_ref = packed_match(qwords, cwords, spec, backend="ref")
     ref_m = out_ref[0] if spec.sentinel else out_ref
     assert np.array_equal(np.asarray(ref_m), want_m)

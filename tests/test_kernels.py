@@ -110,3 +110,62 @@ def test_sigbag_is_eq5_inner_product():
     oh = expand_onehot(tok.astype(jnp.uint32), b)
     via_onehot = np.asarray(oh @ w)
     np.testing.assert_allclose(via_kernel, via_onehot, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Layout regressions: k=512, fused pack, ragged rows, straddling codes
+# ---------------------------------------------------------------------------
+
+def _ragged_batch(n, max_set, s, seed):
+    rng = np.random.default_rng(seed)
+    sets = [rng.choice(1 << s, rng.integers(1, max_set + 1), replace=False)
+            for _ in range(n)]
+    return from_lists(sets)
+
+
+@pytest.mark.parametrize("family,b", [("2u", 1), ("2u", 2), ("2u", 4),
+                                      ("2u", 8), ("2u", 16), ("4u", 8)])
+def test_fused_pack_k512_ragged_rows_matches_ref(family, b):
+    """k=512 over two k-blocks, 37 rows (not a multiple of the 128-row
+    block) and a ragged nnz: the in-kernel pack emits the ref's words."""
+    from repro.core.bbit import pack_codes
+    from repro.kernels import SignatureEngine
+    from repro.kernels.pack import can_pack_in_kernel
+    k, s, blocks = 512, 20, {"blk_n": 128, "blk_t": 128, "blk_k": 256}
+    assert can_pack_in_kernel(k, k, b, blocks["blk_k"])
+    batch = _ragged_batch(37, 300, s, seed=b)
+    key = jax.random.PRNGKey(b)
+    fam = Hash2U.create(key, k, s) if family == "2u" else \
+        Hash4U.create(key, k, s)
+    got = SignatureEngine(fam, b=b, packed=True, backend="interpret",
+                          blocks=blocks).packed_signatures(batch)
+    counts = jnp.sum(batch.mask.astype(jnp.int32), axis=1)[:, None]
+    want = (kref.minhash2u_ref(batch.indices, counts, fam.a1, fam.a2, s=s,
+                               b=b) if family == "2u" else
+            kref.minhash4u_ref(batch.indices, counts, fam.a, s=s, b=b))
+    assert np.array_equal(np.asarray(got.data),
+                          np.asarray(pack_codes(want, b)))
+
+
+@pytest.mark.parametrize("k,b,sentinel", [(512, 8, False), (512, 8, True),
+                                          (100, 2, True), (77, 16, False)])
+def test_packed_match_layouts_match_ref(k, b, sentinel):
+    """Word-aligned and word-straddling codes (9- and 3-bit sentinel
+    wires), k not a multiple of 32, ragged Q/N, garbage past code k."""
+    from repro.kernels import PackSpec, packed_match
+    spec = PackSpec(k, b, sentinel)
+    rng = np.random.default_rng(k + b)
+    q = rng.integers(0, 2**32, (5, spec.words), dtype=np.uint64) \
+        .astype(np.uint32)
+    # corpus rows: the queries with a few words re-drawn, so most codes
+    # match and some do not
+    c = np.repeat(q, 40, axis=0)[:197]
+    flip = rng.random(c.shape) < 0.3
+    c[flip] = rng.integers(0, 2**32, int(flip.sum()), dtype=np.uint64)
+    got = packed_match(q, c, spec, backend="interpret")
+    want = kref.packed_match_ref(jnp.asarray(q), jnp.asarray(c), k=k,
+                                 code_bits=spec.code_bits, sentinel=sentinel)
+    got, want = (got, want) if sentinel else ((got,), (want,))
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    assert np.asarray(want[0]).max() > 0

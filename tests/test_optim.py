@@ -86,14 +86,13 @@ def test_int8_stochastic_rounding_unbiased():
 def test_compressed_psum_matches_mean():
     devs = jax.devices()
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
     mesh = Mesh(np.array(devs[:1]), ("dp",))
     g = jnp.asarray(np.random.default_rng(2).normal(size=(64,)), jnp.float32)
 
     def f(g):
         return compressed_psum_int8(g, jax.random.PRNGKey(0), "dp")
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P()))(g)
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P()))(g)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g), atol=0.1)
 
 

@@ -28,7 +28,7 @@ from repro.data.preprocess import preprocess_shards
 from repro.data.synthetic import DatasetSpec
 from repro.index import (IndexSearcher, build_index, build_sharded,
                          choose_band_config, load_index, load_sharded)
-from repro.launch.serve import build_parser
+from repro.launch.serve import build_parser, serve_index
 from repro.launch.server import (RequestShed, SearchServer, ServerStats,
                                  ZipfianTraffic)
 
@@ -577,6 +577,39 @@ def test_second_router_picks_up_append(growing_router, tmp_path):
 # ---------------------------------------------------------------------------
 # CLI + traffic model
 # ---------------------------------------------------------------------------
+
+def test_serve_refuses_mesh_larger_than_devices():
+    """--mesh D with fewer than D devices fails instead of quietly
+    serving on the devices that are there."""
+    args = build_parser().parse_args(["--index", "--shards", "2",
+                                      "--mesh", "4096"])
+    with pytest.raises(SystemExit, match="--mesh 4096 needs 4096 devices"):
+        serve_index(args)
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir_is_fixed(monkeypatch, tmp_path, env_dir):
+    """Unset: the cache lives at <checkout>/.jax_cache.  Set: JAX's own
+    JAX_COMPILATION_CACHE_DIR wins and nothing is changed."""
+    from repro.launch.compile_cache import enable_compile_cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    try:
+        got = enable_compile_cache()
+        if env_dir is None:
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
 
 def test_serve_cli_smoke_flag_both_ways():
     """--smoke defaults on, and --no-smoke can actually turn it off (the

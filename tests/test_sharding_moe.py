@@ -23,7 +23,8 @@ def mesh8():
     if len(devs) < 8:
         pytest.skip("needs >= 8 devices (run under "
                     "--xla_force_host_platform_device_count)")
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_constrain_is_noop_without_mesh():
