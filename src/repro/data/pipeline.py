@@ -10,7 +10,10 @@ Responsibilities:
     exceeds its deadline is retried and, on repeated failure, reassigned to
     the next healthy worker (bookkeeping mirrors what a real multi-host
     data service does; on one host the "workers" are reader threads),
-  * load-time accounting consumed by the online-learning benchmarks.
+  * load-time accounting consumed by the online-learning benchmarks,
+  * spans on the loader thread, one per shard read (``prep.read``) and
+    one per chunk for the padded layout (``prep.pad``) and its hand-off
+    to the device (``prep.upload``), on the ``repro.obs`` tracer.
 
 The prefetch (``prefetch_iter``) and retry (``read_with_retries``)
 machinery is shared with the signature-cache replay path in
@@ -29,9 +32,11 @@ import threading
 import time
 from typing import Iterator, List, Optional, Sequence
 
+import jax.numpy as jnp
 import numpy as np
 
-from repro.data.sparse import SparseBatch, from_lists
+from repro.data.sparse import SparseBatch, pad_lists
+from repro.obs.trace import Tracer, get_tracer
 
 
 # ---------------------------------------------------------------------------
@@ -275,7 +280,8 @@ class ChunkedLoader:
     shards.  A read exceeding ``straggler_deadline_s`` is retried
     (``max_retries``); persistent failure reassigns the shard to the next
     worker -- the multi-host straggler story, modeled faithfully enough to
-    test the control logic.
+    test the control logic.  Spans go to ``tracer`` (default: the
+    process-wide ``get_tracer()``).
     """
 
     def __init__(self, shard_paths: Sequence[str], chunk_size: int = 10_000,
@@ -284,7 +290,8 @@ class ChunkedLoader:
                  straggler_deadline_s: float = 30.0, max_retries: int = 2,
                  io_backoff_base_s: float = 0.05,
                  io_backoff_cap_s: float = 1.0,
-                 lane_multiple: int = 128):
+                 lane_multiple: int = 128,
+                 tracer: Optional[Tracer] = None):
         self.shard_paths = list(shard_paths)
         self.chunk_size = chunk_size
         self.fmt = fmt
@@ -296,6 +303,7 @@ class ChunkedLoader:
         self.io_backoff_base_s = io_backoff_base_s
         self.io_backoff_cap_s = io_backoff_cap_s
         self.lane_multiple = lane_multiple
+        self.tracer = tracer if tracer is not None else get_tracer()
         self.stats = LoaderStats()
         from repro.obs.metrics import get_registry
         get_registry().register_object(self.stats, loader_collector("load"))
@@ -324,7 +332,8 @@ class ChunkedLoader:
         skip = skip_examples
         for i in range(start_shard, len(self.shard_paths)):
             worker = i % self.n_workers
-            sets, labels = self._read_shard(self.shard_paths[i], worker)
+            with self.tracer.span("prep.read"):
+                sets, labels = self._read_shard(self.shard_paths[i], worker)
             self.shard_examples[i] = len(sets)
             if skip:
                 take = min(skip, len(sets))
@@ -344,9 +353,16 @@ class ChunkedLoader:
             yield self._make_batch(pending_sets, pending_labels)
 
     def _make_batch(self, sets, labels) -> SparseBatch:
+        """``from_lists`` in two spans: the host layout, then the
+        device hand-off (which only enqueues the copies)."""
         self.stats.chunks += 1
-        return from_lists(sets, np.asarray(labels, np.float32),
-                          max_nnz=self.max_nnz, lane_multiple=self.lane_multiple)
+        with self.tracer.span("prep.pad"):
+            idx, msk = pad_lists(sets, self.max_nnz, self.lane_multiple)
+            lab = np.asarray(labels, np.float32)
+        with self.tracer.span("prep.upload"):
+            return SparseBatch(indices=jnp.asarray(idx),
+                               mask=jnp.asarray(msk),
+                               labels=jnp.asarray(lab))
 
     def resume_point(self, example_offset: int):
         """Map a stream example offset -> (shard index, in-shard skip).

@@ -4,7 +4,10 @@ This is the paper's §3 production pipeline as a service: stream raw sparse
 shards through the signature engine in chunks, write bit-packed ``.sig``
 signature shards (k*b bits per example -- the Table-2/§6 wire accounting,
 sentinel OPH included via (b+1)-bit codes), and account the three phases
-(load / kernel / store) exactly as Figures 1-3 split them.  Multiple
+(load / kernel / store) exactly as Figures 1-3 split them, on the host
+clock and as spans on the caller's thread (``prep.wait``, ``prep.hash``,
+``prep.store``; the loader thread adds ``prep.read``, ``prep.pad`` and
+``prep.upload``).  Multiple
 workers own disjoint shard slices (the ChunkedLoader's straggler
 machinery applies); the ``backend`` argument picks execution through the
 ``repro.kernels.SignatureEngine`` registry (compiled on TPU, interpret on
@@ -14,6 +17,7 @@ CPU hosts, jnp fallback on GPU until the triton lowering lands).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import os
 import time
 from typing import Optional, Sequence
@@ -26,10 +30,21 @@ from repro.core.oph import OPH
 from repro.data.pipeline import ChunkedLoader
 from repro.data.sigshard import read_sig_shard, write_sig_shard
 from repro.kernels import SignatureEngine
+from repro.obs.trace import Tracer, get_tracer
 
 
 @dataclasses.dataclass
 class PreprocessStats:
+    """Host-clock phase times of one ``preprocess_shards`` call.
+
+    ``load_s`` is the time the caller waited on the loader's prefetch
+    queue, not the time spent reading: with prefetch on (the default)
+    the loader reads, pads and uploads on its own thread, overlapping
+    the kernel and the store.
+    ``kernel_s`` runs to the packed words being ready on the device;
+    ``store_s`` covers the copy back and the ``.sig`` write.
+    """
+
     examples: int = 0
     load_s: float = 0.0
     kernel_s: float = 0.0
@@ -44,7 +59,8 @@ class PreprocessStats:
 def preprocess_shards(shard_paths: Sequence[str], out_dir: str, family, *,
                       b: int = 8, chunk_size: int = 10_000,
                       n_workers: int = 1, backend: Optional[str] = None,
-                      loader_kwargs: Optional[dict] = None
+                      loader_kwargs: Optional[dict] = None,
+                      tracer: Optional[Tracer] = None
                       ) -> PreprocessStats:
     """Run the full preprocessing pipeline; returns phase accounting.
 
@@ -56,6 +72,9 @@ def preprocess_shards(shard_paths: Sequence[str], out_dir: str, family, *,
     codes; sentinel signatures pack as (b+1)-bit codes with EMPTY stored
     as 2^b, so even the estimator-facing sentinel scheme ships the
     paper's per-example bit budget.
+
+    Spans (one each per chunk) go to ``tracer``, by default the
+    process-wide ``get_tracer()``, and to the loader's too.
     """
     if isinstance(family, OPH):
         if not isinstance(family.base, (Hash2U, Hash4U)):
@@ -65,29 +84,38 @@ def preprocess_shards(shard_paths: Sequence[str], out_dir: str, family, *,
     engine = SignatureEngine(family, b=b, packed=True, backend=backend)
     os.makedirs(out_dir, exist_ok=True)
     stats = PreprocessStats()
+    tracer = tracer if tracer is not None else get_tracer()
     loader = ChunkedLoader(shard_paths, chunk_size=chunk_size,
-                           n_workers=n_workers, **(loader_kwargs or {}))
-    t_mark = time.perf_counter()
-    for idx, chunk in enumerate(loader):
+                           n_workers=n_workers, tracer=tracer,
+                           **(loader_kwargs or {}))
+    chunks = iter(loader)
+    for idx in itertools.count():
+        t_mark = time.perf_counter()
+        with tracer.span("prep.wait"):
+            chunk = next(chunks, None)
         t_loaded = time.perf_counter()
         stats.load_s += t_loaded - t_mark
+        if chunk is None:
+            break
         stats.examples += chunk.n
         stats.bytes_in += chunk.nbytes()
 
-        packed = engine.packed_signatures(chunk)     # packed on device
-        jax.block_until_ready(packed.data)
+        with tracer.span("prep.hash"):
+            packed = engine.packed_signatures(chunk)     # packed on device
+            jax.block_until_ready(packed.data)
         t_kernel = time.perf_counter()
         stats.kernel_s += t_kernel - t_loaded
 
-        out_path = os.path.join(out_dir, f"sig_{idx:05d}.sig")
-        labels = (np.asarray(chunk.labels) if chunk.labels is not None
-                  else np.zeros((chunk.n,), np.float32))
-        write_sig_shard(out_path, np.asarray(packed.data), labels,
-                        k=packed.k, b=packed.b, code_bits=packed.code_bits,
-                        sentinel=packed.sentinel)
-        stats.bytes_out += os.path.getsize(out_path)
-        t_mark = time.perf_counter()
-        stats.store_s += t_mark - t_kernel
+        with tracer.span("prep.store"):
+            out_path = os.path.join(out_dir, f"sig_{idx:05d}.sig")
+            labels = (np.asarray(chunk.labels) if chunk.labels is not None
+                      else np.zeros((chunk.n,), np.float32))
+            write_sig_shard(out_path, np.asarray(packed.data), labels,
+                            k=packed.k, b=packed.b,
+                            code_bits=packed.code_bits,
+                            sentinel=packed.sentinel)
+            stats.bytes_out += os.path.getsize(out_path)
+        stats.store_s += time.perf_counter() - t_kernel
     return stats
 
 

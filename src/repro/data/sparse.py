@@ -54,9 +54,11 @@ def pad_to_multiple(x: np.ndarray, multiple: int, axis: int, value=0) -> np.ndar
     return np.pad(x, pad, constant_values=value)
 
 
-def from_lists(sets: Sequence[np.ndarray], labels: Optional[np.ndarray] = None,
-               max_nnz: Optional[int] = None, lane_multiple: int = 128) -> SparseBatch:
-    """Build a SparseBatch from a list of index arrays (CPU-side)."""
+def pad_lists(sets: Sequence[np.ndarray], max_nnz: Optional[int] = None,
+              lane_multiple: int = 128) -> Tuple[np.ndarray, np.ndarray]:
+    """The padded host arrays of ``from_lists``: indices (n, width) int32
+    and mask (n, width) bool, width ``max_nnz`` (default: the longest
+    set) rounded up to ``lane_multiple``; longer sets are truncated."""
     n = len(sets)
     if max_nnz is None:
         max_nnz = max((len(s) for s in sets), default=1) or 1
@@ -67,6 +69,13 @@ def from_lists(sets: Sequence[np.ndarray], labels: Optional[np.ndarray] = None,
         m = min(len(s), max_nnz)
         idx[i, :m] = np.asarray(s[:m], np.int32)
         msk[i, :m] = True
+    return idx, msk
+
+
+def from_lists(sets: Sequence[np.ndarray], labels: Optional[np.ndarray] = None,
+               max_nnz: Optional[int] = None, lane_multiple: int = 128) -> SparseBatch:
+    """Build a SparseBatch from a list of index arrays (CPU-side)."""
+    idx, msk = pad_lists(sets, max_nnz, lane_multiple)
     lab = None if labels is None else jnp.asarray(labels, jnp.float32)
     return SparseBatch(indices=jnp.asarray(idx), mask=jnp.asarray(msk), labels=lab)
 

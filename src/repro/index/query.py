@@ -64,7 +64,7 @@ from repro.index.builder import SigIndex
 from repro.kernels import PackedSignatures, packed_match
 from repro.kernels.hamming import _packed_match_run
 from repro.obs.metrics import get_registry
-from repro.obs.trace import get_tracer
+from repro.obs.trace import Tracer, get_tracer
 
 # jit-retrace accounting, read by tests: a second flush with the same
 # (query batch, corpus window, topk, block) must be a jit-cache hit.
@@ -357,12 +357,14 @@ class _BatchedAdmission:
 
     Hosts queue single queries with ``submit`` and run the whole queue
     as ONE batch with ``flush``.  Requires the host class to provide
-    ``spec`` (the wire format) and ``search``.
+    ``spec`` (the wire format) and ``search``.  ``flush`` records its
+    spans on ``tracer`` (default: the process-wide ``get_tracer()``).
     """
 
-    def _admission_init(self) -> None:
+    def _admission_init(self, tracer: Optional[Tracer] = None) -> None:
         self._pending: List[Tuple[int, jax.Array, Optional[int]]] = []
         self._next_ticket = 0
+        self._tracer = tracer
 
     def submit(self, query: Union[PackedSignatures, jax.Array, np.ndarray],
                *, query_size: Optional[int] = None) -> int:
@@ -397,15 +399,18 @@ class _BatchedAdmission:
             qsizes = np.asarray(sizes, np.uint32)
         else:
             qsizes = None
-        with get_tracer().span("search_dispatch",
-                               args={"mode": mode, "batch": len(tickets)}):
+        tracer = self._tracer if self._tracer is not None else get_tracer()
+        with tracer.span("search_dispatch",
+                         args={"mode": mode, "batch": len(tickets)}):
             res = self.search(batch, topk, mode=mode, query_sizes=qsizes)
-        return {t: SearchResult(res.indices[i:i + 1], res.scores[i:i + 1],
-                                None if res.n_candidates is None
-                                else res.n_candidates[i:i + 1],
-                                coverage=res.coverage,
-                                failed_shards=res.failed_shards)
-                for i, t in enumerate(tickets)}
+        with tracer.span("flush:split"):
+            return {t: SearchResult(res.indices[i:i + 1],
+                                    res.scores[i:i + 1],
+                                    None if res.n_candidates is None
+                                    else res.n_candidates[i:i + 1],
+                                    coverage=res.coverage,
+                                    failed_shards=res.failed_shards)
+                    for i, t in enumerate(tickets)}
 
 
 class IndexSearcher(_BatchedAdmission):

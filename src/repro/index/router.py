@@ -75,7 +75,7 @@ from repro.index.query import (IndexSearcher, SearchResult, _BatchedAdmission,
                                _query_words, exact_scan_ids, lsh_rerank_ids)
 from repro.kernels import PackedSignatures
 from repro.obs.metrics import Sample, get_registry
-from repro.obs.trace import get_tracer
+from repro.obs.trace import Tracer, get_tracer
 from repro.sharding.rules import data_axis_devices, place_shards
 
 
@@ -393,7 +393,8 @@ class ShardedIndex(_BatchedAdmission):
                topk: int = 10, *, mode: str = "exact",
                query_sizes: Optional[np.ndarray] = None,
                dispatch: Optional[str] = None,
-               on_shard_failure: Optional[str] = None) -> SearchResult:
+               on_shard_failure: Optional[str] = None,
+               tracer: Optional[Tracer] = None) -> SearchResult:
         """Global top-k: fan out to every shard, merge.
 
         With the mesh dispatcher, both modes run as ONE ``shard_map``
@@ -418,8 +419,13 @@ class ShardedIndex(_BatchedAdmission):
         and the failed shard indices.  The mesh dispatcher is a single
         in-process collective with no per-shard failure domain, so the
         policy only applies to the client fan-out.
+
+        The fan-out's phases (``Tracer.phase``) go to ``tracer``
+        (default: the process-wide ``get_tracer()``); a ``SearchServer``
+        passes its own, which replays them into each request's tree.
         """
         state = self._state
+        tracer = tracer if tracer is not None else get_tracer()
         policy = on_shard_failure or self.on_shard_failure
         if policy not in ("fail", "partial"):
             raise ValueError(f"on_shard_failure must be 'fail' or "
@@ -427,7 +433,7 @@ class ShardedIndex(_BatchedAdmission):
         qwords = _query_words(queries, state.searchers[0].index.spec)
         use_mesh = self._use_mesh(dispatch)
         if mode == "exact" and use_mesh:
-            return self._mesh_exact(state, qwords, topk, query_sizes)
+            return self._mesh_exact(state, qwords, topk, query_sizes, tracer)
         qkeys = None
         if mode == "lsh":
             idx0 = state.searchers[0].index
@@ -435,8 +441,7 @@ class ShardedIndex(_BatchedAdmission):
                                                 idx0.banding))
             if use_mesh:
                 return self._mesh_lsh(state, qwords, topk, query_sizes,
-                                      qkeys)
-        tracer = get_tracer()
+                                      qkeys, tracer)
         if policy == "fail":
             with tracer.phase("shard_dispatch",
                               args={"mode": mode,
@@ -619,7 +624,7 @@ class ShardedIndex(_BatchedAdmission):
                 "dispatch='sequential' for out-of-core shards")
 
     def _mesh_exact(self, state: _RouterState, qwords, topk: int,
-                    query_sizes) -> SearchResult:
+                    query_sizes, tracer: Tracer) -> SearchResult:
         if topk < 1:
             raise ValueError(f"topk must be >= 1, got {topk}")
         self._check_mesh_resident(state)
@@ -633,7 +638,6 @@ class ShardedIndex(_BatchedAdmission):
                                 has_sizes=has_sizes,
                                 D_univ=layout["D_univ"],
                                 statics=layout["statics"])
-        tracer = get_tracer()
         with tracer.phase("mesh_dispatch", args={"mode": "exact",
                                                  "devices": layout["D"]}):
             if has_sizes:
@@ -688,7 +692,8 @@ class ShardedIndex(_BatchedAdmission):
         return fn
 
     def _mesh_lsh(self, state: _RouterState, qwords, topk: int,
-                  query_sizes, qkeys: np.ndarray) -> SearchResult:
+                  query_sizes, qkeys: np.ndarray,
+                  tracer: Tracer) -> SearchResult:
         """LSH candidate-gen + rerank as ONE collective per flush.
 
         Candidate generation stays a host-side bucket probe per shard
@@ -711,7 +716,6 @@ class ShardedIndex(_BatchedAdmission):
         if has_sizes and query_sizes is None:
             raise ValueError("index stores set sizes; pass query_sizes "
                              "to search() for the exact Theorem-1 rerank")
-        tracer = get_tracer()
         D, q = layout["D"], qwords.shape[0]
         cand_cols: List[List[np.ndarray]] = [[] for _ in range(D)]
         mem_cols: List[List[np.ndarray]] = [[] for _ in range(D)]

@@ -90,9 +90,9 @@ from typing import Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.index.query import _BatchedAdmission
+from repro.index.router import ShardedIndex
 from repro.obs.metrics import Sample, get_registry
 from repro.obs.trace import get_tracer
-from repro.roofline.search import exact_scan_cost, roofline_gap
 
 
 def _percentile(samples, q: float) -> float:
@@ -353,12 +353,21 @@ class _WorkerHandle(_BatchedAdmission):
     concurrent flushes from different workers are safe and bit-identical
     to direct calls, while each worker's pending queue stays private
     (the shared searcher's own submit/flush state is never raced).
+    Spans of the flush, and a router's phases, go to ``tracer``: the
+    server's, so that ``take_phases`` finds them.
     """
 
-    def __init__(self, searcher, on_shard_failure: Optional[str] = None):
+    def __init__(self, searcher, on_shard_failure: Optional[str] = None,
+                 tracer=None):
         self._searcher = searcher
-        self._on_shard_failure = on_shard_failure
-        self._admission_init()
+        self._search_kwargs = {}
+        if on_shard_failure is not None:
+            # only a sharded router understands the policy; a plain
+            # IndexSearcher server leaves it unset
+            self._search_kwargs["on_shard_failure"] = on_shard_failure
+        if isinstance(searcher, ShardedIndex):
+            self._search_kwargs["tracer"] = tracer
+        self._admission_init(tracer)
 
     @property
     def spec(self):
@@ -366,13 +375,9 @@ class _WorkerHandle(_BatchedAdmission):
 
     def search(self, queries, topk: int = 10, *, mode: str = "exact",
                query_sizes=None):
-        kwargs = {}
-        if self._on_shard_failure is not None:
-            # only a sharded router understands the policy; a plain
-            # IndexSearcher server leaves it unset
-            kwargs["on_shard_failure"] = self._on_shard_failure
         return self._searcher.search(queries, topk, mode=mode,
-                                     query_sizes=query_sizes, **kwargs)
+                                     query_sizes=query_sizes,
+                                     **self._search_kwargs)
 
 
 ADMISSION_POLICIES = ("none", "reject", "shed-oldest", "degrade-to-lsh")
@@ -450,21 +455,6 @@ class SearchServer:
         self.registry = registry if registry is not None else get_registry()
         self.tracer = tracer if tracer is not None else get_tracer()
         self.registry.register_object(self, _server_samples)
-        # live roofline gauges, updated per exact flush: the autotuning
-        # signal (predicted-vs-measured flush bytes/time) at serve time
-        g = self.registry.gauge
-        self._g_roofline = {
-            "bytes": g("serve_roofline_predicted_bytes",
-                       "exact_scan_cost HBM bytes for the last flush"),
-            "predicted_s": g("serve_roofline_predicted_seconds",
-                             "memory-bound time prediction, last flush"),
-            "measured_s": g("serve_roofline_measured_seconds",
-                            "measured wall clock of the last exact flush"),
-            "gap": g("serve_roofline_gap",
-                     "measured / predicted flush time (1.0 = at roofline)"),
-            "gbps": g("serve_roofline_achieved_gbps",
-                      "effective streaming bandwidth of the last flush"),
-        }
         self._queue: Deque[PendingResult] = collections.deque()
         self._cond = threading.Condition()
         self._refresh_lock = threading.Lock()
@@ -492,7 +482,8 @@ class SearchServer:
             raise RuntimeError("server already started")
         self._stopping = False
         self.stats.t_start = time.monotonic()
-        self._handles = [_WorkerHandle(self.searcher, self.on_shard_failure)
+        self._handles = [_WorkerHandle(self.searcher, self.on_shard_failure,
+                                 self.tracer)
                          for _ in range(self.num_workers)]
         self._threads = [
             threading.Thread(target=self._dispatch_loop, args=(i,),
@@ -701,7 +692,8 @@ class SearchServer:
                                              t1=r.t_submit + r.latency_s,
                                              args={"outcome": "error"})
                         r.trace = None
-                handle = _WorkerHandle(self.searcher, self.on_shard_failure)
+                handle = _WorkerHandle(self.searcher, self.on_shard_failure,
+                                 self.tracer)
                 self._handles[wi] = handle
 
     def _flush_batch(self, batch: List[PendingResult], trigger: str,
@@ -736,30 +728,31 @@ class SearchServer:
             finally:
                 self._refresh_lock.release()
         tickets: Dict[int, PendingResult] = {}
-        for r in batch:
-            r.queue_wait_s = t0 - r.t_submit
-            with stats.lock:
-                stats.queue_wait_s.append(r.queue_wait_s)
-            if r.trace is not None:
-                tracer.add_span("queue", r.t_admit, t0, parent=r.trace,
-                                kind="async", args={"worker": wi})
-            try:
-                tickets[handle.submit(
-                    r.query, query_size=r.query_size)] = r
-            except Exception as e:       # a malformed query fails only itself
+        with tracer.span("flush:submit"):
+            for r in batch:
+                r.queue_wait_s = t0 - r.t_submit
                 with stats.lock:
-                    stats.errors += 1
-                r._resolve(None, e)
+                    stats.queue_wait_s.append(r.queue_wait_s)
                 if r.trace is not None:
-                    tracer.end_span(r.trace,
-                                    t1=r.t_submit + r.latency_s,
-                                    args={"outcome": "error"})
-                    r.trace = None
+                    tracer.add_span("queue", r.t_admit, t0, parent=r.trace,
+                                    kind="async", args={"worker": wi})
+                try:
+                    tickets[handle.submit(
+                        r.query, query_size=r.query_size)] = r
+                except Exception as e:   # a malformed query fails only itself
+                    with stats.lock:
+                        stats.errors += 1
+                    r._resolve(None, e)
+                    if r.trace is not None:
+                        tracer.end_span(r.trace,
+                                        t1=r.t_submit + r.latency_s,
+                                        args={"outcome": "error"})
+                        r.trace = None
         error: Optional[BaseException] = None
         out: Dict[int, object] = {}
         if tickets:
             try:
-                with tracer.jax_annotation(f"flush:w{wi}"):
+                with tracer.span(f"flush:w{wi}"):
                     out = handle.flush(self.topk, mode=mode)
             except Exception as e:
                 error = e
@@ -793,9 +786,6 @@ class SearchServer:
                     stats.partial += len(tickets)
         if cov < 1.0:
             outcome = "partial"
-        if (tickets and not degraded and mode == "exact" and error is None
-                and cov == 1.0):   # a partial flush scanned fewer bytes
-            self._update_roofline(len(tickets), dt)
         for ticket, r in tickets.items():
             r._resolve(out.get(ticket), error, outcome=outcome)
             with stats.lock:
@@ -816,26 +806,6 @@ class SearchServer:
                 tracer.end_span(r.trace, t1=t_res,
                                 args={"outcome": r.outcome})
                 r.trace = None
-
-    def _update_roofline(self, n_queries: int, flush_s: float) -> None:
-        """Refresh the live roofline gauges from one measured exact flush
-        (``repro.roofline.search``): predicted HBM bytes for this corpus
-        + batch, the memory-bound time prediction, and the gap."""
-        try:
-            n = getattr(self.searcher, "n", None)
-            if n is None:
-                n = self.searcher.index.n
-            cost = exact_scan_cost(int(n), int(self.searcher.spec.words),
-                                   n_queries, topk=self.topk)
-            gap = roofline_gap(cost["bytes"], flush_s)
-        except (AttributeError, ValueError):
-            return                       # searcher without n/words, dt=0
-        g = self._g_roofline
-        g["bytes"].set(cost["bytes"])
-        g["predicted_s"].set(gap["predicted_s"])
-        g["measured_s"].set(flush_s)
-        g["gap"].set(gap["gap"])
-        g["gbps"].set(gap["achieved_gbps"])
 
 
 # ---------------------------------------------------------------------------

@@ -1,18 +1,20 @@
-"""Lightweight span tracing for the serving request path.
+"""Lightweight span tracing for the serving request path and the
+preprocessing pipeline.
 
 Answers "where did this request's 40 ms go": every admitted request
 grows a span tree -- admission -> queue wait -> worker flush -> the
 mesh ``shard_map`` dispatch -> ``merge_topk`` -> resolution -- and the
 whole buffer exports as Chrome trace-event JSON, loadable directly in
-Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.
+Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.  The
+preprocessing pipeline opens one span per chunk and phase (``prep.*``,
+``repro.data.pipeline`` and ``repro.data.preprocess``).
 
 Design constraints, in order:
 
   1. **Off the hot path when disabled.**  The tracer ships disabled;
      every entry point checks ``enabled`` first and returns a shared
-     no-op span, so an untraced server pays one attribute read per
-     would-be span (the serving benchmark pins total instrumentation
-     overhead < 2%).
+     no-op span, so an untraced caller pays one attribute read per
+     would-be span.
   2. **Tear-free under concurrent workers.**  Span ids come from one
      atomic counter; parent linkage is explicit (``parent=``) or via a
      *thread-local* span stack (``span()`` context manager), so two
@@ -33,10 +35,13 @@ thread's track.  Every event carries ``span_id`` / ``parent_id`` /
 ``trace_id`` in ``args``, so the tree is machine-checkable
 (``tools/check_obs.py``) independent of the rendering.
 
-``jax_annotation()`` optionally brackets a region with
-``jax.profiler.TraceAnnotation`` so server flushes line up with device
-ops inside a captured ``jax.profiler`` trace; it is a no-op unless
-``jax_annotations=True`` AND the profiler import succeeds.
+Profiler clock: with ``jax_annotations=True`` every live span -- one
+opened by ``span()`` or ``phase()`` -- also opens a
+``jax.profiler.TraceAnnotation`` of the same name on the calling thread
+for its lifetime, so inside a captured ``jax.profiler`` trace it lands
+on the host plane on the device ops' clock.  Retroactive spans
+(``add_span``, ``start_span``/``end_span`` with explicit times) cannot
+be backdated into the profiler and stay in the Chrome buffer only.
 """
 
 from __future__ import annotations
@@ -137,7 +142,9 @@ class Tracer:
     def span(self, name: str, *, args: Optional[dict] = None,
              parent: Optional[Span] = None,
              kind: str = "thread") -> Iterator[Span]:
-        """Context-managed span, nested via this thread's span stack."""
+        """Context-managed span, nested via this thread's span stack;
+        on the profiler's clock too with ``jax_annotations`` (under its
+        plain name: ``args`` stay in the Chrome buffer)."""
         if not self.enabled:
             yield _NULL_SPAN
             return
@@ -145,7 +152,9 @@ class Tracer:
         stack = self._stack()
         stack.append(sp)
         try:
-            yield sp
+            with (_annotation(name) if self.jax_annotations
+                  else contextlib.nullcontext()):
+                yield sp
         finally:
             stack.pop()
             self.end_span(sp)
@@ -156,7 +165,8 @@ class Tracer:
                  args: Optional[dict] = None,
                  kind: str = "thread") -> None:
         """Record an already-elapsed interval (e.g. a request's queue
-        wait, only known when its batch pops)."""
+        wait, only known when its batch pops).  Chrome buffer only: the
+        profiler cannot take a span after the fact."""
         if not self.enabled:
             return
         sp = self.start_span(name, parent=parent, trace_id=trace_id,
@@ -186,20 +196,6 @@ class Tracer:
         phases = getattr(self._tls, "phases", None)
         self._tls.phases = []
         return phases or []
-
-    @contextlib.contextmanager
-    def jax_annotation(self, name: str):
-        """``jax.profiler.TraceAnnotation`` bracket (opt-in no-op)."""
-        if not (self.enabled and self.jax_annotations):
-            yield
-            return
-        try:
-            from jax.profiler import TraceAnnotation
-        except ImportError:
-            yield
-            return
-        with TraceAnnotation(name):
-            yield
 
     # -- the Chrome trace-event buffer ------------------------------------
     def _us(self, t: float) -> float:
@@ -254,6 +250,16 @@ class Tracer:
             self._epoch = time.monotonic()
         if enabled is not None:
             self.enabled = enabled
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` (a null context
+    where JAX is not installed)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return contextlib.nullcontext()
+    return TraceAnnotation(name)
 
 
 def request_tree(events: List[dict]) -> Dict[int, List[dict]]:

@@ -18,12 +18,6 @@ On the CPU dry-run host the measured bandwidth is nowhere near the TPU
 constant, so the gap is large and only its TRAJECTORY is meaningful;
 on real hardware the same artifact becomes an absolute utilization
 number.  Pass ``bw=`` to re-anchor.
-
-Besides the offline benchmark artifact, these terms also feed the LIVE
-``serve_roofline_*`` gauges: ``SearchServer`` calls ``exact_scan_cost``
-/ ``roofline_gap`` after every un-degraded exact flush and publishes
-predicted bytes/seconds, measured seconds, the gap ratio, and achieved
-GB/s through ``repro.obs.metrics`` (scrape via ``--metrics-port``).
 """
 
 from __future__ import annotations

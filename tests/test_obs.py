@@ -190,6 +190,41 @@ def test_bounded_buffer_counts_drops():
     assert tr.dropped > 0
 
 
+@pytest.mark.parametrize("enabled,annotations,want", [
+    (True, True, ["outer", "inner", "mesh_dispatch"]),
+    (True, False, []),
+    (False, True, []),
+])
+def test_live_spans_open_one_profiler_annotation_each(monkeypatch, enabled,
+                                                      annotations, want):
+    """Live spans (``span``, ``phase``) reach the profiler under their
+    plain names, args left out; retroactive spans cannot and do not; a
+    disabled tracer builds no annotation at all."""
+    made = []
+
+    class Counting:
+        def __init__(self, name, **kwargs):
+            made.append((name, kwargs))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    tr = Tracer(enabled=enabled, jax_annotations=annotations)
+    with tr.span("outer", args={"batch": 3}):
+        with tr.span("inner"):
+            pass
+    with tr.phase("mesh_dispatch", args={"mode": "exact"}):
+        pass
+    tr.add_span("queue", 0.0, 1.0)
+    tr.end_span(tr.start_span("request", kind="async"))
+    assert made == [(name, {}) for name in want]
+    assert len(tr.events()) == (6 if enabled else 0)
+
+
 # ---------------------------------------------------------------------------
 # HTTP exporter
 # ---------------------------------------------------------------------------
@@ -325,15 +360,43 @@ def test_counter_totals_identical_across_worker_counts(small_index):
         assert np.array_equal(np.asarray(a.scores), np.asarray(b.scores))
 
 
-def test_server_exports_roofline_and_occupancy(small_index):
+def test_server_exports_occupancy_and_queue_depth(small_index):
     index, searcher = small_index
     reg, _, _, _, _srv = _drive_traced(searcher, index, workers=2)
     vals = reg.values()
-    assert vals["serve_roofline_predicted_bytes"] > 0
-    assert vals["serve_roofline_gap"] > 0
     assert 'serve_worker_occupancy{worker="0"}' in vals
     assert 'serve_worker_occupancy{worker="1"}' in vals
     assert vals["serve_queue_depth"] == 0.0    # drained at close
+
+
+@pytest.mark.parametrize("routed", [False, True])
+def test_searcher_spans_go_to_the_servers_tracer(small_index, routed):
+    """The searcher's ``search_dispatch`` and ``flush:split`` and a
+    router's phases land on the tracer the server was given, never on
+    the process-wide default; the server adds one ``flush:submit`` and
+    one ``flush:w<i>`` per flush."""
+    from repro.index import ShardedIndex
+
+    index, searcher = small_index
+    if routed:
+        searcher = ShardedIndex([index])
+    get_tracer().reset(enabled=True)
+    reg, tr, ids, _, srv = _drive_traced(searcher, index, workers=1)
+    assert get_tracer().events() == []
+    names = collections.Counter(e["name"] for e in tr.events()
+                                if e["ph"] == "X")
+    flushes = srv.stats.batches
+    for name in ("flush:submit", "flush:w0", "search_dispatch",
+                 "flush:split", "worker_flush"):
+        assert names[name] == flushes, name
+    phases = {"shard_dispatch", "harvest", "merge"}
+    assert all(names[p] == (flushes if routed else 0) for p in phases)
+    trees = request_tree(tr.events())
+    trees.pop(0, None)
+    assert len(trees) == len(ids)
+    for evs in trees.values():
+        replayed = {e["name"] for e in evs if e["ph"] == "b"}
+        assert (phases <= replayed) == routed
 
 
 def test_trace_counts_alias_still_behaves_like_the_old_dict(small_index):
@@ -361,8 +424,8 @@ def test_trace_counts_alias_still_behaves_like_the_old_dict(small_index):
 def test_mesh_serving_scrape_and_trace(host_devices, tmp_path):
     """ISSUE 9 acceptance: a seeded serving run on the device mesh with
     4 workers yields a Prometheus scrape carrying queue-depth,
-    shed/degraded, per-worker occupancy, mesh dispatch counters, and
-    roofline gauges; a trace whose request trees cover
+    shed/degraded, per-worker occupancy and mesh dispatch counters; a
+    trace whose request trees cover
     admission→flush→dispatch→merge and partition the latency (±5%); and
     bit-identical results vs direct search()."""
     from repro.launch.mesh import make_debug_mesh
@@ -405,7 +468,7 @@ def test_mesh_serving_scrape_and_trace(host_devices, tmp_path):
     for text in (live, final):
         for name in ("serve_queue_depth", "serve_shed_total",
                      "serve_degraded_total", "serve_worker_occupancy",
-                     "index_mesh_dispatches_total", "serve_roofline_gap"):
+                     "index_mesh_dispatches_total"):
             assert name in text, f"{name} missing from scrape"
     assert 'index_mesh_dispatches_total{mode="exact"}' in final
     assert reg.values()["index_mesh_dispatches_total{mode=\"exact\"}"] > 0

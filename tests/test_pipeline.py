@@ -204,3 +204,58 @@ def test_chunk_contents_pinned(tmp_path, n, chunk_size):
         np.testing.assert_array_equal(np.asarray(c.labels),
                                       labels[pos:pos + c.n])
         pos += c.n
+
+
+# ---------------------------------------------------------------------------
+# Spans of the preprocessing pipeline
+# ---------------------------------------------------------------------------
+
+def _preprocess(tmp_path, tracer, out="sig"):
+    import jax
+    from repro.core.hashing import Hash2U
+    from repro.data.preprocess import preprocess_shards
+    sets, labels = _toy_sets(64, seed=5)
+    raw = tmp_path / "raw"
+    if not raw.exists():
+        write_shards(sets, labels, str(raw), n_shards=2)    # 32 rows each
+    paths = sorted(str(p) for p in raw.iterdir())
+    fam = Hash2U.create(jax.random.PRNGKey(0), 64, 10)
+    return preprocess_shards(paths, str(tmp_path / out), fam, b=4,
+                             chunk_size=32, tracer=tracer,
+                             loader_kwargs={"lane_multiple": 8})
+
+
+def test_preprocess_spans_one_set_per_chunk(tmp_path):
+    """The loader thread reads, pads and uploads once per chunk (one
+    shard here); the caller waits, hashes and stores once per chunk and
+    waits once more for the end of the stream."""
+    import threading
+    from repro.obs.trace import Tracer
+    tr = Tracer(enabled=True, jax_annotations=True)
+    stats = _preprocess(tmp_path, tr)
+    chunks = 2
+    assert stats.examples == 64
+    me = threading.get_ident()
+    seen = {}
+    for e in tr.events():
+        seen.setdefault(e["name"], []).append(e["tid"])
+    caller = {"prep.wait": chunks + 1, "prep.hash": chunks,
+              "prep.store": chunks}
+    loader = {"prep.read": chunks, "prep.pad": chunks,
+              "prep.upload": chunks}
+    assert {n: len(t) for n, t in seen.items()} == {**caller, **loader}
+    assert all(set(seen[n]) == {me} for n in caller)
+    loader_tids = {t for n in loader for t in seen[n]}
+    assert len(loader_tids) == 1 and me not in loader_tids
+
+
+def test_sig_output_identical_with_tracing_on_and_off(tmp_path):
+    from repro.obs.trace import Tracer
+    _preprocess(tmp_path, Tracer(enabled=True, jax_annotations=True), "on")
+    _preprocess(tmp_path, Tracer(enabled=False), "off")
+    on = sorted((tmp_path / "on").iterdir())
+    off = sorted((tmp_path / "off").iterdir())
+    assert [p.name for p in on] == [p.name for p in off] == [
+        "sig_00000.sig", "sig_00001.sig"]
+    for a, b in zip(on, off):
+        assert a.read_bytes() == b.read_bytes()
