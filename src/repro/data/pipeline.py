@@ -4,16 +4,23 @@ Responsibilities:
   * on-disk shard format(s): LibSVM-style text and binary .npz -- the paper
     notes binary loading is ~5x faster than text (§3.7 Table 2 caption, §6.1);
     both are implemented so benchmarks can reproduce that ratio,
-  * chunked iteration: yield SparseBatch chunks of ``chunk_size`` sets,
+  * chunked iteration: yield SparseBatch chunks of ``chunk_size`` sets.  A
+    shard travels as flat CSR ``(flat, offsets, labels)`` from the file to
+    the padded chunk: a binary shard whose members are stored is read
+    with one ``readinto`` (into a buffer reused within a pass) and its
+    members viewed in place, not decoded, and each id is then copied
+    once, into the padded chunk,
   * double-buffered background prefetch (overlap load with compute),
   * worker shard assignment + straggler mitigation: a shard read that
     exceeds its deadline is retried and, on repeated failure, reassigned to
     the next healthy worker (bookkeeping mirrors what a real multi-host
     data service does; on one host the "workers" are reader threads),
   * load-time accounting consumed by the online-learning benchmarks,
-  * spans on the loader thread, one per shard read (``prep.read``) and
-    one per chunk for the padded layout (``prep.pad``) and its hand-off
-    to the device (``prep.upload``), on the ``repro.obs`` tracer.
+  * spans on the loader thread, one per shard read (``prep.read``: the
+    whole file's ``readinto``, or the decode of a shard whose members are
+    not stored) and one per chunk for the padded layout (``prep.pad``:
+    the one copy of the ids and the mask) and its hand-off to the device
+    (``prep.upload``), on the ``repro.obs`` tracer.
 
 The prefetch (``prefetch_iter``) and retry (``read_with_retries``)
 machinery is shared with the signature-cache replay path in
@@ -24,18 +31,21 @@ story as raw-shard epochs.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import queue
 import random
+import struct
 import tempfile
 import threading
 import time
-from typing import Iterator, List, Optional, Sequence
+import zipfile
+from typing import Iterator, List, NamedTuple, Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
 
-from repro.data.sparse import SparseBatch, pad_lists
+from repro.data.sparse import SparseBatch, pad_csr_parts
 from repro.obs.trace import Tracer, get_tracer
 
 
@@ -72,10 +82,121 @@ def write_shard_binary(path: str, sets: Sequence[np.ndarray], labels: np.ndarray
 
 
 def read_shard_binary(path: str):
-    with np.load(path) as z:
-        flat, offsets, labels = z["indices"], z["offsets"], z["labels"]
+    flat, offsets, labels = _decode_npz(path)[:3]
     sets = [flat[offsets[i]:offsets[i + 1]] for i in range(len(labels))]
     return sets, labels
+
+
+class CsrShard(NamedTuple):
+    """One shard as flat CSR: row ``i`` is
+    ``flat[offsets[i]:offsets[i + 1]]``.  ``mapped`` says the arrays are
+    views of the members in place, in ``buffer``, one host buffer that
+    holds the whole file, rather than decoded copies."""
+
+    flat: np.ndarray
+    offsets: np.ndarray
+    labels: np.ndarray
+    mapped: bool
+    buffer: Optional[np.ndarray] = None
+
+
+_CSR_MEMBERS = ("indices", "offsets", "labels")
+_ZIP_LOCAL_HEADER = struct.Struct("<4sHHHHHIIIHH")
+_ALIGN = 64
+
+
+def _decode_npz(path: str) -> CsrShard:
+    with np.load(path) as z:
+        return CsrShard(*(z[m] for m in _CSR_MEMBERS), mapped=False)
+
+
+def _stored_npy(f, info: zipfile.ZipInfo):
+    """(dtype, shape, data offset in the file) of an ``.npz`` member
+    that can be viewed in place: stored, not compressed or encrypted,
+    and a C-order ``.npy`` (format 1.0 or 2.0) of little-endian items
+    whose data fills the member.  None otherwise."""
+    if info.compress_type != zipfile.ZIP_STORED or info.flag_bits & 0x1:
+        return None
+    f.seek(info.header_offset)
+    head = _ZIP_LOCAL_HEADER.unpack(f.read(_ZIP_LOCAL_HEADER.size))
+    if head[0] != b"PK\x03\x04":
+        return None
+    start = info.header_offset + _ZIP_LOCAL_HEADER.size + head[9] + head[10]
+    f.seek(start)
+    fmt = np.lib.format
+    try:
+        header = {(1, 0): fmt.read_array_header_1_0,
+                  (2, 0): fmt.read_array_header_2_0}.get(fmt.read_magic(f))
+        if header is None:
+            return None
+        shape, fortran, dtype = header(f)
+    except ValueError:
+        return None
+    if fortran or dtype.hasobject or dtype.byteorder == ">":
+        return None
+    data = f.tell()
+    if data - start + dtype.itemsize * int(np.prod(shape)) != info.file_size:
+        return None
+    return dtype, shape, data
+
+
+def _read_buffer(nbytes: int, spare: Optional[List[np.ndarray]]):
+    """A uint8 buffer of at least ``nbytes``: the first big enough one
+    popped from ``spare`` (smaller ones are dropped), else a new one."""
+    while spare:
+        try:
+            buf = spare.pop()
+        except IndexError:      # emptied by a concurrent pass
+            break
+        if buf.size >= nbytes:
+            return buf
+    return np.empty(nbytes, np.uint8)
+
+
+def read_shard_csr(path: str,
+                   spare: Optional[List[np.ndarray]] = None) -> CsrShard:
+    """A binary shard as flat CSR.
+
+    Where every member is stored (``np.savez``, ``write_shard_binary``)
+    the whole file is read with one ``readinto`` into one buffer, placed
+    so that the ids start on a 64-byte boundary, and the arrays are
+    views of their members there: no per-member copy, no CRC pass.  The
+    buffer comes from ``spare``, a free list of buffers whose views the
+    caller no longer reads, when one is big enough (a fresh buffer costs
+    a page fault per page), else it is new.  All of the shard's I/O
+    happens inside this call.  Any other ``.npz``
+    (``np.savez_compressed``, a big-endian or Fortran-order member) is
+    decoded through ``np.load``, which gives the same arrays."""
+    with open(path, "rb") as f:
+        try:
+            with zipfile.ZipFile(f) as zf:
+                infos = {i.filename: i for i in zf.infolist()}
+        except zipfile.BadZipFile:
+            return _decode_npz(path)
+        layout = [infos.get(m + ".npy") for m in _CSR_MEMBERS]
+        layout = [None if i is None else _stored_npy(f, i) for i in layout]
+        if None in layout:
+            return _decode_npz(path)
+        size = os.fstat(f.fileno()).st_size
+        raw = _read_buffer(size + _ALIGN, spare)
+        shift = -(raw.ctypes.data + layout[0][2]) % _ALIGN
+        buf = raw[shift:shift + size]
+        f.seek(0)
+        got = f.readinto(buf)
+    if got != size:
+        raise OSError(f"short read of {path}: {got} of {size} bytes")
+    arrays = [buf[off:off + dtype.itemsize * int(np.prod(shape))]
+              .view(dtype).reshape(shape) for dtype, shape, off in layout]
+    return CsrShard(*arrays, mapped=True, buffer=raw)
+
+
+def read_shard_csr_libsvm(path: str) -> CsrShard:
+    """A LibSVM text shard as flat CSR (decoded; ids int64)."""
+    sets, labels = read_shard_libsvm(path)
+    offsets = np.zeros(len(sets) + 1, np.int64)
+    np.cumsum([len(s) for s in sets], out=offsets[1:])
+    flat = np.concatenate(sets) if sets else np.zeros((0,), np.int64)
+    return CsrShard(flat, offsets, labels, mapped=False)
 
 
 def write_shards(batch_sets: Sequence[np.ndarray], labels: np.ndarray,
@@ -105,6 +226,8 @@ class LoaderStats:
     straggler_retries: int = 0
     shard_reassignments: int = 0
     io_errors: int = 0
+    mapped_reads: int = 0
+    decoded_reads: int = 0
 
 
 # LoaderStats field -> (metric name, help); every field is monotone, so
@@ -120,6 +243,10 @@ _LOADER_METRICS = {
                             "slow reads kept after exhausted retries"),
     "io_errors": ("data_loader_io_errors_total",
                   "OSErrors absorbed by the retry loop"),
+    "mapped_reads": ("data_loader_mapped_reads_total",
+                     "shards read whole, members viewed in place"),
+    "decoded_reads": ("data_loader_decoded_reads_total",
+                      "shards decoded into fresh arrays"),
 }
 
 
@@ -310,7 +437,11 @@ class ChunkedLoader:
         # examples per shard index, recorded as shards are read; lets a
         # consumer resume mid-stream (``resume_point`` + ``iter_from``)
         self.shard_examples: dict = {}
-        self._reader = read_shard_binary if fmt == "binary" else read_shard_libsvm
+        # read buffers no pending chunk views any more, reused by the
+        # next binary read of the same pass
+        self._spare: List[np.ndarray] = []
+        self._reader = (functools.partial(read_shard_csr, spare=self._spare)
+                        if fmt == "binary" else read_shard_csr_libsvm)
 
     # -- straggler-aware shard read ------------------------------------
     def _read_shard(self, path: str, worker: int):
@@ -322,43 +453,55 @@ class ChunkedLoader:
 
     def _chunk_iter(self, start_shard: int = 0,
                     skip_examples: int = 0) -> Iterator[SparseBatch]:
-        pending_sets: List[np.ndarray] = []
-        pending_labels: List[float] = []
-        # consume via a moving cursor instead of re-slicing the remainder
-        # per chunk (pending = pending[chunk:] re-copied O(n) per yielded
-        # chunk -- O(n^2) for many small chunks per shard); the buffers
-        # compact once per shard, so each element moves at most twice
-        start = 0
+        # the pending chunk is a list of CSR pieces (flat, row offsets,
+        # labels), so a chunk may span shards; no row is split out.  A
+        # shard's read buffer goes back to ``_spare`` once no pending
+        # piece views it (``held``: buffers of earlier shards still in
+        # ``parts``)
+        parts: list = []
+        held: list = []
+        pending = 0
         skip = skip_examples
         for i in range(start_shard, len(self.shard_paths)):
             worker = i % self.n_workers
             with self.tracer.span("prep.read"):
-                sets, labels = self._read_shard(self.shard_paths[i], worker)
-            self.shard_examples[i] = len(sets)
-            if skip:
-                take = min(skip, len(sets))
-                sets, labels = sets[take:], labels[take:]
-                skip -= take
-            pending_sets.extend(sets)
-            pending_labels.extend(labels.tolist())
-            while len(pending_sets) - start >= self.chunk_size:
-                stop = start + self.chunk_size
-                yield self._make_batch(pending_sets[start:stop],
-                                       pending_labels[start:stop])
-                start = stop
-            if start:
-                del pending_sets[:start], pending_labels[:start]
-                start = 0
-        if pending_sets:
-            yield self._make_batch(pending_sets, pending_labels)
+                shard = self._read_shard(self.shard_paths[i], worker)
+            if shard.mapped:
+                self.stats.mapped_reads += 1
+            else:
+                self.stats.decoded_reads += 1
+            n = len(shard.labels)
+            self.shard_examples[i] = n
+            lo = min(skip, n)
+            skip -= lo
+            while lo < n:
+                take = min(n - lo, self.chunk_size - pending)
+                parts.append((shard.flat, shard.offsets[lo:lo + take + 1],
+                              shard.labels[lo:lo + take]))
+                pending += take
+                lo += take
+                if pending == self.chunk_size:
+                    yield self._make_batch(parts)
+                    parts, pending = [], 0
+                    self._spare.extend(held)
+                    held.clear()
+            if shard.buffer is not None:
+                in_parts = bool(parts) and parts[-1][0] is shard.flat
+                (held if in_parts else self._spare).append(shard.buffer)
+        if parts:
+            yield self._make_batch(parts)
+        self._spare.clear()
 
-    def _make_batch(self, sets, labels) -> SparseBatch:
-        """``from_lists`` in two spans: the host layout, then the
-        device hand-off (which only enqueues the copies)."""
+    def _make_batch(self, parts) -> SparseBatch:
+        """Pad the chunk's CSR pieces into fresh host arrays (no view of
+        a shard survives into the batch), then hand them to the device,
+        which only enqueues the copies."""
         self.stats.chunks += 1
         with self.tracer.span("prep.pad"):
-            idx, msk = pad_lists(sets, self.max_nnz, self.lane_multiple)
-            lab = np.asarray(labels, np.float32)
+            idx, msk = pad_csr_parts([(f, o) for f, o, _ in parts],
+                                     self.max_nnz, self.lane_multiple)
+            lab = np.concatenate([y for _, _, y in parts]
+                                 ).astype(np.float32, copy=False)
         with self.tracer.span("prep.upload"):
             return SparseBatch(indices=jnp.asarray(idx),
                                mask=jnp.asarray(msk),

@@ -54,22 +54,49 @@ def pad_to_multiple(x: np.ndarray, multiple: int, axis: int, value=0) -> np.ndar
     return np.pad(x, pad, constant_values=value)
 
 
+def _pad_rows(rows: Iterable[np.ndarray], lens: np.ndarray,
+              max_nnz: Optional[int],
+              lane_multiple: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The one padding rule: ``rows`` (1-D arrays, in order, of lengths
+    ``lens``) into indices (n, width) int32 and mask (n, width) bool,
+    width ``max_nnz`` (default: the longest row) rounded up to
+    ``lane_multiple``; longer rows are truncated.  Each id is copied
+    once, cast to int32 in that copy, into a fresh buffer; the mask is
+    one compare."""
+    if max_nnz is None:
+        max_nnz = int(lens.max(initial=0)) or 1
+    width = ((max_nnz + lane_multiple - 1) // lane_multiple) * lane_multiple
+    lens = np.minimum(lens, width).astype(np.int32)
+    idx = np.zeros((lens.size, width), np.int32)
+    for r, (row, m) in enumerate(zip(rows, lens.tolist())):
+        idx[r, :m] = row[:m]
+    return idx, np.arange(width, dtype=np.int32) < lens[:, None]
+
+
+def pad_csr_parts(parts: Sequence[Tuple[np.ndarray, np.ndarray]],
+                  max_nnz: Optional[int] = None,
+                  lane_multiple: int = 128) -> Tuple[np.ndarray, np.ndarray]:
+    """The padded host arrays straight from CSR pieces ``(flat,
+    offsets)``: row ``i`` of a piece is ``flat[offsets[i]:offsets[i +
+    1]]`` (``offsets`` need not start at 0), and the rows of the first
+    piece come first, then those of the next, under one width (the rule
+    of ``pad_lists``).  A chunk that spans two shards pads this way
+    without joining them first."""
+    offs = [np.asarray(o, np.int64) for _, o in parts]
+    lens = np.concatenate([np.diff(o) for o in offs] or [[]]).astype(np.int64)
+    rows = (flat[a:b] for (flat, _), o in zip(parts, offs)
+            for a, b in zip(o[:-1].tolist(), o[1:].tolist()))
+    return _pad_rows(rows, lens, max_nnz, lane_multiple)
+
+
 def pad_lists(sets: Sequence[np.ndarray], max_nnz: Optional[int] = None,
               lane_multiple: int = 128) -> Tuple[np.ndarray, np.ndarray]:
     """The padded host arrays of ``from_lists``: indices (n, width) int32
     and mask (n, width) bool, width ``max_nnz`` (default: the longest
     set) rounded up to ``lane_multiple``; longer sets are truncated."""
-    n = len(sets)
-    if max_nnz is None:
-        max_nnz = max((len(s) for s in sets), default=1) or 1
-    max_nnz = ((max_nnz + lane_multiple - 1) // lane_multiple) * lane_multiple
-    idx = np.zeros((n, max_nnz), np.int32)
-    msk = np.zeros((n, max_nnz), bool)
-    for i, s in enumerate(sets):
-        m = min(len(s), max_nnz)
-        idx[i, :m] = np.asarray(s[:m], np.int32)
-        msk[i, :m] = True
-    return idx, msk
+    rows = [np.asarray(s).reshape(-1) for s in sets]
+    return _pad_rows(rows, np.array([r.size for r in rows], np.int64),
+                     max_nnz, lane_multiple)
 
 
 def from_lists(sets: Sequence[np.ndarray], labels: Optional[np.ndarray] = None,

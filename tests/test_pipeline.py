@@ -1,17 +1,21 @@
 """Data pipeline: shard formats, chunked iteration, prefetch, stragglers."""
 
-import numpy as np
-import pytest
-
+import os
 import random
 import time
+import types
+
+import numpy as np
+import pytest
 
 from repro.data import TINY, generate
 from repro.data.pipeline import (ChunkedLoader, LoaderStats,
                                  make_sharded_dataset, read_shard_binary,
+                                 read_shard_csr, read_shard_csr_libsvm,
                                  read_shard_libsvm, read_with_retries,
                                  write_shard_binary, write_shard_libsvm,
                                  write_shards)
+from repro.data.sparse import pad_csr_parts, pad_lists
 
 
 def _toy_sets(n=50, seed=0):
@@ -83,8 +87,10 @@ def test_read_shard_oserror_accounted(tmp_path):
     chunks = list(loader)
     assert sum(c.n for c in chunks) == 20
     assert loader.stats.io_errors == 2
-    # the successful attempt is fully accounted (no silent re-read)
+    # the successful attempt is fully accounted (no silent re-read);
+    # only the kept read counts as a mapped read
     assert loader.stats.load_seconds > 0 and loader.stats.bytes_read > 0
+    assert (loader.stats.mapped_reads, loader.stats.decoded_reads) == (1, 0)
 
     # every attempt failing must surface the OSError, all attempts counted
     dead = ChunkedLoader(paths, chunk_size=20, prefetch=0, max_retries=1,
@@ -181,19 +187,18 @@ def test_binary_faster_than_text(tmp_path):
     assert tb < tt  # text parsing is slower
 
 
-@pytest.mark.parametrize("n,chunk_size", [(101, 25), (96, 16), (30, 64)])
-def test_chunk_contents_pinned(tmp_path, n, chunk_size):
-    """Chunk boundaries AND per-row set contents must equal slicing the
-    concatenated shard stream -- pins that the O(n) moving-cursor chunk
-    assembly (no per-chunk list re-copy) changed nothing observable."""
-    sets, labels = _toy_sets(n, seed=3)
-    paths = write_shards(sets, labels, str(tmp_path), n_shards=4)
-    loader = ChunkedLoader(paths, chunk_size=chunk_size, prefetch=0,
-                           lane_multiple=8)
-    chunks = list(loader)
-    sizes = [c.n for c in chunks]
-    assert sizes[:-1] == [chunk_size] * (len(chunks) - 1)
-    assert sum(sizes) == n
+def _shred(paths):
+    """Overwrite each shard in place with zeros, then delete it: data
+    still read lazily from the file (a map of it) would read zeros
+    afterwards."""
+    for p in paths:
+        size = os.path.getsize(p)
+        with open(p, "r+b") as f:
+            f.write(bytes(size))
+        os.remove(p)
+
+
+def _assert_rows(chunks, sets, labels):
     pos = 0
     for c in chunks:
         idx = np.asarray(c.indices)
@@ -204,6 +209,341 @@ def test_chunk_contents_pinned(tmp_path, n, chunk_size):
         np.testing.assert_array_equal(np.asarray(c.labels),
                                       labels[pos:pos + c.n])
         pos += c.n
+    assert pos == len(sets)
+
+
+@pytest.mark.parametrize("n,chunk_size", [(101, 25), (96, 16), (30, 64),
+                                          (101, 40)])
+def test_chunk_contents_pinned(tmp_path, monkeypatch, n, chunk_size):
+    """Chunk boundaries AND per-row set contents must equal slicing the
+    concatenated shard stream (chunks straddle shard boundaries: 101
+    rows are shards of 26, 26, 26 and 23), and no batch may view a
+    shard's buffer or file: no host array handed to the device shares
+    memory with a shard, and the files are shredded before the batches
+    are read."""
+    import jax.numpy as jnp
+    from repro.data import pipeline
+    sets, labels = _toy_sets(n, seed=3)
+    paths = write_shards(sets, labels, str(tmp_path), n_shards=4)
+    loader = ChunkedLoader(paths, chunk_size=chunk_size, prefetch=0,
+                           lane_multiple=8)
+    real_reader, shards, handed = loader._reader, [], []
+
+    def keep(path):
+        shards.append(real_reader(path))
+        return shards[-1]
+
+    def asarray(x, *a, **kw):
+        handed.append(x)
+        return jnp.asarray(x, *a, **kw)
+
+    loader._reader = keep
+    monkeypatch.setattr(pipeline, "jnp", types.SimpleNamespace(
+        asarray=asarray))
+    chunks = list(loader)
+    sizes = [c.n for c in chunks]
+    assert sizes[:-1] == [chunk_size] * (len(chunks) - 1)
+    assert sum(sizes) == n
+    assert loader.stats.mapped_reads == 4 and loader.stats.decoded_reads == 0
+    assert all(sh.mapped for sh in shards)
+    assert len(handed) == 3 * len(chunks)
+    for host in handed:
+        for sh in shards:
+            for view in (sh.flat, sh.offsets, sh.labels):
+                assert not np.shares_memory(host, view)
+    del shards[:], handed[:]
+    _shred(paths)
+    _assert_rows(chunks, sets, labels)
+
+
+@pytest.mark.parametrize("n,chunk_size,offset", [
+    (101, 25, 50),     # chunk-aligned, mid-shard
+    (101, 16, 37),     # unaligned, mid-shard
+    (101, 40, 78),     # on a shard boundary (shards of 26)
+    (101, 25, 101),    # past the end
+])
+def test_resume_contents_pinned(tmp_path, n, chunk_size, offset):
+    """``resume_point`` + ``iter_from`` (offset arithmetic on the CSR
+    offsets) yield exactly the stream's rows from ``offset`` on, cut
+    into ``chunk_size`` chunks from there; a chunk-aligned resume
+    reproduces the full pass's remaining chunks."""
+    sets, labels = _toy_sets(n, seed=4)
+    paths = write_shards(sets, labels, str(tmp_path), n_shards=4)
+    loader = ChunkedLoader(paths, chunk_size=chunk_size, prefetch=0,
+                           lane_multiple=8)
+    full = list(loader)
+    start, skip = loader.resume_point(offset)
+    tail = list(loader.iter_from(start, skip))
+    sizes = [c.n for c in tail]
+    assert sum(sizes) == n - offset
+    assert sizes[:-1] == [chunk_size] * (len(tail) - 1)
+    if offset % chunk_size == 0:
+        assert len(tail) == len(full) - offset // chunk_size
+        for a, b in zip(tail, full[offset // chunk_size:]):
+            np.testing.assert_array_equal(np.asarray(a.indices),
+                                          np.asarray(b.indices))
+    _shred(paths)
+    _assert_rows(tail, sets[offset:], labels[offset:])
+
+
+# ---------------------------------------------------------------------------
+# Flat-CSR shard reads and padding
+# ---------------------------------------------------------------------------
+
+def _csr(sets):
+    offsets = np.zeros(len(sets) + 1, np.int64)
+    np.cumsum([len(s) for s in sets], out=offsets[1:])
+    return np.concatenate(sets), offsets
+
+
+def _write_bench_style(path, sets, labels):
+    """The benchmark's raw shard: ``np.savez`` of int32 ids, int64
+    offsets, float32 labels."""
+    flat, offsets = _csr(sets)
+    np.savez(path, indices=flat.astype(np.int32), offsets=offsets,
+             labels=labels)
+
+
+def _write_compressed(path, sets, labels):
+    flat, offsets = _csr(sets)
+    np.savez_compressed(path, indices=flat, offsets=offsets, labels=labels)
+
+
+@pytest.mark.parametrize("writer,mapped", [
+    (_write_bench_style, True),
+    (write_shard_binary, True),
+    (_write_compressed, False),
+], ids=["bench-int32", "write_shard_binary", "savez_compressed"])
+def test_csr_reader_matches_read_shard_binary(tmp_path, writer, mapped):
+    sets, labels = _toy_sets(60, seed=6)
+    path = str(tmp_path / "s.npz")
+    writer(path, sets, labels)
+    want_sets, want_labels = read_shard_binary(path)
+    from repro.obs.metrics import get_registry
+    loader = ChunkedLoader([path], chunk_size=25, prefetch=0,
+                           lane_multiple=8)
+    _assert_rows(list(loader), sets, labels)
+    assert (loader.stats.mapped_reads, loader.stats.decoded_reads) == (
+        (1, 0) if mapped else (0, 1))
+    vals = get_registry().values()
+    assert vals['data_loader_mapped_reads_total{role="load"}'] == int(mapped)
+    assert vals['data_loader_decoded_reads_total{role="load"}'] == int(
+        not mapped)
+
+    # every byte is read inside the call: shredding the file afterwards
+    # changes nothing the reader returned
+    got = read_shard_csr(path)
+    _shred([path])
+    assert got.mapped is mapped
+    assert got.flat.dtype == want_sets[0].dtype
+    assert len(got.offsets) == len(want_sets) + 1
+    for i, want in enumerate(want_sets):
+        np.testing.assert_array_equal(
+            got.flat[got.offsets[i]:got.offsets[i + 1]], want)
+    np.testing.assert_array_equal(got.labels, want_labels)
+    assert got.labels.dtype == want_labels.dtype
+    if mapped:   # the ids' view is aligned in the read buffer
+        assert got.flat.flags.aligned and got.flat.ctypes.data % 64 == 0
+
+
+def test_csr_reader_short_read_retried(tmp_path, monkeypatch):
+    """A file that yields fewer bytes than its size raises ``OSError``
+    inside the reader, so ``read_with_retries`` counts and retries it."""
+    from repro.data import pipeline
+    sets, labels = _toy_sets(40, seed=9)
+    path = str(tmp_path / "s.npz")
+    _write_bench_style(path, sets, labels)
+    real_fstat, calls = os.fstat, []
+
+    def fstat(fd):
+        st = real_fstat(fd)
+        calls.append(fd)
+        if len(calls) > 1:
+            return st
+        return types.SimpleNamespace(st_size=st.st_size + 1)
+
+    monkeypatch.setattr(pipeline.os, "fstat", fstat)
+    with pytest.raises(OSError, match="short read"):
+        read_shard_csr(path)
+    calls.clear()
+    loader = ChunkedLoader([path], chunk_size=16, prefetch=0,
+                           lane_multiple=8, io_backoff_base_s=0.0)
+    chunks = list(loader)
+    assert loader.stats.io_errors == 1
+    assert (loader.stats.mapped_reads, loader.stats.decoded_reads) == (1, 0)
+    _assert_rows(chunks, sets, labels)
+
+
+@pytest.mark.parametrize("chunk_size,max_buffers", [
+    (12, 1),    # one chunk per shard: every read reuses the first buffer
+    (18, 2),    # chunks straddle two shards
+    (30, 3),    # a chunk views three shards at once
+])
+def test_read_buffers_recycled(tmp_path, chunk_size, max_buffers):
+    """A pass over equal-size shards reads into at most as many buffers
+    as a pending chunk views at once, and the recycling changes no row."""
+    rng = np.random.default_rng(10)
+    lens = rng.integers(3, 30, size=12)
+    sets, labels, paths = [], [], []
+    for i in range(6):
+        rows = [rng.choice(1000, size=n, replace=False) for n in lens]
+        lab = rng.choice([-1.0, 1.0], 12).astype(np.float32)
+        paths.append(str(tmp_path / f"s{i}.npz"))
+        _write_bench_style(paths[-1], rows, lab)
+        sets += rows
+        labels.append(lab)
+    labels = np.concatenate(labels)
+    loader = ChunkedLoader(paths, chunk_size=chunk_size, prefetch=0,
+                           lane_multiple=8)
+    real_reader, buffers = loader._reader, set()
+
+    def note(path):
+        shard = real_reader(path)
+        buffers.add(shard.buffer.ctypes.data)
+        return shard
+
+    loader._reader = note
+    _assert_rows(list(loader), sets, labels)
+    assert 1 <= len(buffers) <= max_buffers
+    assert loader._spare == []
+
+
+def test_read_buffers_concurrent_passes(tmp_path):
+    """Passes over one loader in several threads at once share its spare
+    read buffers; every pass still yields exactly the stream's rows."""
+    import sys
+    import threading
+    rng = np.random.default_rng(11)
+    sets, labels, paths = [], [], []
+    for i in range(6):
+        rows = [rng.choice(1000, size=n, replace=False)
+                for n in rng.integers(3, 30, size=12)]
+        lab = rng.choice([-1.0, 1.0], 12).astype(np.float32)
+        paths.append(str(tmp_path / f"s{i}.npz"))
+        _write_bench_style(paths[-1], rows, lab)
+        sets += rows
+        labels.append(lab)
+    labels = np.concatenate(labels)
+    loader = ChunkedLoader(paths, chunk_size=18, prefetch=0,
+                           lane_multiple=8)
+    results, errors = {}, []
+
+    def run(k):
+        try:
+            for _ in range(5):
+                results[k] = [(np.asarray(c.indices), np.asarray(c.mask),
+                               np.asarray(c.labels)) for c in loader]
+                _assert_rows([types.SimpleNamespace(
+                    indices=i, mask=m, labels=y, n=len(y))
+                    for i, m, y in results[k]], sets, labels)
+        except Exception as e:   # surfaced by the main thread
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=run, args=(k,))
+                   for k in range(2 * (os.cpu_count() or 2))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert errors == []
+    assert len(results) == len(threads)
+
+
+def _write_odd_member(path, sets, labels, odd):
+    flat, offsets = _csr(sets)
+    if odd == "big-endian":
+        flat = flat.astype(">i4")
+    else:                       # a Fortran-order (2-D) label member
+        labels = np.asfortranarray(np.stack([labels, labels], 1))
+    np.savez(path, indices=flat, offsets=offsets, labels=labels)
+
+
+@pytest.mark.parametrize("odd", ["big-endian", "fortran"])
+def test_csr_reader_odd_member_decoded(tmp_path, odd):
+    """A stored member the reader does not view in place (big-endian
+    ids, a Fortran-order array) goes through ``np.load`` and reads the
+    same as ``read_shard_binary``."""
+    sets, labels = _toy_sets(20, seed=7)
+    path = str(tmp_path / "odd.npz")
+    _write_odd_member(path, sets, labels, odd)
+    want_sets, want_labels = read_shard_binary(path)
+    got = read_shard_csr(path)
+    assert not got.mapped
+    for i, want in enumerate(want_sets):
+        np.testing.assert_array_equal(
+            got.flat[got.offsets[i]:got.offsets[i + 1]], want)
+    np.testing.assert_array_equal(got.labels, want_labels)
+    if odd == "big-endian":
+        loader = ChunkedLoader([path], chunk_size=8, prefetch=0,
+                               lane_multiple=8)
+        _assert_rows(list(loader), sets, labels)
+        assert loader.stats.decoded_reads == 1
+
+
+def test_csr_libsvm_reader_matches(tmp_path):
+    sets, labels = _toy_sets(30, seed=8)
+    path = str(tmp_path / "s.txt")
+    write_shard_libsvm(path, sets, labels)
+    want_sets, want_labels = read_shard_libsvm(path)
+    got = read_shard_csr_libsvm(path)
+    assert not got.mapped
+    for i, want in enumerate(want_sets):
+        np.testing.assert_array_equal(
+            got.flat[got.offsets[i]:got.offsets[i + 1]], want)
+    np.testing.assert_array_equal(got.labels, want_labels)
+
+
+def _pad_rowwise(sets, max_nnz, lane_multiple):
+    """The padding rule written out row by row (independent of
+    ``pad_csr_parts``)."""
+    if max_nnz is None:
+        max_nnz = max((len(s) for s in sets), default=1) or 1
+    width = -(-max_nnz // lane_multiple) * lane_multiple
+    idx = np.zeros((len(sets), width), np.int32)
+    msk = np.zeros((len(sets), width), bool)
+    for i, s in enumerate(sets):
+        m = min(len(s), width)
+        idx[i, :m] = np.asarray(s[:m], np.int32)
+        msk[i, :m] = True
+    return idx, msk
+
+
+def _rows(lens, dtype=np.int32, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 1 << 24, size=n).astype(dtype) for n in lens]
+
+
+@pytest.mark.parametrize("sets,max_nnz,lane", [
+    (_rows([0, 5, 0, 3]), None, 8),              # empty rows
+    (_rows([0, 0]), None, 8),                    # only empty rows
+    (_rows([40, 7, 64]), 16, 8),                 # truncation at max_nnz
+    (_rows([128, 3]), None, 128),                # width on a lane multiple
+    (_rows([129, 3]), None, 128),                # just past one
+    (_rows([17, 9, 0, 33], np.int64), None, 8),  # int64 ids
+    (_rows([17, 9, 33], np.int64), 20, 8),       # int64, truncated
+], ids=["empty-rows", "all-empty", "truncate", "on-lane", "past-lane",
+        "int64", "int64-truncate"])
+def test_pad_csr_matches_pad_lists(sets, max_nnz, lane):
+    flat, offsets = _csr(sets)
+    want = _pad_rowwise(sets, max_nnz, lane)
+    got_csr = pad_csr_parts([(flat, offsets)], max_nnz, lane)
+    got_lists = pad_lists(sets, max_nnz, lane)
+    # a window of a larger CSR array (offsets not from 0), in two pieces
+    pre = np.arange(11, dtype=flat.dtype)
+    mid = len(sets) // 2
+    big = np.concatenate([pre, flat])
+    got_parts = pad_csr_parts([(big, offsets[:mid + 1] + 11),
+                               (big, offsets[mid:] + 11)], max_nnz, lane)
+    for got in (got_csr, got_lists, got_parts):
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
 
 
 # ---------------------------------------------------------------------------
