@@ -60,7 +60,7 @@ def run() -> list[Row]:
         raw = make_sharded_dataset(spec, os.path.join(tmp, "raw"),
                                    n_shards=4)
         preprocess_shards(raw, os.path.join(tmp, "sig"), fam, b=B,
-                          chunk_size=256, loader_kwargs={"lane_multiple": 8})
+                          chunk_size=256)
         sig_paths = sorted(glob.glob(os.path.join(tmp, "sig", "*.sig")))
         cfg = choose_band_config(K, B, threshold=THRESHOLD)
 

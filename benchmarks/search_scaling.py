@@ -77,8 +77,7 @@ def _build_corpus(tmp: str, n: int):
     # chunk small enough that every corpus yields >= 8 .sig files, so
     # the largest SHARD_COUNTS row is buildable (file-granularity split)
     preprocess_shards(raw, os.path.join(tmp, f"sig{n}"), fam, b=B,
-                      chunk_size=max(64, n // 16),
-                      loader_kwargs={"lane_multiple": 8})
+                      chunk_size=max(64, n // 16))
     return sorted(glob.glob(os.path.join(tmp, f"sig{n}", "*.sig")))
 
 

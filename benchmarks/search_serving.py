@@ -107,8 +107,7 @@ def _build_sigs(tmp: str, name: str, n: int, seed: int) -> list:
     raw = make_sharded_dataset(spec, os.path.join(tmp, f"raw_{name}"),
                                n_shards=4)
     preprocess_shards(raw, os.path.join(tmp, f"sig_{name}"), fam, b=B,
-                      chunk_size=max(128, n // 4),
-                      loader_kwargs={"lane_multiple": 8})
+                      chunk_size=max(128, n // 4))
     return sorted(glob.glob(os.path.join(tmp, f"sig_{name}", "*.sig")))
 
 

@@ -4,12 +4,14 @@ Responsibilities:
   * on-disk shard format(s): LibSVM-style text and binary .npz -- the paper
     notes binary loading is ~5x faster than text (§3.7 Table 2 caption, §6.1);
     both are implemented so benchmarks can reproduce that ratio,
-  * chunked iteration: yield SparseBatch chunks of ``chunk_size`` sets.  A
-    shard travels as flat CSR ``(flat, offsets, labels)`` from the file to
-    the padded chunk: a binary shard whose members are stored is read
-    with one ``readinto`` (into a buffer reused within a pass) and its
-    members viewed in place, not decoded, and each id is then copied
-    once, into the padded chunk,
+  * chunked iteration: yield ``SegmentedBatch`` chunks of ``chunk_size``
+    sets.  A shard travels as flat CSR ``(flat, offsets, labels)`` from
+    the file to the chunk: a binary shard whose members are stored is
+    read with one ``readinto`` (into a buffer reused within a pass) and
+    its members viewed in place, not decoded, and each id is then copied
+    once, into the chunk's fixed-width segments
+    (``repro.data.sparse.segment_csr_parts``), so a heavy-tailed chunk
+    costs about its real ids, not its longest row times its rows,
   * double-buffered background prefetch (overlap load with compute),
   * worker shard assignment + straggler mitigation: a shard read that
     exceeds its deadline is retried and, on repeated failure, reassigned to
@@ -18,9 +20,9 @@ Responsibilities:
   * load-time accounting consumed by the online-learning benchmarks,
   * spans on the loader thread, one per shard read (``prep.read``: the
     whole file's ``readinto``, or the decode of a shard whose members are
-    not stored) and one per chunk for the padded layout (``prep.pad``:
-    the one copy of the ids and the mask) and its hand-off to the device
-    (``prep.upload``), on the ``repro.obs`` tracer.
+    not stored) and one per chunk for the segmented layout (``prep.pad``:
+    the one copy of the ids and the per-segment counts) and its hand-off
+    to the device (``prep.upload``), on the ``repro.obs`` tracer.
 
 The prefetch (``prefetch_iter``) and retry (``read_with_retries``)
 machinery is shared with the signature-cache replay path in
@@ -45,7 +47,7 @@ from typing import Iterator, List, NamedTuple, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from repro.data.sparse import SparseBatch, pad_csr_parts
+from repro.data.sparse import SegmentedBatch, SparseBatch, segment_csr_parts
 from repro.obs.trace import Tracer, get_tracer
 
 
@@ -228,6 +230,8 @@ class LoaderStats:
     io_errors: int = 0
     mapped_reads: int = 0
     decoded_reads: int = 0
+    nonzeros: int = 0
+    slots: int = 0
 
 
 # LoaderStats field -> (metric name, help); every field is monotone, so
@@ -247,6 +251,11 @@ _LOADER_METRICS = {
                      "shards read whole, members viewed in place"),
     "decoded_reads": ("data_loader_decoded_reads_total",
                       "shards decoded into fresh arrays"),
+    "nonzeros": ("data_loader_nonzeros_total",
+                 "real ids laid out in chunks"),
+    "slots": ("data_loader_slots_total",
+              "index slots laid out in chunks (segments x width), "
+              "what the signature kernels hash"),
 }
 
 
@@ -401,7 +410,7 @@ def device_put_iter(make_host_iter, prefetch: int = 2):
 
 
 class ChunkedLoader:
-    """Iterate SparseBatch chunks over a list of shard files.
+    """Iterate ``SegmentedBatch`` chunks over a list of shard files.
 
     ``n_workers`` reader threads each own a disjoint round-robin slice of
     shards.  A read exceeding ``straggler_deadline_s`` is retried
@@ -412,24 +421,21 @@ class ChunkedLoader:
     """
 
     def __init__(self, shard_paths: Sequence[str], chunk_size: int = 10_000,
-                 fmt: str = "binary", max_nnz: Optional[int] = None,
+                 fmt: str = "binary",
                  prefetch: int = 2, n_workers: int = 1,
                  straggler_deadline_s: float = 30.0, max_retries: int = 2,
                  io_backoff_base_s: float = 0.05,
                  io_backoff_cap_s: float = 1.0,
-                 lane_multiple: int = 128,
                  tracer: Optional[Tracer] = None):
         self.shard_paths = list(shard_paths)
         self.chunk_size = chunk_size
         self.fmt = fmt
-        self.max_nnz = max_nnz
         self.prefetch = prefetch
         self.n_workers = n_workers
         self.deadline = straggler_deadline_s
         self.max_retries = max_retries
         self.io_backoff_base_s = io_backoff_base_s
         self.io_backoff_cap_s = io_backoff_cap_s
-        self.lane_multiple = lane_multiple
         self.tracer = tracer if tracer is not None else get_tracer()
         self.stats = LoaderStats()
         from repro.obs.metrics import get_registry
@@ -452,7 +458,7 @@ class ChunkedLoader:
                                  backoff_cap_s=self.io_backoff_cap_s)
 
     def _chunk_iter(self, start_shard: int = 0,
-                    skip_examples: int = 0) -> Iterator[SparseBatch]:
+                    skip_examples: int = 0) -> Iterator[SegmentedBatch]:
         # the pending chunk is a list of CSR pieces (flat, row offsets,
         # labels), so a chunk may span shards; no row is split out.  A
         # shard's read buffer goes back to ``_spare`` once no pending
@@ -492,20 +498,23 @@ class ChunkedLoader:
             yield self._make_batch(parts)
         self._spare.clear()
 
-    def _make_batch(self, parts) -> SparseBatch:
-        """Pad the chunk's CSR pieces into fresh host arrays (no view of
-        a shard survives into the batch), then hand them to the device,
-        which only enqueues the copies."""
+    def _make_batch(self, parts) -> SegmentedBatch:
+        """Lay the chunk's CSR pieces out as fixed-width segments in fresh
+        host arrays (no view of a shard survives into the batch), then
+        hand them to the device, which only enqueues the copies."""
         self.stats.chunks += 1
         with self.tracer.span("prep.pad"):
-            idx, msk = pad_csr_parts([(f, o) for f, o, _ in parts],
-                                     self.max_nnz, self.lane_multiple)
+            idx, counts, rows = segment_csr_parts(
+                [(f, o) for f, o, _ in parts])
             lab = np.concatenate([y for _, _, y in parts]
                                  ).astype(np.float32, copy=False)
+        self.stats.nonzeros += sum(int(o[-1] - o[0]) for _, o, _ in parts)
+        self.stats.slots += idx.size
         with self.tracer.span("prep.upload"):
-            return SparseBatch(indices=jnp.asarray(idx),
-                               mask=jnp.asarray(msk),
-                               labels=jnp.asarray(lab))
+            return SegmentedBatch(
+                indices=jnp.asarray(idx), counts=jnp.asarray(counts),
+                rows=None if rows is None else jnp.asarray(rows),
+                labels=jnp.asarray(lab), n=lab.size)
 
     def resume_point(self, example_offset: int):
         """Map a stream example offset -> (shard index, in-shard skip).
@@ -528,7 +537,7 @@ class ChunkedLoader:
         return len(self.shard_paths), 0
 
     def iter_from(self, start_shard: int = 0,
-                  skip_examples: int = 0) -> Iterator[SparseBatch]:
+                  skip_examples: int = 0) -> Iterator[SegmentedBatch]:
         """Iterate chunks starting at ``start_shard``, dropping the first
         ``skip_examples`` examples (same prefetch machinery as iteration
         from the top).  Chunk boundaries line up with a full pass when
@@ -538,7 +547,7 @@ class ChunkedLoader:
             lambda: self._chunk_iter(start_shard, skip_examples),
             self.prefetch)
 
-    def __iter__(self) -> Iterator[SparseBatch]:
+    def __iter__(self) -> Iterator[SegmentedBatch]:
         yield from self.iter_from()
 
 
@@ -582,8 +591,8 @@ class SignatureStream:
                 "bytes_read": self.loader.stats.bytes_read,
                 "source": "hash"}
 
-    def hash_chunk(self, chunk: SparseBatch):
-        """Hash one SparseBatch chunk (with kernel-time accounting)."""
+    def hash_chunk(self, chunk: SegmentedBatch):
+        """Hash one loader chunk (with kernel-time accounting)."""
         import jax
         t0 = time.perf_counter()
         sig = self.engine(chunk)

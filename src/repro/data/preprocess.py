@@ -43,6 +43,10 @@ class PreprocessStats:
     the kernel and the store.
     ``kernel_s`` runs to the packed words being ready on the device;
     ``store_s`` covers the copy back and the ``.sig`` write.
+    ``nonzeros`` counts the real ids hashed and ``slots_hashed`` the index
+    slots the kernels hashed for them (segments times segment width,
+    padding included), so their ratio is the share of the kernels' work
+    that fell on real ids.
     """
 
     examples: int = 0
@@ -51,6 +55,8 @@ class PreprocessStats:
     store_s: float = 0.0
     bytes_in: int = 0
     bytes_out: int = 0
+    nonzeros: int = 0
+    slots_hashed: int = 0
 
     def reduction(self) -> float:
         return self.bytes_in / max(self.bytes_out, 1)
@@ -72,6 +78,12 @@ def preprocess_shards(shard_paths: Sequence[str], out_dir: str, family, *,
     codes; sentinel signatures pack as (b+1)-bit codes with EMPTY stored
     as 2^b, so even the estimator-facing sentinel scheme ships the
     paper's per-example bit budget.
+
+    Every family and configuration takes one layout: the loader cuts
+    each row into fixed-width segments (``repro.data.sparse.
+    SegmentedBatch``), the kernels hash the segments, and the engine
+    takes each row's minimum over its segments before the b-bit step and
+    the pack.  The ``.sig`` rows come out in file order.
 
     Spans (one each per chunk) go to ``tracer``, by default the
     process-wide ``get_tracer()``, and to the loader's too.
@@ -116,6 +128,8 @@ def preprocess_shards(shard_paths: Sequence[str], out_dir: str, family, *,
                             sentinel=packed.sentinel)
             stats.bytes_out += os.path.getsize(out_path)
         stats.store_s += time.perf_counter() - t_kernel
+    stats.nonzeros = loader.stats.nonzeros
+    stats.slots_hashed = loader.stats.slots
     return stats
 
 

@@ -31,7 +31,7 @@ import dataclasses
 import functools
 import json
 import os
-from typing import Dict, Optional
+from typing import Dict, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -39,13 +39,16 @@ import jax.numpy as jnp
 from repro.core.bbit import pack_codes
 from repro.core.hashing import Hash2U, Hash4U, PermutationFamily
 from repro.core.oph import OPH, densify_and_bbit, oph_signatures
-from repro.data.sparse import SparseBatch
+from repro.data.sparse import SegmentedBatch, SparseBatch
 from repro.kernels import ref as kref
 from repro.kernels.minhash import minhash2u_pallas, minhash4u_pallas
 from repro.kernels.oph import oph2u_pallas, oph4u_pallas
 from repro.kernels.pack import (PackSpec, can_pack_in_kernel, encode_sentinel,
                                 pack_device, unpack_device)
 from repro.kernels.sigbag import sigbag_pallas
+
+# what the engine hashes: padded rows, or fixed-width segments of rows
+Batch = Union[SparseBatch, SegmentedBatch]
 
 
 # ---------------------------------------------------------------------------
@@ -298,55 +301,86 @@ def _pad_axis(x, mult, axis, value=0):
     return jnp.pad(x, pads, constant_values=value)
 
 
-@functools.partial(jax.jit, static_argnames=("s", "b", "variant", "backend",
-                                             "blk_n", "blk_t", "blk_k",
-                                             "packed"))
-def _minhash2u_run(indices, counts, a1, a2, *, s, b, variant, backend,
-                   blk_n, blk_t, blk_k, packed=False):
-    n, _ = indices.shape
+def _rows_from_segments(out, rows, n):
+    """Row minima from segment minima: a row's minimum is the least of
+    its segments' (``rows`` maps segment to row, non-decreasing; None
+    where segment i is row i).  Padding segments hash to the maximum
+    and name no row, so they change nothing."""
+    if rows is None:
+        return out[:n]
+    return jax.ops.segment_min(out, rows, num_segments=n,
+                               indices_are_sorted=True)
+
+
+def _bbit(out, b):
+    return out & jnp.uint32((1 << b) - 1) if b > 0 else out
+
+
+@functools.partial(jax.jit, static_argnames=("n", "s", "b", "variant",
+                                             "backend", "blk_n", "blk_t",
+                                             "blk_k", "packed"))
+def _minhash2u_run(indices, counts, a1, a2, rows=None, *, n=None, s, b,
+                   variant, backend, blk_n, blk_t, blk_k, packed=False):
+    """Signatures of ``n`` rows (default: one per index row) from
+    segments ``indices`` with ``counts`` real ids each; with ``rows``
+    the kernel emits raw segment minima and the b-bit step and the pack
+    follow the reduction to rows, else both may be fused in the kernel."""
     k = a1.shape[0]
+    n = indices.shape[0] if n is None else n
+    kb = b if rows is None else 0
     counts = counts.reshape(-1, 1).astype(jnp.int32)
     be = BACKENDS[backend]
     if not be.use_pallas:
-        out = kref.minhash2u_ref(indices, counts, a1, a2, s=s, b=b,
+        out = kref.minhash2u_ref(indices, counts, a1, a2, s=s, b=kb,
                                  variant=variant)
-        return pack_device(out, PackSpec(k, b)) if packed else out
-    idx = _pad_axis(_pad_axis(indices, blk_t, 1), blk_n, 0)
-    cts = _pad_axis(counts, blk_n, 0)
-    a1p = _pad_axis(a1, blk_k, 0)
-    a2p = _pad_axis(a2, blk_k, 0, value=1)
-    if packed and can_pack_in_kernel(a1p.shape[0], k, b, blk_k):
-        words = minhash2u_pallas(idx, cts, a1p, a2p, s=s, b=b, blk_n=blk_n,
-                                 blk_t=blk_t, blk_k=blk_k, variant=variant,
-                                 pack=True, interpret=be.interpret)
-        return words[:n]
-    out = minhash2u_pallas(idx, cts, a1p, a2p, s=s, b=b, blk_n=blk_n,
-                           blk_t=blk_t, blk_k=blk_k, variant=variant,
-                           interpret=be.interpret)[:n, :k]
+    else:
+        idx = _pad_axis(_pad_axis(indices, blk_t, 1), blk_n, 0)
+        cts = _pad_axis(counts, blk_n, 0)
+        a1p = _pad_axis(a1, blk_k, 0)
+        a2p = _pad_axis(a2, blk_k, 0, value=1)
+        if kb and packed and can_pack_in_kernel(a1p.shape[0], k, b, blk_k):
+            words = minhash2u_pallas(idx, cts, a1p, a2p, s=s, b=b,
+                                     blk_n=blk_n, blk_t=blk_t, blk_k=blk_k,
+                                     variant=variant, pack=True,
+                                     interpret=be.interpret)
+            return words[:n]
+        out = minhash2u_pallas(idx, cts, a1p, a2p, s=s, b=kb, blk_n=blk_n,
+                               blk_t=blk_t, blk_k=blk_k, variant=variant,
+                               interpret=be.interpret)[:, :k]
+    out = _rows_from_segments(out, rows, n)
+    if rows is not None:
+        out = _bbit(out, b)
     return pack_device(out, PackSpec(k, b)) if packed else out
 
 
-@functools.partial(jax.jit, static_argnames=("s", "b", "backend", "blk_n",
-                                             "blk_t", "blk_k", "packed"))
-def _minhash4u_run(indices, counts, a, *, s, b, backend, blk_n, blk_t, blk_k,
-                   packed=False):
-    n, _ = indices.shape
+@functools.partial(jax.jit, static_argnames=("n", "s", "b", "backend",
+                                             "blk_n", "blk_t", "blk_k",
+                                             "packed"))
+def _minhash4u_run(indices, counts, a, rows=None, *, n=None, s, b, backend,
+                   blk_n, blk_t, blk_k, packed=False):
+    """``_minhash2u_run`` for the 4U family."""
     k = a.shape[1]
+    n = indices.shape[0] if n is None else n
+    kb = b if rows is None else 0
     counts = counts.reshape(-1, 1).astype(jnp.int32)
     be = BACKENDS[backend]
     if not be.use_pallas:
-        out = kref.minhash4u_ref(indices, counts, a, s=s, b=b)
-        return pack_device(out, PackSpec(k, b)) if packed else out
-    idx = _pad_axis(_pad_axis(indices, blk_t, 1), blk_n, 0)
-    cts = _pad_axis(counts, blk_n, 0)
-    ap = _pad_axis(a, blk_k, 1, value=1)
-    if packed and can_pack_in_kernel(ap.shape[1], k, b, blk_k):
-        words = minhash4u_pallas(idx, cts, ap, s=s, b=b, blk_n=blk_n,
-                                 blk_t=blk_t, blk_k=blk_k, pack=True,
-                                 interpret=be.interpret)
-        return words[:n]
-    out = minhash4u_pallas(idx, cts, ap, s=s, b=b, blk_n=blk_n, blk_t=blk_t,
-                           blk_k=blk_k, interpret=be.interpret)[:n, :k]
+        out = kref.minhash4u_ref(indices, counts, a, s=s, b=kb)
+    else:
+        idx = _pad_axis(_pad_axis(indices, blk_t, 1), blk_n, 0)
+        cts = _pad_axis(counts, blk_n, 0)
+        ap = _pad_axis(a, blk_k, 1, value=1)
+        if kb and packed and can_pack_in_kernel(ap.shape[1], k, b, blk_k):
+            words = minhash4u_pallas(idx, cts, ap, s=s, b=b, blk_n=blk_n,
+                                     blk_t=blk_t, blk_k=blk_k, pack=True,
+                                     interpret=be.interpret)
+            return words[:n]
+        out = minhash4u_pallas(idx, cts, ap, s=s, b=kb, blk_n=blk_n,
+                               blk_t=blk_t, blk_k=blk_k,
+                               interpret=be.interpret)[:, :k]
+    out = _rows_from_segments(out, rows, n)
+    if rows is not None:
+        out = _bbit(out, b)
     return pack_device(out, PackSpec(k, b)) if packed else out
 
 
@@ -394,18 +428,21 @@ def _oph4u_raw(indices, counts, a, *, s, bin_bits, backend, k_lanes,
                         interpret=be.interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "s", "bin_bits", "densify",
-                                             "b", "packed", "coded"))
-def _oph_epilogue_jit(raw, *, k, s, bin_bits, densify, b, packed=False,
-                      coded=False):
-    """Slice lane padding, densify, extract b bits, optionally pack.
+@functools.partial(jax.jit, static_argnames=("n", "k", "s", "bin_bits",
+                                             "densify", "b", "packed",
+                                             "coded"))
+def _oph_epilogue_jit(raw, rows=None, *, n=None, k, s, bin_bits, densify, b,
+                      packed=False, coded=False):
+    """Reduce segment bin minima to ``n`` rows (EMPTY, the maximum, is
+    what an empty bin holds), slice lane padding, densify, extract b
+    bits, optionally pack.
 
     Shares ``repro.core.oph.densify_and_bbit`` with the jnp reference so
     the kernel path is bit-exact against it.  ``coded=True`` means the
-    kernel already emitted (b+1)-bit sentinel codes (fused epilogue) and
-    only the bitstream pack remains.
+    kernel already emitted (b+1)-bit sentinel codes (fused epilogue, one
+    segment per row) and only the bitstream pack remains.
     """
-    sig = raw[:, :k]
+    sig = _rows_from_segments(raw, rows, n)[:, :k]
     spec = PackSpec(k, b, sentinel=(densify == "sentinel")) if packed else None
     if coded:
         return pack_codes(sig, spec.code_bits)
@@ -553,57 +590,65 @@ class SignatureEngine:
                              packed=self.packed, **self.statics, **blocks)
 
     # -- execution ------------------------------------------------------
-    def signatures(self, batch: SparseBatch) -> jax.Array:
+    def signatures(self, batch: Batch) -> jax.Array:
         """(n, k) uint32 signatures (b-bit masked when plan.b > 0)."""
         return self._runner(self, batch, self.plan_for(batch.indices.shape[1]),
                             packed=False)
 
-    def packed_signatures(self, batch: SparseBatch) -> PackedSignatures:
+    def packed_signatures(self, batch: Batch) -> PackedSignatures:
         """The packed wire format: k*code_bits bits per example."""
         plan = self.plan_for(batch.indices.shape[1])
         words = self._runner(self, batch, plan, packed=True)
         return PackedSignatures(words, plan.k, plan.b, plan.sentinel)
 
-    def __call__(self, batch: SparseBatch):
+    def __call__(self, batch: Batch):
         return self.packed_signatures(batch) if self.packed \
             else self.signatures(batch)
 
 
-def _counts(batch: SparseBatch) -> jax.Array:
-    return jnp.sum(batch.mask.astype(jnp.int32), axis=1)
+def _segments(batch):
+    """``(indices, counts, rows, n)``: the batch as fixed-width segments
+    (a ``SparseBatch`` is one segment per row)."""
+    if isinstance(batch, SegmentedBatch):
+        return batch.indices, batch.counts, batch.rows, batch.n
+    return (batch.indices, jnp.sum(batch.mask.astype(jnp.int32), axis=1),
+            None, batch.n)
 
 
 def _run_minhash_2u(eng, batch, plan, *, packed):
     fam = eng.family_obj
-    return _minhash2u_run(batch.indices, _counts(batch), fam.a1, fam.a2,
-                          s=plan.s, b=plan.b, variant=plan.variant,
+    idx, counts, rows, n = _segments(batch)
+    return _minhash2u_run(idx, counts, fam.a1, fam.a2, rows, n=n, s=plan.s,
+                          b=plan.b, variant=plan.variant,
                           backend=plan.backend, blk_n=plan.blk_n,
                           blk_t=plan.blk_t, blk_k=plan.blk_k, packed=packed)
 
 
 def _run_minhash_4u(eng, batch, plan, *, packed):
     fam = eng.family_obj
-    return _minhash4u_run(batch.indices, _counts(batch), fam.a, s=plan.s,
-                          b=plan.b, backend=plan.backend, blk_n=plan.blk_n,
+    idx, counts, rows, n = _segments(batch)
+    return _minhash4u_run(idx, counts, fam.a, rows, n=n, s=plan.s, b=plan.b,
+                          backend=plan.backend, blk_n=plan.blk_n,
                           blk_t=plan.blk_t, blk_k=plan.blk_k, packed=packed)
 
 
 def _run_oph(eng, batch, plan, *, packed, raw_fn, coeff_args):
-    n = batch.indices.shape[0]
-    counts = _counts(batch).reshape(-1, 1).astype(jnp.int32)
+    idx, counts, rows, n = _segments(batch)
+    counts = counts.reshape(-1, 1).astype(jnp.int32)
     bin_bits = plan.k.bit_length() - 1
     k_lanes, blk_k = _oph_lanes(plan.k, plan.blk_k)
-    # packed sentinel: the kernel's fused final-step epilogue emits the
-    # (b+1)-bit codes; everything else uses the raw-minima stage (shared
-    # across densify/b sweeps) + the jnp epilogue.
-    coded = packed and plan.sentinel
-    raw = raw_fn(batch.indices, counts, *coeff_args, s=plan.s,
+    # packed sentinel, one segment per row: the kernel's fused final-step
+    # epilogue emits the (b+1)-bit codes; everything else uses the
+    # raw-minima stage (shared across densify/b sweeps) + the jnp
+    # epilogue, which first reduces segments to rows.
+    coded = packed and plan.sentinel and rows is None
+    raw = raw_fn(idx, counts, *coeff_args, s=plan.s,
                  bin_bits=bin_bits, backend=plan.backend, k_lanes=k_lanes,
                  blk_n=plan.blk_n, blk_t=plan.blk_t, blk_k=blk_k,
                  code_b=plan.b if coded else 0)
-    return _oph_epilogue_jit(raw, k=plan.k, s=plan.s, bin_bits=bin_bits,
-                             densify=plan.densify, b=plan.b, packed=packed,
-                             coded=coded)[:n]
+    return _oph_epilogue_jit(raw, rows, n=n, k=plan.k, s=plan.s,
+                             bin_bits=bin_bits, densify=plan.densify,
+                             b=plan.b, packed=packed, coded=coded)
 
 
 def _run_oph_2u(eng, batch, plan, *, packed):
@@ -620,8 +665,15 @@ def _run_oph_4u(eng, batch, plan, *, packed):
 
 
 def _run_oph_perm(eng, batch, plan, *, packed):
-    # permutation base: gold-standard jnp reference (tests/small D only)
-    sig = oph_signatures(batch.indices, batch.mask, eng.family_obj, b=plan.b)
+    # permutation base: gold-standard jnp reference (tests/small D only);
+    # raw bin minima per segment, reduced to rows, then densified
+    oph = eng.family_obj
+    idx, counts, rows, n = _segments(batch)
+    mask = jnp.arange(idx.shape[1])[None, :] < counts[:, None]
+    raw = oph_signatures(idx, mask, dataclasses.replace(oph,
+                                                        densify="sentinel"))
+    sig = densify_and_bbit(_rows_from_segments(raw, rows, n), oph.bin_width,
+                           oph.densify, plan.b)
     return pack_device(sig, plan.pack_spec) if packed else sig
 
 

@@ -68,8 +68,7 @@ def serve_index(args) -> None:
                           densify=args.densify)
         t0 = time.perf_counter()
         preprocess_shards(raw, os.path.join(tmp, "sig"), fam, b=b,
-                          chunk_size=max(64, args.docs // 4),
-                          loader_kwargs={"lane_multiple": 8})
+                          chunk_size=max(64, args.docs // 4))
         t_hash = time.perf_counter() - t0
         sig_paths = sorted(glob.glob(os.path.join(tmp, "sig", "*.sig")))
         cfg = choose_band_config(

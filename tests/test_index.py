@@ -160,7 +160,7 @@ def corpus_idx(tmp_path_factory):
     raw = make_sharded_dataset(spec, os.path.join(tmp, "raw"), n_shards=3)
     fam = OPH.create(jax.random.PRNGKey(0), K, S, "2u", "rotation")
     preprocess_shards(raw, os.path.join(tmp, "sig"), fam, b=8,
-                      chunk_size=128, loader_kwargs={"lane_multiple": 8})
+                      chunk_size=128)
     sig_paths = sorted(glob.glob(os.path.join(tmp, "sig", "*.sig")))
     assert len(sig_paths) > 1
     cfg = choose_band_config(K, 8, threshold=0.5, target_recall=0.95)

@@ -66,8 +66,7 @@ def test_preprocess_pipeline_roundtrip(tmp_path):
                                  n=120)
     fam = Hash2U.create(jax.random.PRNGKey(3), 64, 16)
     out = str(tmp_path / "sig")
-    stats = preprocess_shards(paths, out, fam, b=8, chunk_size=48,
-                              loader_kwargs={"lane_multiple": 8})
+    stats = preprocess_shards(paths, out, fam, b=8, chunk_size=48)
     assert stats.examples == 96          # 80% train split of 120
     assert stats.kernel_s > 0 and stats.load_s > 0 and stats.store_s > 0
     assert stats.reduction() > 2.0       # the paper's size reduction
@@ -77,9 +76,9 @@ def test_preprocess_pipeline_roundtrip(tmp_path):
     shard0 = sorted(os.listdir(out))[0]
     packed, labels, k, b = read_signature_shard(os.path.join(out, shard0))
     assert (k, b) == (64, 8)
-    from repro.data.pipeline import ChunkedLoader
-    chunk = next(iter(ChunkedLoader(paths, chunk_size=48,
-                                    lane_multiple=8)))
+    from repro.data.pipeline import read_shard_binary
+    sets = [r for p in paths for r in read_shard_binary(p)[0]][:48]
+    chunk = from_lists(sets)
     direct = lowest_bits(
         minhash_signatures(chunk.indices, chunk.mask, fam), 8)
     got = unpack_signatures(jax.numpy.asarray(packed), 8, 64)

@@ -432,8 +432,7 @@ def test_oph_preprocess_shards_roundtrip(tmp_path):
     n_total = sum(len(read_shard_binary(p)[1]) for p in paths)
     oph = OPH.create(jax.random.PRNGKey(0), 128, 14, "2u", "rotation")
     stats = preprocess_shards(paths, str(tmp_path / "sig"), oph, b=8,
-                              chunk_size=64,
-                              loader_kwargs={"lane_multiple": 8})
+                              chunk_size=64)
     assert stats.examples == n_total >= 64
     packed, labels, k, b = read_signature_shard(
         str(tmp_path / "sig" / "sig_00000.sig"))
@@ -445,7 +444,7 @@ def test_oph_preprocess_shards_roundtrip(tmp_path):
     from repro.data.sigshard import read_sig_shard
     sent = OPH.create(jax.random.PRNGKey(0), 128, 14, "2u", "sentinel")
     preprocess_shards(paths, str(tmp_path / "sig_sent"), sent, b=8,
-                      chunk_size=64, loader_kwargs={"lane_multiple": 8})
+                      chunk_size=64)
     words, _, meta = read_sig_shard(str(tmp_path / "sig_sent" /
                                         "sig_00000.sig"))
     assert meta.sentinel and meta.code_bits == 9
@@ -471,8 +470,7 @@ def test_oph_signature_stream(tmp_path):
     from repro.data.pipeline import read_shard_binary
     n_total = sum(len(read_shard_binary(p)[1]) for p in paths)
     oph = OPH.create(jax.random.PRNGKey(0), 64, 12, "2u", "rotation")
-    stream = SignatureStream(paths, oph, b=4, chunk_size=32,
-                             loader_kwargs={"lane_multiple": 8})
+    stream = SignatureStream(paths, oph, b=4, chunk_size=32)
     chunks = list(stream)
     assert stream.examples == n_total > 0
     assert sum(sig.shape[0] for sig, _ in chunks) == n_total

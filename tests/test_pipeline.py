@@ -15,7 +15,7 @@ from repro.data.pipeline import (ChunkedLoader, LoaderStats,
                                  read_shard_libsvm, read_with_retries,
                                  write_shard_binary, write_shard_libsvm,
                                  write_shards)
-from repro.data.sparse import pad_csr_parts, pad_lists
+from repro.data.sparse import pad_lists
 
 
 def _toy_sets(n=50, seed=0):
@@ -43,8 +43,7 @@ def test_shard_roundtrip(tmp_path, fmt):
 def test_chunked_iteration(tmp_path, prefetch):
     sets, labels = _toy_sets(101)
     paths = write_shards(sets, labels, str(tmp_path), n_shards=4)
-    loader = ChunkedLoader(paths, chunk_size=25, prefetch=prefetch,
-                           lane_multiple=8)
+    loader = ChunkedLoader(paths, chunk_size=25, prefetch=prefetch)
     chunks = list(loader)
     assert sum(c.n for c in chunks) == 101
     assert chunks[0].n == 25
@@ -60,8 +59,7 @@ def test_straggler_detection_counters(tmp_path):
     paths = write_shards(sets, labels, str(tmp_path), n_shards=2)
     # absurd deadline of 0 -> every read is a straggler, then reassigned
     loader = ChunkedLoader(paths, chunk_size=40, prefetch=0,
-                           straggler_deadline_s=0.0, max_retries=1,
-                           lane_multiple=8)
+                           straggler_deadline_s=0.0, max_retries=1)
     chunks = list(loader)
     assert sum(c.n for c in chunks) == 40
     assert loader.stats.straggler_retries >= 2
@@ -72,8 +70,7 @@ def test_read_shard_oserror_accounted(tmp_path):
     """Flaky reads retry with accounting; exhausted retries raise."""
     sets, labels = _toy_sets(20)
     paths = write_shards(sets, labels, str(tmp_path), n_shards=1)
-    loader = ChunkedLoader(paths, chunk_size=20, prefetch=0, max_retries=2,
-                           lane_multiple=8)
+    loader = ChunkedLoader(paths, chunk_size=20, prefetch=0, max_retries=2)
     real_reader = loader._reader
     fails = {"n": 2}
 
@@ -93,8 +90,7 @@ def test_read_shard_oserror_accounted(tmp_path):
     assert (loader.stats.mapped_reads, loader.stats.decoded_reads) == (1, 0)
 
     # every attempt failing must surface the OSError, all attempts counted
-    dead = ChunkedLoader(paths, chunk_size=20, prefetch=0, max_retries=1,
-                         lane_multiple=8)
+    dead = ChunkedLoader(paths, chunk_size=20, prefetch=0, max_retries=1)
 
     def always_fails(path):
         raise OSError("gone")
@@ -148,7 +144,7 @@ def test_loader_backoff_knobs_reach_reader(tmp_path):
     sets, labels = _toy_sets(20)
     paths = write_shards(sets, labels, str(tmp_path), n_shards=1)
     loader = ChunkedLoader(paths, chunk_size=20, prefetch=0, max_retries=2,
-                           lane_multiple=8, io_backoff_base_s=1e-4,
+                           io_backoff_base_s=1e-4,
                            io_backoff_cap_s=2e-4)
     real_reader = loader._reader
     fails = {"n": 2}
@@ -171,7 +167,7 @@ def test_loader_backoff_knobs_reach_reader(tmp_path):
 def test_make_sharded_dataset(tmp_path):
     paths = make_sharded_dataset(TINY, str(tmp_path), n_shards=3, n=60)
     assert len(paths) == 3
-    loader = ChunkedLoader(paths, chunk_size=16, lane_multiple=8)
+    loader = ChunkedLoader(paths, chunk_size=16)
     total = sum(c.n for c in loader)
     assert total == 48  # 80% train split of 60
 
@@ -198,14 +194,26 @@ def _shred(paths):
         os.remove(p)
 
 
+def _chunk_rows(c):
+    """The sets of a segmented chunk, read back from its segments in
+    order (padding segments name no set)."""
+    idx, counts = np.asarray(c.indices), np.asarray(c.counts)
+    rows = (np.arange(len(counts)) if c.rows is None
+            else np.asarray(c.rows))
+    out = [[] for _ in range(c.n)]
+    for seg, row in enumerate(rows.tolist()):
+        if row < c.n:
+            out[row].append(idx[seg, :counts[seg]])
+        else:
+            assert counts[seg] == 0
+    return [np.concatenate(r) for r in out]
+
+
 def _assert_rows(chunks, sets, labels):
     pos = 0
     for c in chunks:
-        idx = np.asarray(c.indices)
-        mask = np.asarray(c.mask)
-        for row in range(c.n):
-            got = np.sort(idx[row][mask[row]])
-            np.testing.assert_array_equal(got, np.sort(sets[pos + row]))
+        for row, got in enumerate(_chunk_rows(c)):
+            np.testing.assert_array_equal(got, sets[pos + row])
         np.testing.assert_array_equal(np.asarray(c.labels),
                                       labels[pos:pos + c.n])
         pos += c.n
@@ -225,8 +233,7 @@ def test_chunk_contents_pinned(tmp_path, monkeypatch, n, chunk_size):
     from repro.data import pipeline
     sets, labels = _toy_sets(n, seed=3)
     paths = write_shards(sets, labels, str(tmp_path), n_shards=4)
-    loader = ChunkedLoader(paths, chunk_size=chunk_size, prefetch=0,
-                           lane_multiple=8)
+    loader = ChunkedLoader(paths, chunk_size=chunk_size, prefetch=0)
     real_reader, shards, handed = loader._reader, [], []
 
     def keep(path):
@@ -269,8 +276,7 @@ def test_resume_contents_pinned(tmp_path, n, chunk_size, offset):
     reproduces the full pass's remaining chunks."""
     sets, labels = _toy_sets(n, seed=4)
     paths = write_shards(sets, labels, str(tmp_path), n_shards=4)
-    loader = ChunkedLoader(paths, chunk_size=chunk_size, prefetch=0,
-                           lane_multiple=8)
+    loader = ChunkedLoader(paths, chunk_size=chunk_size, prefetch=0)
     full = list(loader)
     start, skip = loader.resume_point(offset)
     tail = list(loader.iter_from(start, skip))
@@ -320,8 +326,7 @@ def test_csr_reader_matches_read_shard_binary(tmp_path, writer, mapped):
     writer(path, sets, labels)
     want_sets, want_labels = read_shard_binary(path)
     from repro.obs.metrics import get_registry
-    loader = ChunkedLoader([path], chunk_size=25, prefetch=0,
-                           lane_multiple=8)
+    loader = ChunkedLoader([path], chunk_size=25, prefetch=0)
     _assert_rows(list(loader), sets, labels)
     assert (loader.stats.mapped_reads, loader.stats.decoded_reads) == (
         (1, 0) if mapped else (0, 1))
@@ -367,7 +372,7 @@ def test_csr_reader_short_read_retried(tmp_path, monkeypatch):
         read_shard_csr(path)
     calls.clear()
     loader = ChunkedLoader([path], chunk_size=16, prefetch=0,
-                           lane_multiple=8, io_backoff_base_s=0.0)
+                           io_backoff_base_s=0.0)
     chunks = list(loader)
     assert loader.stats.io_errors == 1
     assert (loader.stats.mapped_reads, loader.stats.decoded_reads) == (1, 0)
@@ -393,8 +398,7 @@ def test_read_buffers_recycled(tmp_path, chunk_size, max_buffers):
         sets += rows
         labels.append(lab)
     labels = np.concatenate(labels)
-    loader = ChunkedLoader(paths, chunk_size=chunk_size, prefetch=0,
-                           lane_multiple=8)
+    loader = ChunkedLoader(paths, chunk_size=chunk_size, prefetch=0)
     real_reader, buffers = loader._reader, set()
 
     def note(path):
@@ -424,18 +428,18 @@ def test_read_buffers_concurrent_passes(tmp_path):
         sets += rows
         labels.append(lab)
     labels = np.concatenate(labels)
-    loader = ChunkedLoader(paths, chunk_size=18, prefetch=0,
-                           lane_multiple=8)
+    loader = ChunkedLoader(paths, chunk_size=18, prefetch=0)
     results, errors = {}, []
 
     def run(k):
         try:
             for _ in range(5):
-                results[k] = [(np.asarray(c.indices), np.asarray(c.mask),
-                               np.asarray(c.labels)) for c in loader]
-                _assert_rows([types.SimpleNamespace(
-                    indices=i, mask=m, labels=y, n=len(y))
-                    for i, m, y in results[k]], sets, labels)
+                results[k] = [types.SimpleNamespace(
+                    indices=np.asarray(c.indices),
+                    counts=np.asarray(c.counts),
+                    rows=None if c.rows is None else np.asarray(c.rows),
+                    labels=np.asarray(c.labels), n=c.n) for c in loader]
+                _assert_rows(results[k], sets, labels)
         except Exception as e:   # surfaced by the main thread
             errors.append(e)
 
@@ -480,8 +484,7 @@ def test_csr_reader_odd_member_decoded(tmp_path, odd):
             got.flat[got.offsets[i]:got.offsets[i + 1]], want)
     np.testing.assert_array_equal(got.labels, want_labels)
     if odd == "big-endian":
-        loader = ChunkedLoader([path], chunk_size=8, prefetch=0,
-                               lane_multiple=8)
+        loader = ChunkedLoader([path], chunk_size=8, prefetch=0)
         _assert_rows(list(loader), sets, labels)
         assert loader.stats.decoded_reads == 1
 
@@ -529,21 +532,85 @@ def _rows(lens, dtype=np.int32, seed=0):
     (_rows([17, 9, 33], np.int64), 20, 8),       # int64, truncated
 ], ids=["empty-rows", "all-empty", "truncate", "on-lane", "past-lane",
         "int64", "int64-truncate"])
-def test_pad_csr_matches_pad_lists(sets, max_nnz, lane):
-    flat, offsets = _csr(sets)
+def test_pad_lists_matches_rowwise(sets, max_nnz, lane):
     want = _pad_rowwise(sets, max_nnz, lane)
-    got_csr = pad_csr_parts([(flat, offsets)], max_nnz, lane)
-    got_lists = pad_lists(sets, max_nnz, lane)
-    # a window of a larger CSR array (offsets not from 0), in two pieces
+    for g, w in zip(pad_lists(sets, max_nnz, lane), want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+
+
+def _segment_rowwise(sets):
+    """The segmented layout written out segment by segment (independent
+    of ``segment_csr_parts``): width 1280, or the longest row rounded up
+    to 128 where that is less; each row cut into width-long segments,
+    an empty row one empty segment; the segment count padded to a power
+    of two from 128 up to 4096, a multiple of 4096 above."""
+    longest = max((len(s) for s in sets), default=0)
+    width = 1280 if longest > 1280 else max(128, -(-longest // 128) * 128)
+    segs, owner = [], []
+    for r, s in enumerate(sets):
+        for lo in range(0, max(len(s), 1), width):
+            segs.append(np.asarray(s[lo:lo + width]))
+            owner.append(r)
+    total = len(segs)
+    padded = (max(128, 1 << (total - 1).bit_length()) if total <= 4096
+              else -(-total // 4096) * 4096)
+    idx = np.zeros((padded, width), np.int32)
+    counts = np.zeros(padded, np.int32)
+    for i, seg in enumerate(segs):
+        idx[i, :len(seg)] = seg
+        counts[i] = len(seg)
+    rows = None
+    if total != len(sets):
+        rows = np.full(padded, len(sets), np.int32)
+        rows[:total] = owner
+    return idx, counts, rows
+
+
+@pytest.mark.parametrize("lens,dtype", [
+    ([0, 5, 0, 3], np.int32),                    # short rows, empty rows
+    ([0, 0], np.int32),                          # only empty rows
+    ([1280, 7], np.int32),                       # a row exactly one segment
+    ([1281, 0, 1280, 3000, 2560, 7], np.int32),  # rows several segments long
+    ([128, 129, 3], np.int64),                   # int64 ids, one segment each
+    ([5000] + [3] * 130, np.int64),              # 135 segments: bucket 256
+], ids=["short", "all-empty", "exactly-w", "multi", "int64", "bucket"])
+def test_segment_layout_matches_rowwise(lens, dtype):
+    """``segment_csr_parts`` lays every row out as the segment-by-segment
+    rule does, from one CSR piece and from a window of a larger array in
+    two pieces (offsets not from 0)."""
+    from repro.data.sparse import segment_csr_parts
+    sets = _rows(lens, dtype)
+    flat, offsets = _csr(sets)
+    want = _segment_rowwise(sets)
     pre = np.arange(11, dtype=flat.dtype)
     mid = len(sets) // 2
     big = np.concatenate([pre, flat])
-    got_parts = pad_csr_parts([(big, offsets[:mid + 1] + 11),
-                               (big, offsets[mid:] + 11)], max_nnz, lane)
-    for got in (got_csr, got_lists, got_parts):
+    for got in (segment_csr_parts([(flat, offsets)]),
+                segment_csr_parts([(big, offsets[:mid + 1] + 11),
+                                   (big, offsets[mid:] + 11)])):
         for g, w in zip(got, want):
+            if w is None:
+                assert g is None
+                continue
             assert g.dtype == w.dtype and g.shape == w.shape
             np.testing.assert_array_equal(g, w)
+
+
+def test_segment_layout_webspam_width():
+    """A webspam-shaped chunk (4,096 rows of 3,616-3,840 ids) is three
+    1,280-wide segments a row: 3,840 slots a row, what padding each row
+    to the chunk's longest gave."""
+    from repro.data.sparse import segment_csr_parts
+    lens = np.rint(3615.5 + 225 * (np.arange(4096) + 0.5) / 4096)
+    offsets = np.zeros(4097, np.int64)
+    np.cumsum(lens.astype(np.int64), out=offsets[1:])
+    flat = np.arange(offsets[-1], dtype=np.int32)
+    idx, counts, rows = segment_csr_parts([(flat, offsets)])
+    assert idx.shape == (3 * 4096, 1280)
+    assert idx.size == 4096 * 3840
+    assert int(counts.sum()) == offsets[-1]
+    np.testing.assert_array_equal(rows, np.repeat(np.arange(4096), 3))
 
 
 # ---------------------------------------------------------------------------
@@ -561,8 +628,7 @@ def _preprocess(tmp_path, tracer, out="sig"):
     paths = sorted(str(p) for p in raw.iterdir())
     fam = Hash2U.create(jax.random.PRNGKey(0), 64, 10)
     return preprocess_shards(paths, str(tmp_path / out), fam, b=4,
-                             chunk_size=32, tracer=tracer,
-                             loader_kwargs={"lane_multiple": 8})
+                             chunk_size=32, tracer=tracer)
 
 
 def test_preprocess_spans_one_set_per_chunk(tmp_path):

@@ -32,7 +32,7 @@ def sharded(tmp_path_factory):
 def test_full_pipeline_batch_learning(sharded):
     k, b, s = 128, 8, 16
     fam = Hash2U.create(jax.random.PRNGKey(0), k, s)
-    loader = ChunkedLoader(sharded, chunk_size=64, lane_multiple=8)
+    loader = ChunkedLoader(sharded, chunk_size=64)
 
     sigs, labels = [], []
     for chunk in loader:                       # Pallas kernel preprocessing
@@ -61,7 +61,7 @@ def test_online_learning_with_load_accounting(sharded):
 
     # Preprocess once; "hashed dataset" is the signatures on disk (here:
     # in memory as a small array -- the size ratio is what matters).
-    loader = ChunkedLoader(sharded, chunk_size=64, lane_multiple=8)
+    loader = ChunkedLoader(sharded, chunk_size=64)
     chunks = list(loader)
     sig_chunks = [(jnp.asarray(batch_signatures(c, fam, b=b)), c.labels)
                   for c in chunks]
@@ -89,7 +89,7 @@ def test_preprocessing_deterministic_across_chunk_sizes(sharded):
     fam = Hash2U.create(jax.random.PRNGKey(2), 32, 16)
     outs = []
     for cs in (32, 64, 256):
-        loader = ChunkedLoader(sharded, chunk_size=cs, lane_multiple=8)
+        loader = ChunkedLoader(sharded, chunk_size=cs)
         sigs = np.concatenate(
             [np.asarray(batch_signatures(c, fam, b=4)) for c in loader])
         outs.append(sigs)

@@ -86,6 +86,27 @@ def test_minhash_compiles(one_chip, family, packed):
                        _arg(one_chip, (4, K), jnp.uint32), **common)
 
 
+@pytest.mark.parametrize("family,segments,s", [
+    ("2u", 3 * 4096, 24),       # webspam: 3 segments a row
+    ("4u", 40960, 30),          # expanded rcv1: heavy-tailed rows
+])
+def test_segmented_minhash_compiles(one_chip, family, segments, s):
+    """A chunk of 4,096 rows laid out as 1,280-wide segments: the kernel
+    with raw minima, the reduction to rows and the pack."""
+    idx = _arg(one_chip, (segments, 1280), jnp.int32)
+    counts = _arg(one_chip, (segments,), jnp.int32)
+    rows = _arg(one_chip, (segments,), jnp.int32)
+    common = dict(n=4096, s=s, b=B, backend="tpu", packed=True,
+                  **MINHASH_BLOCKS)
+    if family == "2u":
+        a = _arg(one_chip, (K,), jnp.uint32)
+        _assert_kernel(_minhash2u_run, idx, counts, a, a, rows,
+                       variant="high", **common)
+    else:
+        _assert_kernel(_minhash4u_run, idx, counts,
+                       _arg(one_chip, (4, K), jnp.uint32), rows, **common)
+
+
 @pytest.mark.parametrize("sentinel", [False, True])
 @pytest.mark.parametrize("family", ["2u", "4u"])
 def test_oph_compiles(one_chip, family, sentinel):

@@ -45,7 +45,7 @@ def corpus(tmp_path_factory):
     raw = make_sharded_dataset(spec, os.path.join(tmp, "raw"), n_shards=4)
     fam = OPH.create(jax.random.PRNGKey(4), K, S, "2u", "rotation")
     preprocess_shards(raw, os.path.join(tmp, "sig"), fam, b=B,
-                      chunk_size=64, loader_kwargs={"lane_multiple": 8})
+                      chunk_size=64)
     sig_paths = sorted(glob.glob(os.path.join(tmp, "sig", "*.sig")))
     cfg = choose_band_config(K, B, threshold=0.5)
     idx_path = os.path.join(tmp, "single.idx")
