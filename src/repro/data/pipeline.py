@@ -18,6 +18,10 @@ Responsibilities:
     the next healthy worker (bookkeeping mirrors what a real multi-host
     data service does; on one host the "workers" are reader threads),
   * load-time accounting consumed by the online-learning benchmarks,
+  * segment buffers recycled within a pass: a chunk's ids are laid out
+    in the host buffer of an earlier chunk of the same shape once the
+    upload from it has completed (``_SegmentRing``), so a chunk does not
+    fault in tens of MB of fresh pages,
   * spans on the loader thread, one per shard read (``prep.read``: the
     whole file's ``readinto``, or the decode of a shard whose members are
     not stored) and one per chunk for the segmented layout (``prep.pad``:
@@ -44,6 +48,7 @@ import time
 import zipfile
 from typing import Iterator, List, NamedTuple, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -232,6 +237,8 @@ class LoaderStats:
     decoded_reads: int = 0
     nonzeros: int = 0
     slots: int = 0
+    segment_buffers_reused: int = 0
+    segment_buffers_fresh: int = 0
 
 
 # LoaderStats field -> (metric name, help); every field is monotone, so
@@ -256,6 +263,11 @@ _LOADER_METRICS = {
     "slots": ("data_loader_slots_total",
               "index slots laid out in chunks (segments x width), "
               "what the signature kernels hash"),
+    "segment_buffers_reused": ("data_loader_segment_buffers_reused_total",
+                               "chunks laid out in the segment buffer of "
+                               "an earlier chunk of the pass"),
+    "segment_buffers_fresh": ("data_loader_segment_buffers_fresh_total",
+                              "chunks laid out in a new segment buffer"),
 }
 
 
@@ -306,17 +318,18 @@ def read_with_retries(reader, path: str, stats: LoaderStats, *,
     """
     if rng is None:
         rng = _default_backoff_rng
-    last_err: Optional[OSError] = None
+    # no exception is kept in a local: its traceback holds this frame,
+    # and the cycle would keep ``stats`` (and a loader) alive until gc
     for attempt in range(max_retries + 1):
         t0 = time.perf_counter()
         try:
             out = reader(path)
-        except OSError as e:
+        except OSError:
             stats.io_errors += 1
-            last_err = e
-            if attempt < max_retries:
-                delay = min(backoff_cap_s, backoff_base_s * (2.0 ** attempt))
-                sleep(delay * (0.5 + 0.5 * rng.random()))
+            if attempt == max_retries:
+                raise
+            delay = min(backoff_cap_s, backoff_base_s * (2.0 ** attempt))
+            sleep(delay * (0.5 + 0.5 * rng.random()))
             continue
         dt = time.perf_counter() - t0
         if dt > deadline:
@@ -330,8 +343,6 @@ def read_with_retries(reader, path: str, stats: LoaderStats, *,
         stats.load_seconds += dt
         stats.bytes_read += os.path.getsize(path)
         return out
-    assert last_err is not None
-    raise last_err
 
 
 def prefetch_iter(make_iter, prefetch: int):
@@ -382,7 +393,8 @@ def prefetch_iter(make_iter, prefetch: int):
             yield item
         t.join()
         if err:
-            raise err[0]
+            # popped, so no frame of its traceback keeps it (and them) alive
+            raise err.pop()
     finally:
         # also runs on generator close (abandoned consumer): joining here
         # guarantees the producer no longer touches shared loader stats
@@ -407,6 +419,38 @@ def device_put_iter(make_host_iter, prefetch: int = 2):
             yield jax.tree_util.tree_map(jax.device_put, item)
 
     yield from prefetch_iter(produce, prefetch)
+
+
+class _SegmentRing:
+    """The host buffers one pass lays its chunks' segments out in.
+
+    ``take(shape)`` hands out the oldest held buffer of that shape, else
+    a new one; ``give(buffer, uploaded)`` holds a buffer with the device
+    arrays uploaded from it, at most ``size`` of them (the oldest go
+    first).  The upload is asynchronous and reads the buffer, so a
+    buffer is handed out again only after ``jax.block_until_ready`` on
+    those arrays.  The upload copies (``jnp.array``), so what a consumer
+    holds never changes when the buffer is written again.
+    """
+
+    def __init__(self, size: int, stats: LoaderStats):
+        self.size = size
+        self.stats = stats
+        self.held: list = []
+
+    def take(self, shape) -> np.ndarray:
+        for i, (buf, uploaded) in enumerate(self.held):
+            if buf.shape == shape:
+                del self.held[i]
+                jax.block_until_ready(uploaded)
+                self.stats.segment_buffers_reused += 1
+                return buf
+        self.stats.segment_buffers_fresh += 1
+        return np.empty(shape, np.int32)
+
+    def give(self, buf: np.ndarray, uploaded) -> None:
+        self.held.append((buf, uploaded))
+        del self.held[:-self.size]
 
 
 class ChunkedLoader:
@@ -463,9 +507,11 @@ class ChunkedLoader:
         # labels), so a chunk may span shards; no row is split out.  A
         # shard's read buffer goes back to ``_spare`` once no pending
         # piece views it (``held``: buffers of earlier shards still in
-        # ``parts``)
+        # ``parts``).  Segment buffers are recycled through ``ring``, one
+        # per pass, bounded by what the prefetch keeps in flight
         parts: list = []
         held: list = []
+        ring = _SegmentRing(max(1, self.prefetch), self.stats)
         pending = 0
         skip = skip_examples
         for i in range(start_shard, len(self.shard_paths)):
@@ -487,7 +533,7 @@ class ChunkedLoader:
                 pending += take
                 lo += take
                 if pending == self.chunk_size:
-                    yield self._make_batch(parts)
+                    yield self._make_batch(parts, ring)
                     parts, pending = [], 0
                     self._spare.extend(held)
                     held.clear()
@@ -495,26 +541,32 @@ class ChunkedLoader:
                 in_parts = bool(parts) and parts[-1][0] is shard.flat
                 (held if in_parts else self._spare).append(shard.buffer)
         if parts:
-            yield self._make_batch(parts)
+            yield self._make_batch(parts, ring)
         self._spare.clear()
 
-    def _make_batch(self, parts) -> SegmentedBatch:
-        """Lay the chunk's CSR pieces out as fixed-width segments in fresh
-        host arrays (no view of a shard survives into the batch), then
-        hand them to the device, which only enqueues the copies."""
+    def _make_batch(self, parts, ring: _SegmentRing) -> SegmentedBatch:
+        """Lay the chunk's CSR pieces out as fixed-width segments in host
+        arrays (the segments in a buffer from ``ring``; no view of a shard
+        survives into the batch), then hand them to the device, which
+        only enqueues the copies."""
         self.stats.chunks += 1
         with self.tracer.span("prep.pad"):
             idx, counts, rows = segment_csr_parts(
-                [(f, o) for f, o, _ in parts])
+                [(f, o) for f, o, _ in parts], ring.take)
             lab = np.concatenate([y for _, _, y in parts]
                                  ).astype(np.float32, copy=False)
         self.stats.nonzeros += sum(int(o[-1] - o[0]) for _, o, _ in parts)
         self.stats.slots += idx.size
         with self.tracer.span("prep.upload"):
-            return SegmentedBatch(
-                indices=jnp.asarray(idx), counts=jnp.asarray(counts),
+            # ``jnp.array`` copies: ``jnp.asarray`` of a numpy array may
+            # alias it (the CPU backend does for 64-byte aligned ones),
+            # and ``ring`` lays a later chunk out in ``idx``
+            batch = SegmentedBatch(
+                indices=jnp.array(idx), counts=jnp.asarray(counts),
                 rows=None if rows is None else jnp.asarray(rows),
                 labels=jnp.asarray(lab), n=lab.size)
+        ring.give(idx, batch.indices)
+        return batch
 
     def resume_point(self, example_offset: int):
         """Map a stream example offset -> (shard index, in-shard skip).

@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -100,7 +100,13 @@ def _segment_bucket(n_segments: int) -> int:
     return -(-n_segments // SEGMENT_BUCKET) * SEGMENT_BUCKET
 
 
-def segment_csr_parts(parts: Sequence[Tuple[np.ndarray, np.ndarray]]
+def _fresh_segments(shape: Tuple[int, int]) -> np.ndarray:
+    return np.empty(shape, np.int32)
+
+
+def segment_csr_parts(parts: Sequence[Tuple[np.ndarray, np.ndarray]],
+                      buffer: Callable[[Tuple[int, int]], np.ndarray]
+                      = _fresh_segments
                       ) -> Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]:
     """The segmented host arrays of a chunk straight from CSR pieces
     ``(flat, offsets)``: row ``i`` of a piece is ``flat[offsets[i]:
@@ -117,7 +123,11 @@ def segment_csr_parts(parts: Sequence[Tuple[np.ndarray, np.ndarray]]
     row's segments
     are consecutive rows of ``indices``, so its ids are one contiguous
     run of the flattened array: each id is copied once, cast to int32 in
-    that copy, into a fresh zeroed buffer."""
+    that copy, into ``buffer((S, W))``, an int32 array of that shape
+    (default: a new one).  Whatever the buffer held, the slots the ids
+    do not fill (the rest of each row's last segment, and the padding
+    segments) are set to 0, so a reused buffer gives the same layout as
+    a new one."""
     offs = [np.asarray(o, np.int64) for _, o in parts]
     lens = np.concatenate([np.diff(o) for o in offs] or [[]]).astype(np.int64)
     longest = int(lens.max(initial=0))
@@ -128,13 +138,16 @@ def segment_csr_parts(parts: Sequence[Tuple[np.ndarray, np.ndarray]]
     np.cumsum(per_row, out=first[1:])
     real = int(first[-1])
     total = _segment_bucket(real)
-    idx = np.zeros((total, width), np.int32)
+    idx = buffer((total, width))
     out = idx.reshape(-1)
-    starts = iter((first[:-1] * width).tolist())
+    bounds = (first * width).tolist()
+    spans = zip(bounds[:-1], bounds[1:])
     for (flat, _), o in zip(parts, offs):
         for a, b in zip(o[:-1].tolist(), o[1:].tolist()):
-            at = next(starts)
-            out[at:at + b - a] = flat[a:b]
+            lo, hi = next(spans)
+            out[lo:lo + b - a] = flat[a:b]
+            out[lo + b - a:hi] = 0
+    idx[real:] = 0
     seg_row = np.repeat(np.arange(lens.size, dtype=np.int32), per_row)
     within = np.arange(real, dtype=np.int64) - np.repeat(first[:-1], per_row)
     counts = np.zeros(total, np.int32)

@@ -15,7 +15,7 @@ from repro.data.pipeline import (ChunkedLoader, LoaderStats,
                                  read_shard_libsvm, read_with_retries,
                                  write_shard_binary, write_shard_libsvm,
                                  write_shards)
-from repro.data.sparse import pad_lists
+from repro.data.sparse import pad_lists, segment_csr_parts
 
 
 def _toy_sets(n=50, seed=0):
@@ -100,6 +100,44 @@ def test_read_shard_oserror_accounted(tmp_path):
         list(dead)
     assert dead.stats.io_errors == 2  # max_retries + 1 attempts
     assert dead.stats.bytes_read == 0
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+@pytest.mark.parametrize("failures", [1, 2], ids=["retried", "exhausted"])
+def test_loader_freed_after_read_errors(tmp_path, prefetch, failures):
+    """A loader whose reads raised, whether a retry then succeeded or the
+    error surfaced, is freed with its last reference, without the cycle
+    collector: no exception's traceback keeps the frames that hold the
+    loader (its stats, its buffers) alive, so its counters leave the
+    registry at once."""
+    import gc
+    import weakref
+    sets, labels = _toy_sets(20, seed=15)
+    paths = write_shards(sets, labels, str(tmp_path), n_shards=1)
+    loader = ChunkedLoader(paths, chunk_size=20, prefetch=prefetch,
+                           max_retries=1, io_backoff_base_s=0.0)
+    real_reader, fails = loader._reader, {"n": failures}
+
+    def flaky(path):
+        if fails["n"] > 0:
+            fails["n"] -= 1
+            raise OSError("read failure")
+        return real_reader(path)
+
+    loader._reader = flaky
+    gc.disable()
+    try:
+        if failures > loader.max_retries:
+            with pytest.raises(OSError):
+                list(loader)
+        else:
+            assert sum(c.n for c in loader) == 20
+        assert loader.stats.io_errors == failures
+        alive = weakref.ref(loader.stats)
+        del loader, flaky
+        assert alive() is None
+    finally:
+        gc.enable()
 
 
 def test_io_backoff_schedule_pinned(tmp_path):
@@ -240,13 +278,15 @@ def test_chunk_contents_pinned(tmp_path, monkeypatch, n, chunk_size):
         shards.append(real_reader(path))
         return shards[-1]
 
-    def asarray(x, *a, **kw):
-        handed.append(x)
-        return jnp.asarray(x, *a, **kw)
+    def handing(upload):
+        def put(x, *a, **kw):
+            handed.append(x)
+            return upload(x, *a, **kw)
+        return put
 
     loader._reader = keep
     monkeypatch.setattr(pipeline, "jnp", types.SimpleNamespace(
-        asarray=asarray))
+        array=handing(jnp.array), asarray=handing(jnp.asarray)))
     chunks = list(loader)
     sizes = [c.n for c in chunks]
     assert sizes[:-1] == [chunk_size] * (len(chunks) - 1)
@@ -410,6 +450,65 @@ def test_read_buffers_recycled(tmp_path, chunk_size, max_buffers):
     _assert_rows(list(loader), sets, labels)
     assert 1 <= len(buffers) <= max_buffers
     assert loader._spare == []
+
+
+def _assert_fresh_layout(chunks, sets, labels):
+    """Each chunk's segments, counts, row map and labels are those of a
+    fresh layout of its rows (``_segment_rowwise``)."""
+    pos = 0
+    for c in chunks:
+        want = _segment_rowwise(sets[pos:pos + c.n])
+        for got, w in zip((c.indices, c.counts, c.rows), want):
+            assert (got is None) == (w is None)
+            if w is not None:
+                np.testing.assert_array_equal(np.asarray(got), w)
+        np.testing.assert_array_equal(np.asarray(c.labels),
+                                      labels[pos:pos + c.n])
+        pos += c.n
+    assert pos == len(sets)
+
+
+def test_held_batches_keep_their_layout(tmp_path):
+    """A consumer that holds every batch of a prefetched pass to its end
+    still reads each one as laid out, though later chunks were laid out
+    in the same host buffers: no buffer is reused before its upload
+    completes, and no upload aliases the buffer."""
+    sets, labels = _toy_sets(96, seed=12)
+    paths = write_shards(sets, labels, str(tmp_path), n_shards=4)
+    loader = ChunkedLoader(paths, chunk_size=16, prefetch=2)
+    held = list(loader)
+    assert len(held) == 6
+    assert loader.stats.segment_buffers_reused > 0
+    _assert_fresh_layout(held, sets, labels)
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_segment_buffers_recycled(tmp_path, monkeypatch, prefetch):
+    """A pass of equal-shape chunks lays them all out in one segment
+    buffer, counts every chunk once as reused or fresh, exports both
+    counts, and changes no row."""
+    from repro.data import pipeline
+    from repro.obs.metrics import get_registry
+    sets, labels = _toy_sets(96, seed=13)
+    paths = write_shards(sets, labels, str(tmp_path), n_shards=4)
+    laid_out = []
+
+    def keep(parts, buffer):
+        out = segment_csr_parts(parts, buffer)
+        laid_out.append(out[0])
+        return out
+
+    monkeypatch.setattr(pipeline, "segment_csr_parts", keep)
+    loader = ChunkedLoader(paths, chunk_size=16, prefetch=prefetch)
+    chunks = list(loader)
+    st = loader.stats
+    assert st.chunks == len(chunks) == 6
+    assert len({a.ctypes.data for a in laid_out}) == 1
+    assert (st.segment_buffers_reused, st.segment_buffers_fresh) == (5, 1)
+    vals = get_registry().values()
+    assert vals['data_loader_segment_buffers_reused_total{role="load"}'] == 5
+    assert vals['data_loader_segment_buffers_fresh_total{role="load"}'] == 1
+    _assert_fresh_layout(chunks, sets, labels)
 
 
 def test_read_buffers_concurrent_passes(tmp_path):

@@ -132,3 +132,77 @@ def test_preprocess_heavy_tailed_file_order(tmp_path):
     vals = get_registry().values()
     assert vals['data_loader_nonzeros_total{role="load"}'] == lens.sum()
     assert vals['data_loader_slots_total{role="load"}'] == slots
+
+
+# rows of 3,616-3,840 ids, three segments each, and a bucket of padding
+# segments (192 real of 256)
+WEBSPAM_LENS = np.rint(3615.5 + 225 * (np.arange(64) + 0.5) / 64
+                       ).astype(int).tolist()
+# one row of 16 segments among rows of one: 79 segments of 128
+HEAVY_LENS = [20000] + [40] * 62 + [0]
+# one segment a row, and a width of one 128-lane tile
+SHORT_LENS = [1 + i % 100 for i in range(64)]
+
+
+def _csr(sets):
+    offsets = np.zeros(len(sets) + 1, np.int64)
+    np.cumsum([len(s) for s in sets], out=offsets[1:])
+    return np.concatenate(sets), offsets
+
+
+@pytest.mark.parametrize("lens", [WEBSPAM_LENS, HEAVY_LENS, SHORT_LENS],
+                         ids=["webspam", "heavy-tailed", "short"])
+def test_recycled_buffer_layout_equals_fresh(lens):
+    """Laid out into a buffer that holds -1 everywhere, a chunk comes out
+    bit for bit as in a zeroed new one: the rest of each row's last
+    segment and the padding segments are zeros, the ids land in the
+    buffer handed over, and it is asked for at the chunk's own shape."""
+    flat, offsets = _csr(_sets(lens, s=24, seed=5))
+    asked = []
+
+    def dirty(shape):
+        asked.append(shape)
+        return np.full(shape, -1, np.int32)
+
+    want = segment_csr_parts([(flat, offsets)],
+                             lambda shape: np.zeros(shape, np.int32))
+    got = segment_csr_parts([(flat, offsets)], dirty)
+    assert asked == [want[0].shape]
+    for g, w in zip(got, want):
+        if w is None:
+            assert g is None
+            continue
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+    np.testing.assert_array_equal(segment_csr_parts([(flat, offsets)])[0],
+                                  want[0])
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_segment_buffers_reused_by_shape(tmp_path, prefetch):
+    """A pass reuses a segment buffer only for a chunk of the same padded
+    segment count and width: chunks shaped A, A, B (fewer segments), C
+    (narrower), C, A lay out in 4 buffers, 2 of them reused, whether
+    the ring holds one buffer (no prefetch) or two; every chunk comes
+    out as its fresh layout."""
+    kinds = {"A": WEBSPAM_LENS, "B": HEAVY_LENS, "C": SHORT_LENS}
+    order = "AABCCA"
+    sets, paths = [], []
+    for i, kind in enumerate(order):
+        rows = _sets(kinds[kind], s=24, seed=20 + i)
+        paths.append(_write(str(tmp_path / f"s{i}.npz"), rows,
+                            np.ones(len(rows), np.float32)))
+        sets.append(rows)
+    loader = ChunkedLoader(paths, chunk_size=64, prefetch=prefetch)
+    chunks = list(loader)
+    assert [c.indices.shape for c in chunks] == [
+        {"A": (256, SEGMENT_WIDTH), "B": (128, SEGMENT_WIDTH),
+         "C": (128, 128)}[k] for k in order]
+    for chunk, rows in zip(chunks, sets):
+        want = segment_csr_parts([_csr(rows)])
+        for g, w in zip((chunk.indices, chunk.counts, chunk.rows), want):
+            assert (g is None) == (w is None)
+            if w is not None:
+                np.testing.assert_array_equal(np.asarray(g), w)
+    st = loader.stats
+    assert (st.segment_buffers_reused, st.segment_buffers_fresh) == (2, 4)
