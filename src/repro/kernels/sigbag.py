@@ -7,8 +7,8 @@ inner product with the weight vector is
     f(x) = sum_j  W[j, z_j]            (W reshaped to (k, 2^b, d))
 
 i.e., a k-way embedding-bag over per-slot tables.  With d = 1 this *is*
-the paper's linear SVM / logistic forward; with d > 1 it is the hashed
-embedding frontend used by the recsys architectures.
+the paper's linear SVM / logistic forward; d > 1 gives a d-wide hashed
+embedding per example.
 
 TPU design: the per-slot gather is expressed as a one-hot (2^b, BLK_N)
 matrix multiplied into a (d, 2^b) table slice so it runs on the MXU (the

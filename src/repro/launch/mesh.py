@@ -1,11 +1,6 @@
-"""Production mesh construction.
+"""Debug meshes over the real local devices.
 
-Single pod: (16, 16) = 256 chips, axes ("data", "model").
-Multi-pod:  (2, 16, 16) = 512 chips, axes ("pod", "data", "model") -- the
-"pod" axis is an outer data-parallel axis whose gradient all-reduce
-crosses the inter-pod links once per step.
-
-Defined as functions (never module-level constants) so importing this
+Built by a function (never a module-level constant) so importing this
 module does not touch jax device state.
 """
 
@@ -15,13 +10,6 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(n_devices: int = 1, *,

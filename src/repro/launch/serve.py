@@ -1,22 +1,18 @@
-"""Serving launcher: batched decode (LMs), batched scoring (recsys), or
-similarity-search serving over a packed signature index.
+"""Serving launcher: similarity-search serving over a packed signature
+index.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch <id>
-        [--smoke | --no-smoke] [--tokens N | --requests N]
     PYTHONPATH=src python -m repro.launch.serve --index [--mode exact|lsh]
-        [--docs N] [--queries N] [--topk K] [--densify d]
+        [--docs N] [--queries N] [--requests N] [--topk K] [--densify d]
         [--shards S] [--device-window BYTES]
         [--serve --rate QPS --max-delay-ms MS --workers N
          --admission none|reject|shed-oldest|degrade-to-lsh
          --max-queue Q --deadline-budget-ms MS]
 
-LMs run the KV-cache serve_step autoregressively for --tokens steps on a
-batch of prompts; recsys archs score --requests synthetic requests through
-``serve_scores`` (including the minhash-frontend featurization, i.e. the
-paper's online-preprocessing path).  ``--index`` drives the retrieval
-workload (``repro.index``): shard a synthetic corpus, hash it to packed
-``.sig`` shards, build the banded ``.idx``, then serve batched top-k
-queries through the packed-Hamming kernel, reporting p50/p99 latency.
+The launcher drives the retrieval workload (``repro.index``): shard a
+synthetic corpus, hash it to packed ``.sig`` shards, build the banded
+``.idx``, then serve ``--requests`` batches of top-k queries through the
+packed-Hamming kernel, reporting p50 and max latency.  ``--index`` names
+that workload and is the only one; it is accepted, not required.
 ``--shards S`` builds S ``.idx`` shards and serves them through the
 ``ShardedIndex`` router (bit-identical merge); ``--device-window`` caps
 the device-resident packed corpus bytes -- beyond it the exact path
@@ -39,9 +35,6 @@ import tempfile
 import time
 
 import jax
-import jax.numpy as jnp
-
-from repro.sharding.rules import set_mesh
 
 
 def serve_index(args) -> None:
@@ -228,18 +221,12 @@ def _sharded_row_reader(sharded):
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
-    # BooleanOptionalAction so --no-smoke can actually turn full-size
-    # builds back on (a bare store_true with default=True could not be
-    # disabled from the command line at all).
-    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
-                    default=True,
-                    help="shrink the arch for a fast smoke run "
-                         "(--no-smoke serves the full-size config)")
-    ap.add_argument("--tokens", type=int, default=16)
-    ap.add_argument("--requests", type=int, default=4)
+    ap.add_argument("--requests", type=int, default=4,
+                    help="query batches served (the total is --requests x "
+                         "--queries under --serve)")
     ap.add_argument("--index", action="store_true",
-                    help="serve the similarity-search index workload")
+                    help="serve the similarity-search index workload (the "
+                         "only workload; accepted, not required)")
     ap.add_argument("--mode", choices=("exact", "lsh"), default="lsh")
     ap.add_argument("--docs", type=int, default=2048)
     ap.add_argument("--queries", type=int, default=16,
@@ -307,55 +294,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main():
-    ap = build_parser()
-    args = ap.parse_args()
+    args = build_parser().parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
-
-    if args.index:
-        serve_index(args)
-        return
-    if not args.arch:
-        ap.error("--arch is required unless --index is given")
-    from repro.configs import get_arch
-    from repro.launch.steps import build_cell, init_inputs
-    spec = get_arch(args.arch)
-    if spec.family == "lm":
-        prog = build_cell(args.arch, "decode_32k", smoke=args.smoke)
-        key = jax.random.PRNGKey(0)
-        params = prog.init_params(key)
-        inputs = init_inputs(prog, key)
-        cache, tokens = inputs["cache"], inputs["tokens"]
-        step = jax.jit(prog.step)
-        t0 = time.perf_counter()
-        out_tokens = [tokens]
-        for pos in range(1, args.tokens + 1):
-            tokens, cache = step(params, {"cache": cache, "tokens": tokens,
-                                          "pos": jnp.int32(pos)})
-            out_tokens.append(tokens)
-        jax.block_until_ready(tokens)
-        dt = time.perf_counter() - t0
-        print(f"decoded {args.tokens} tokens x batch {tokens.shape[0]} "
-              f"in {dt:.2f}s ({args.tokens * tokens.shape[0] / dt:.1f} "
-              f"tok/s); first sequence: "
-              f"{[int(t[0]) for t in out_tokens[:8]]}")
-    else:
-        cell = "serve_p99" if spec.family == "recsys" else None
-        prog = build_cell(args.arch, cell, smoke=args.smoke)
-        key = jax.random.PRNGKey(0)
-        params = prog.init_params(key)
-        step = jax.jit(prog.step)
-        lat = []
-        for r in range(args.requests):
-            inputs = init_inputs(prog, jax.random.PRNGKey(r))
-            t0 = time.perf_counter()
-            scores = step(params, inputs)
-            jax.block_until_ready(scores)
-            lat.append((time.perf_counter() - t0) * 1e3)
-        lat = sorted(lat)
-        print(f"{args.requests} requests, batch "
-              f"{scores.shape[0]}: p50={lat[len(lat) // 2]:.1f}ms "
-              f"p99={lat[-1]:.1f}ms")
+    serve_index(args)
 
 
 if __name__ == "__main__":

@@ -3,14 +3,9 @@ from repro.optim.base import (Optimizer, add_decayed_weights, apply_updates,
                               scale_by_schedule)
 from repro.optim.optimizers import adafactor, adamw, sgd
 from repro.optim.schedules import constant, inverse_time, warmup_cosine
-from repro.optim.compression import (compressed_psum_int8, dequantize_int8,
-                                     quantize_int8, topk_compress,
-                                     topk_decompress, topk_error_feedback)
 
 __all__ = [
     "Optimizer", "add_decayed_weights", "apply_updates", "chain",
     "clip_by_global_norm", "scale", "scale_by_schedule", "adafactor",
     "adamw", "sgd", "constant", "inverse_time", "warmup_cosine",
-    "compressed_psum_int8", "dequantize_int8", "quantize_int8",
-    "topk_compress", "topk_decompress", "topk_error_feedback",
 ]

@@ -1,8 +1,7 @@
 """Concrete optimizers: SGD(+momentum), AdamW, Adafactor.
 
-Adafactor (Shazeer & Stern) is the memory lever that lets the 123B/671B
-dry-run configs fit 16 GB/chip: second moments are factored into row/col
-statistics (O(n+m) instead of O(nm)) and first-moment momentum is kept in
+Adafactor (Shazeer & Stern) factors second moments into row/col
+statistics (O(n+m) instead of O(nm)) and keeps first-moment momentum in
 bf16.
 """
 
@@ -73,107 +72,6 @@ def adamw(lr: Callable[[jax.Array], jax.Array] | float, b1: float = 0.9,
         return updates, {"count": count, "m": m, "v": v}
 
     return Optimizer(init, update)
-
-
-def adafactor_fused(lr: Callable[[jax.Array], jax.Array] | float,
-                    momentum: Optional[float] = None,
-                    momentum_dtype=jnp.bfloat16,
-                    decay: float = 0.8, eps: float = 1e-30,
-                    clip_threshold: float = 1.0, scan_min_leading: int = 8):
-    """Adafactor whose update is fused with the parameter apply and
-    *scanned over the layer-stack axis* for big leaves.
-
-    Motivation (100B+ models on 16 GB chips): a whole-tree update
-    materializes fp32 gradient/precondition copies of every layer-stacked
-    tensor simultaneously (~2x params in fp32).  Scanning over axis 0 of
-    each (L, ...) leaf keeps only one layer-slice of fp32 temporaries live
-    (factored stats are per-slice exact; update clipping becomes per-slice,
-    a standard variation).  Returns (init, update_apply) where
-    ``update_apply(grads, state, params) -> (new_params, new_state)``.
-    """
-    lr_fn = lr if callable(lr) else (lambda _: lr)
-
-    def _factored(p):
-        return p.ndim >= 2
-
-    def init(params):
-        def v_for(p):
-            if _factored(p):
-                return {"vr": jnp.zeros(p.shape[:-1], jnp.float32),
-                        "vc": jnp.zeros(p.shape[:-2] + p.shape[-1:],
-                                        jnp.float32)}
-            return {"v": jnp.zeros(p.shape, jnp.float32)}
-
-        state = {"count": jnp.zeros((), jnp.int32),
-                 "v": jax.tree_util.tree_map(v_for, params)}
-        if momentum is not None:
-            state["m"] = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, momentum_dtype), params)
-        return state
-
-    def update_apply(grads, state, params):
-        count = state["count"] + 1
-        beta2 = 1.0 - count.astype(jnp.float32) ** (-decay)
-        step = lr_fn(state["count"])
-
-        def slice_update(g, p, vr, vc, m):
-            # barrier: stops XLA hoisting the fp32 convert of the (loop-
-            # invariant) stacked grads/params out of the scan, which would
-            # materialize whole-stack fp32 copies (2x params in fp32).
-            g, p = jax.lax.optimization_barrier((g, p))
-            g32 = g.astype(jnp.float32)
-            g2 = jnp.square(g32) + eps
-            if vr is not None:
-                vr = beta2 * vr + (1 - beta2) * jnp.mean(g2, axis=-1)
-                vc = beta2 * vc + (1 - beta2) * jnp.mean(g2, axis=-2)
-                denom_r = vr / jnp.maximum(
-                    jnp.mean(vr, axis=-1, keepdims=True), eps)
-                precond = g32 / (jnp.sqrt(denom_r)[..., None]
-                                 * jnp.sqrt(vc)[..., None, :] + eps)
-            else:
-                vc = beta2 * vc + (1 - beta2) * g2     # vc doubles as v
-                precond = g32 / (jnp.sqrt(vc) + eps)
-            rms = jnp.sqrt(jnp.mean(jnp.square(precond)) + 1e-30)
-            precond = precond / jnp.maximum(1.0, rms / clip_threshold)
-            if m is not None:
-                m = (momentum * m.astype(jnp.float32)
-                     + (1 - momentum) * precond).astype(momentum_dtype)
-                upd = m.astype(jnp.float32)
-            else:
-                upd = precond
-            new_p = (p.astype(jnp.float32) - step * upd).astype(p.dtype)
-            return new_p, vr, vc, m
-
-        def leaf(g, p, v, m):
-            vr = v.get("vr")
-            vc = v.get("vc", v.get("v"))
-            if p.ndim >= 3 and p.shape[0] >= scan_min_leading:
-                def body(_, xs):
-                    g_s, p_s, vr_s, vc_s, m_s = xs
-                    out = slice_update(g_s, p_s, vr_s, vc_s, m_s)
-                    return None, out
-                xs = (g, p, vr, vc, m)
-                _, (new_p, nvr, nvc, nm) = jax.lax.scan(body, None, xs)
-            else:
-                new_p, nvr, nvc, nm = slice_update(g, p, vr, vc, m)
-            nv = ({"vr": nvr, "vc": nvc} if "vr" in v else {"v": nvc})
-            return new_p, nv, nm
-
-        flat_g, tdef = jax.tree_util.tree_flatten(grads)
-        flat_p = tdef.flatten_up_to(params)
-        flat_v = tdef.flatten_up_to(state["v"])
-        flat_m = (tdef.flatten_up_to(state["m"]) if momentum is not None
-                  else [None] * len(flat_g))
-        outs = [leaf(g, p, v, m)
-                for g, p, v, m in zip(flat_g, flat_p, flat_v, flat_m)]
-        new_params = tdef.unflatten([o[0] for o in outs])
-        new_state = {"count": count,
-                     "v": tdef.unflatten([o[1] for o in outs])}
-        if momentum is not None:
-            new_state["m"] = tdef.unflatten([o[2] for o in outs])
-        return new_params, new_state
-
-    return Optimizer(init, update_apply)
 
 
 def adafactor(lr: Callable[[jax.Array], jax.Array] | float,

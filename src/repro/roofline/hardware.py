@@ -1,7 +1,3 @@
 """TPU v5e hardware constants for the roofline model."""
 
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s per chip, bf16
 HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link (approx, per direction)
-HBM_BYTES = 16 * 2**30          # 16 GiB HBM per chip
-VMEM_BYTES = 128 * 2**20

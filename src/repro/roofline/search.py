@@ -14,7 +14,7 @@ corpus once past ``q`` resident query rows (PAPER.md §6's preprocessing
 arithmetic -- b-bit codes exist precisely to shrink this stream), then
 materializes a (q, n) score panel that the top-k reduction re-reads.
 
-On the CPU dry-run host the measured bandwidth is nowhere near the TPU
+On a CPU host the measured bandwidth is nowhere near the TPU
 constant, so the gap is large and only its TRAJECTORY is meaningful;
 on real hardware the same artifact becomes an absolute utilization
 number.  Pass ``bw=`` to re-anchor.

@@ -1,23 +1,14 @@
-"""Logical sharding rules and the mesh-context constraint helper.
+"""The ambient mesh and the retrieval mesh's shard placement rule.
 
-Models call ``constrain(x, "batch", None, "model")`` with *logical* axis
-names; under an active mesh (set by ``set_mesh`` in the launcher/dry-run)
-this becomes ``with_sharding_constraint``; with no mesh it is a no-op, so
-the same model code runs in single-device smoke tests and 512-chip
-dry-runs.
+``set_mesh`` makes a mesh current for the calling thread and
+``current_mesh`` reads it; ``place_shards`` falls back to it when no
+mesh is passed.
 
-Logical -> physical:
-  "batch"  -> all data-parallel axes present in the mesh ("pod", "data")
-  "model"  -> the tensor/expert-parallel axis ("model")
-  "data"   -> FSDP weight sharding axis ("data")
-  None     -> replicated
-
-The retrieval stack adds a *placement* rule on top: ``place_shards``
-maps S ``.idx`` shards onto the D devices of the mesh's ``"data"`` axis
-round-robin (shard s -> device s mod D).  The mapping depends only on
-the shard's position, so growing the tail of the shard list (a live
-append or spill) never moves an already-placed shard -- the property
-``ShardedIndex.refresh`` relies on to keep unchanged shards'
+``place_shards`` maps S ``.idx`` shards onto the D devices of the mesh's
+``"data"`` axis round-robin (shard s -> device s mod D).  The mapping
+depends only on the shard's position, so growing the tail of the shard
+list (a live append or spill) never moves an already-placed shard -- the
+property ``ShardedIndex.refresh`` relies on to keep unchanged shards'
 device-resident corpora warm.
 """
 
@@ -25,11 +16,11 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 _STATE = threading.local()
 
@@ -46,81 +37,6 @@ def set_mesh(mesh: Optional[Mesh]):
         yield
     finally:
         _STATE.mesh = prev
-
-
-def _resolve(axis, mesh: Mesh):
-    if axis is None:
-        return None
-    if isinstance(axis, tuple):
-        # tuple members are literal mesh axes ("data" does NOT expand to
-        # pod+data here), except the logical names "batch" / "all"
-        out = []
-        for a in axis:
-            if a in ("batch", "all"):
-                r = _resolve(a, mesh)
-                if isinstance(r, tuple):
-                    out.extend(r)
-                elif r is not None:
-                    out.append(r)
-            elif a in mesh.axis_names:
-                out.append(a)
-        return tuple(dict.fromkeys(out)) or None
-    if axis == "all":
-        return tuple(mesh.axis_names)
-    if axis in ("batch", "data"):
-        # "batch" (activations) and "data" (FSDP weight sharding) both
-        # span every data-parallel axis: ("pod", "data") on the multi-pod
-        # mesh -- ZeRO-3 over all DP ranks is what lets 671B state fit.
-        axes = tuple(n for n in ("pod", "data") if n in mesh.axis_names)
-        return axes if axes else None
-    if axis in mesh.axis_names:
-        return axis
-    return None
-
-
-def spec(*axes) -> P:
-    mesh = current_mesh()
-    if mesh is None:
-        return P()
-    return P(*[_resolve(a, mesh) for a in axes])
-
-
-def _axis_size(mesh: Mesh, resolved) -> int:
-    if resolved is None:
-        return 1
-    if isinstance(resolved, tuple):
-        out = 1
-        for r in resolved:
-            out *= mesh.shape[r]
-        return out
-    return mesh.shape[resolved]
-
-
-def constrain(x: jax.Array, *axes) -> jax.Array:
-    """Sharding constraint by logical axis names; no-op without a mesh.
-
-    Drops any axis whose mesh extent does not evenly divide the dim size
-    (e.g. 56 heads over a 16-way model axis), so model code never has to
-    special-case divisibility.
-    """
-    mesh = current_mesh()
-    if mesh is None:
-        return x
-    resolved = [_resolve(a, mesh) for a in axes]
-    cleaned = []
-    for dim, r in zip(x.shape, resolved):
-        if r is not None and dim % _axis_size(mesh, r) != 0:
-            r = None
-        cleaned.append(r)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*cleaned)))
-
-
-def named_sharding(*axes) -> Optional[NamedSharding]:
-    mesh = current_mesh()
-    if mesh is None:
-        return None
-    return NamedSharding(mesh, spec(*axes))
 
 
 # ---------------------------------------------------------------------------

@@ -3,12 +3,11 @@ from repro.train.trainer import (EpochTimes, TrainState, Trainer,
 from repro.train.online import (CacheStats, EpochStats, OnlineTrainer,
                                 SignatureCache, make_family)
 from repro.train import checkpoint
-from repro.train.elastic import replicate_shardings, reshard_restore
 from repro.train.fault import Heartbeat, RestartStats, run_with_restarts
 
 __all__ = [
     "EpochTimes", "TrainState", "Trainer", "make_train_step",
     "online_epochs", "CacheStats", "EpochStats", "OnlineTrainer",
-    "SignatureCache", "make_family", "checkpoint", "replicate_shardings",
-    "reshard_restore", "Heartbeat", "RestartStats", "run_with_restarts",
+    "SignatureCache", "make_family", "checkpoint", "Heartbeat",
+    "RestartStats", "run_with_restarts",
 ]

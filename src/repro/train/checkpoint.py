@@ -6,9 +6,9 @@ so a crash mid-write never corrupts the latest checkpoint -- the
 fault-tolerance contract the restart path relies on.
 
 Arrays are flattened with their tree paths as keys, so restore works into
-any pytree with the same structure, and ``restore_resharded`` can place
-each array under a *different* sharding/mesh than it was saved with
-(elastic scaling; see repro.train.elastic).
+any pytree with the same structure, and ``restore(..., shardings=...)``
+can place each array under a *different* sharding/mesh than it was saved
+with.
 """
 
 from __future__ import annotations
@@ -100,8 +100,8 @@ def restore(ckpt_dir: str, template: Any, step: Optional[int] = None,
     """Restore into the structure of ``template``.
 
     If ``shardings`` (a matching pytree of jax.sharding.Sharding or None) is
-    given, each array is device_put under it -- this is the elastic-rescale
-    entry point.
+    given, each array is device_put under it, so a checkpoint saved on one
+    mesh restores onto another.
     """
     step = step if step is not None else latest_step(ckpt_dir)
     if step is None:

@@ -1,10 +1,7 @@
 """Test session setup: 8 host devices for sharding/shard_map tests.
 
 Must run before the first ``import jax`` anywhere in the test session --
-XLA reads the flag once at backend init.  NOTE: the multi-pod dry-run
-uses 512 devices but sets that itself in repro.launch.dryrun (never
-globally); tests use a small count so smoke tests and collective tests
-can coexist.
+XLA reads the flag once at backend init.
 
 Tiers: the ``multidevice`` marker (registered in pyproject.toml, and
 excluded from the default addopts selection next to ``slow``) guards

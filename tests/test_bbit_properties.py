@@ -68,7 +68,7 @@ def test_elastic_reshard_changes_mesh(tmp_path):
     """Checkpoint saved unsharded restores under a 2-device mesh (elastic
     scale-up) and values survive."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from repro.train import checkpoint, reshard_restore
+    from repro.train import checkpoint
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices")
     state = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
@@ -76,10 +76,8 @@ def test_elastic_reshard_changes_mesh(tmp_path):
     checkpoint.save(d, 1, state)
     mesh = Mesh(np.array(jax.devices()[:2]).reshape(2, 1), ("data", "model"))
 
-    def sharding_fn(template):
-        return {"w": NamedSharding(mesh, P("data", None))}
-
-    restored, step = reshard_restore(d, state, sharding_fn)
+    shardings = {"w": NamedSharding(mesh, P("data", None))}
+    restored, step = checkpoint.restore(d, state, shardings=shardings)
     assert step == 1
     np.testing.assert_array_equal(np.asarray(restored["w"]),
                                   np.asarray(state["w"]))

@@ -1,4 +1,4 @@
-"""Checkpointing, restart, elastic resharding, straggler heartbeat."""
+"""Checkpointing, restart, straggler heartbeat."""
 
 import os
 import time
@@ -9,8 +9,7 @@ import numpy as np
 import pytest
 
 from repro.train import (Heartbeat, TrainState, Trainer, checkpoint,
-                         make_train_step, run_with_restarts,
-                         reshard_restore)
+                         make_train_step, run_with_restarts)
 from repro.optim import adamw, constant
 
 
@@ -82,25 +81,6 @@ def test_run_with_restarts_exhausts():
     with pytest.raises(ValueError):
         run_with_restarts(init_state=0, init_step=0, run_steps=always_fails,
                           restore_fn=lambda: (0, 0), max_failures=2)
-
-
-def test_elastic_reshard_roundtrip(tmp_path):
-    """Restore a checkpoint under explicit (new-mesh) shardings."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    state, _ = _toy_state()
-    d = str(tmp_path / "ckpt")
-    checkpoint.save(d, 3, state)
-    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-
-    def sharding_fn(template):
-        s = NamedSharding(mesh, P())
-        return jax.tree_util.tree_map(lambda _: s, template)
-
-    template = jax.tree_util.tree_map(jnp.zeros_like, state)
-    restored, step = reshard_restore(d, template, sharding_fn)
-    assert step == 3
-    leaf = jax.tree_util.tree_leaves(restored)[0]
-    assert leaf.sharding.mesh.axis_names == ("data",)
 
 
 def test_heartbeat_straggler_detection():

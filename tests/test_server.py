@@ -7,8 +7,8 @@ appends under readers: the PR-6 serving-path promises.
   * flush racing ``ShardedIndex.append``: every result consistent with
     the pre- OR post-append corpus, never a torn mix; a second router
     picks the append up via the manifest generation,
-  * ``--smoke``/``--no-smoke`` actually both parse (the old store_true
-    default=True could never be disabled),
+  * the serve CLI parses its index options and refuses the removed
+    model options,
   * ``ZipfianTraffic`` determinism and shape.
 """
 
@@ -612,12 +612,17 @@ def test_compile_cache_dir_is_fixed(monkeypatch, tmp_path, env_dir):
 
 
 def test_serve_cli_smoke_flag_both_ways():
-    """--smoke defaults on, and --no-smoke can actually turn it off (the
-    old action="store_true", default=True made that impossible)."""
+    """The serve CLI serves the index only: --smoke/--no-smoke, --arch and
+    --tokens are refused, --index is accepted but not required, and the
+    index and --serve options parse."""
     ap = build_parser()
-    assert ap.parse_args([]).smoke is True
-    assert ap.parse_args(["--smoke"]).smoke is True
-    assert ap.parse_args(["--no-smoke"]).smoke is False
+    for gone in (["--smoke"], ["--no-smoke"], ["--arch", "din"],
+                 ["--tokens", "8"]):
+        with pytest.raises(SystemExit):
+            ap.parse_args(gone)
+    assert ap.parse_args(["--index"]).index is True
+    assert ap.parse_args([]).index is False
+    assert ap.parse_args(["--requests", "3"]).requests == 3
     args = ap.parse_args(["--index", "--serve", "--rate", "123",
                           "--max-delay-ms", "2.5"])
     assert args.serve and args.rate == 123.0 and args.max_delay_ms == 2.5

@@ -1,50 +1,12 @@
-"""repro.sharding.rules: the logical-axis constraint helper and the
-retrieval mesh's shard placement rule."""
+"""repro.sharding.rules: the ambient mesh and the retrieval mesh's shard
+placement rule."""
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.mesh import make_debug_mesh
-from repro.sharding.rules import (constrain, current_mesh, data_axis_devices,
-                                  place_shards, set_mesh)
-
-
-def test_constrain_is_noop_without_mesh():
-    x = jnp.arange(16.0).reshape(4, 4)
-    assert current_mesh() is None
-    y = constrain(x, "batch", "model")
-    assert y is x                                 # literally untouched
-
-
-def test_constrain_applies_named_sharding_under_set_mesh():
-    n = min(2, len(jax.devices()))
-    mesh = make_debug_mesh(n, axes=("data", "model"),
-                           shape=(n, 1))
-    x = jnp.arange(4.0 * n * 3).reshape(2 * n, 6)
-
-    @jax.jit
-    def f(x):
-        return constrain(x, "batch", "model") * 2.0
-
-    with set_mesh(mesh):
-        out = f(x)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(x) * 2.0)
-    # the constraint actually shaped the output sharding: rows split
-    # over the data axis
-    assert out.sharding.is_equivalent_to(
-        NamedSharding(mesh, P(("data",), None)), out.ndim)
-
-
-def test_constrain_drops_non_divisible_axes():
-    n = min(2, len(jax.devices()))
-    mesh = make_debug_mesh(n, axes=("data", "model"), shape=(n, 1))
-    x = jnp.arange(float(3 * n + 1)).reshape(3 * n + 1, 1)  # indivisible
-    with set_mesh(mesh):
-        y = constrain(x, "batch", None)
-    np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
+from repro.sharding.rules import data_axis_devices, place_shards, set_mesh
 
 
 def test_data_axis_devices_orders_and_validates():
