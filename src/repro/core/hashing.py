@@ -9,11 +9,21 @@ This module implements the paper's three hashing schemes:
     p = 2^31 - 1, with the modulo replaced by the paper's §3.4 ``BitMod``
     shift/mask/conditional-subtract sequence.
 
-All arithmetic is 32-bit (TPU-native).  64-bit intermediates needed by the
-4U polynomial are emulated with 16-bit-limb long multiplication
-(``umul32_wide``) so the exact same code path runs inside Pallas TPU
-kernels, where 64-bit integers do not exist.  This is the TPU adaptation of
-the paper's "avoid modulo operations" GPU tricks.
+All arithmetic is 32-bit (TPU-native), the TPU adaptation of the paper's
+"avoid modulo operations" GPU tricks.  The reference 4U path
+(``hash4u_apply``) evaluates the polynomial by Horner's rule, each step a
+64-bit product emulated with 16-bit-limb long multiplication
+(``umul32_wide``) and reduced by ``BitMod`` (``mod_mersenne31``).
+
+The 4U kernel evaluates the same polynomial as ``a1 + a2 x + a3 x^2 +
+a4 x^3 (mod p)`` instead: x, x^2 mod p and x^3 mod p depend only on the
+id, so their 16-bit limbs are computed once per tile, and each hash
+function then costs three scalar-by-vector products.  A product is kept
+as exact 16-bit-aligned words (``mul31_words``), never as a (hi, lo)
+pair; the three products' words are summed word by word, and because
+``2^31 = 1`` and ``2^32 = 2 (mod p)`` the sums reduce below 2p with
+shifts and masks (``fold_words31``) and to [0, p) with one add-carry
+step (``mod_mersenne31_2p``); ``hash4u_limbs`` is the whole hash.
 """
 
 from __future__ import annotations
@@ -84,6 +94,72 @@ def mulmod_mersenne31(a: jax.Array, b: jax.Array) -> jax.Array:
     """``a * b mod (2^31 - 1)`` for a, b < 2^31, all in uint32."""
     hi, lo = umul32_wide(a, b)
     return mod_mersenne31(hi, lo)
+
+
+def mul31_words(al, ah, yl, yh):
+    """``a * y`` as exact words ``(w32, w16, w0)``: ``a * y = w32 * 2^32 +
+    w16 * 2^16 + w0`` with ``w16, w0 < 2^16`` and ``w32 < 2^30``.
+
+    ``a = ah * 2^16 + al`` and ``y = yh * 2^16 + yl`` are below 2^31
+    (16-bit low limbs, 15-bit high limbs); either side may be a scalar.
+    The middle sum ``ah yl + al yh + (al yl >> 16)`` is below 2^32 - 2^17.
+    """
+    low = al * yl
+    mid = ah * yl + al * yh + (low >> 16)
+    return ah * yh + (mid >> 16), mid & _U32(0xFFFF), low & _U32(0xFFFF)
+
+
+def fold_words31(w32, w16, w0):
+    """A value ``x < 2^31 + 2^30 + 2^17`` (below 2p) with ``x = w32 * 2^32 +
+    w16 * 2^16 + w0 (mod p)``, for any uint32 ``w32``, ``w16`` and
+    ``w0 < 2^30``.
+
+    ``2^32 = 2`` and ``2^31 = 1 (mod p)``: ``2 w32`` is its top two bits
+    plus ``(w32 << 1) & p``, and ``2^16 w16`` is ``w16 >> 15`` plus
+    ``(w16 << 16) & p``.  The two masked parts sum to at most
+    ``2^32 - 2^16 - 2`` and fold once below 2^31; the rest is small.
+    """
+    p = _U32(MERSENNE_P)
+    t = ((w32 << 1) & p) + ((w16 << 16) & p)
+    f = (t >> 31) + (t & p)                                  # < 2^31
+    return f + w0 + (w16 >> 15) + (w32 >> 30)
+
+
+def mod_mersenne31_2p(x, mask=MERSENNE_P):
+    """``(x mod p) & mask`` for ``x < 2p`` and a mask of low bits: adding
+    the carry of ``x + 1`` past bit 31 subtracts p exactly when
+    ``x >= p``, once bit 31 is masked off."""
+    return (x + ((x + _U32(1)) >> 31)) & _U32(mask)
+
+
+def limbs16(v):
+    """``(v & 0xFFFF, v >> 16)``, the limbs ``mul31_words`` takes."""
+    return v & _U32(0xFFFF), v >> 16
+
+
+def word16_mersenne31(a):
+    """A word ``c < 2^31`` with ``c * 2^16 = a (mod p)`` for ``a < 2^31``:
+    its halves swapped, since ``2^16 * 2^15 = 2^31 = 1``."""
+    return (a >> 16) | ((a & _U32(0xFFFF)) << 15)
+
+
+def hash4u_limbs(a1_word16, a_limbs, y_limbs, mask):
+    """``(a1 + a2 y2 + a3 y3 + a4 y4 mod p) & mask`` from limbs: the 4U
+    hash when ``y`` is x, x^2 mod p, x^3 mod p.
+
+    ``a_limbs`` holds the ``limbs16`` of a2, a3, a4 (< 2^31), ``y_limbs``
+    those of the three ``y`` (< 2^31), ``a1_word16`` is
+    ``word16_mersenne31(a1)``, and ``mask`` is ``2^s - 1`` for s <= 31.
+    The word sums stay in uint32: ``w32 < 3 * 2^30``, ``w16 < 2^31 +
+    3 * 2^16``, ``w0 < 3 * 2^16``.
+    """
+    w32 = w0 = None
+    w16 = a1_word16
+    for (al, ah), (yl, yh) in zip(a_limbs, y_limbs):
+        h, m, lo = mul31_words(al, ah, yl, yh)
+        w16 = w16 + m
+        w32, w0 = (h, lo) if w32 is None else (w32 + h, w0 + lo)
+    return mod_mersenne31_2p(fold_words31(w32, w16, w0), mask)
 
 
 # ---------------------------------------------------------------------------

@@ -18,10 +18,15 @@ Mapping of the paper's GPU design onto TPU v5e:
                                        "arbitrary" dim iterates nnz chunks)
   avoid % via 2^32 overflow (Eq. 10)   identical uint32 wraparound +
                                        multiply-shift
-  avoid % via BitMod, p = 2^31-1       identical shift/mask/cond-subtract,
-                                       with the 64-bit intermediate emulated
-                                       by 16-bit-limb long multiplication
-                                       (TPU has no 64-bit integer unit)
+  avoid % via BitMod, p = 2^31-1       4U kernel: the limbs of x, x^2 mod p
+                                       and x^3 mod p made once per tile;
+                                       per hash three scalar-limb x vector-
+                                       limb products kept as 16-bit-aligned
+                                       words, summed, folded with 2^31 = 1
+                                       and 2^32 = 2 (mod p), one add-carry
+                                       step to [0, p): no 64-bit product is
+                                       built (TPU has no 64-bit integer
+                                       unit; ``core.hashing.hash4u_limbs``)
 
 Layout.  The wrapper hands the kernel the indices transposed, (nnz, n):
 examples on the 128-lane axis, nonzeros on sublanes.  For hash j the
@@ -36,11 +41,13 @@ running min is kept in int32 after the order-preserving bias
 
 Padding is communicated via per-example nonzero counts: sublane t of
 example i is valid iff ``t < counts[i]``; invalid sublanes hash to
-0xFFFFFFFF so they never win the min.  If ``b > 0`` the lowest-b-bit
-extraction (the *b-bit* step) is fused into the final grid iteration; with
-``pack=True`` that step instead emits the bit-packed words
-(``repro.kernels.pack.pack_block``), so signatures leave the kernel in the
-paper's k*b-bit wire format.
+0xFFFFFFFF so they never win the min.  The 4U kernel's hashes are below
+2^31, so it keeps them unbiased, ORs a per-tile pad word (0x7FFFFFFF on
+invalid sublanes) into them, and hands out 0xFFFFFFFF where no slot was
+valid.  If ``b > 0`` the lowest-b-bit extraction (the *b-bit* step) is
+fused into the final grid iteration; with ``pack=True`` that step instead
+emits the bit-packed words (``repro.kernels.pack.pack_block``), so
+signatures leave the kernel in the paper's k*b-bit wire format.
 """
 
 from __future__ import annotations
@@ -53,13 +60,18 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.hashing import add64, hash2u_apply, mod_mersenne31, umul32_wide
+from repro.core.hashing import (add64, hash2u_apply, hash4u_limbs, limbs16,
+                                mod_mersenne31, mulmod_mersenne31,
+                                umul32_wide, word16_mersenne31)
 from repro.kernels.pack import pack_block, pack_row
 
 _U32 = jnp.uint32
 # numpy scalars (not traced jax arrays) so kernels don't capture constants
 _BIAS = np.uint32(0x80000000)
 _IMAX = np.int32(0x7FFFFFFF)          # biased 0xFFFFFFFF: never wins a min
+# rows of a tile the 4U body hashes at once: on a v5e the k=512 kernel took
+# 0.30 s a 40,960 x 1,280 chunk in 32-row strips, 0.41 s over whole tiles
+_STRIP = 32
 
 
 def _biased(h: jax.Array) -> jax.Array:
@@ -90,8 +102,9 @@ def _min_into(acc_ref, row, vals) -> None:
 
 
 # ---------------------------------------------------------------------------
-# 4U hash on a (BLK_T, BLK_N) tile with scalar coefficients (2U is
-# ``repro.core.hashing.hash2u_apply`` as is)
+# 4U hash of one function on a (BLK_T, BLK_N) tile by Horner's rule, with
+# scalar coefficients: the OPH kernel's (the k-pass 4U body below hoists
+# the per-id work; 2U is ``repro.core.hashing.hash2u_apply`` as is)
 # ---------------------------------------------------------------------------
 
 def _hash4u(t, a1, a2, a3, a4, *, s: int):
@@ -135,6 +148,54 @@ def _minhash_kernel(counts_ref, idx_ref, coef_ref, out_ref, acc_ref, *,
         out_ref[...] = pack_block(sig, b) if pack else sig
 
 
+def _minhash4u_kernel(counts_ref, idx_ref, coef_ref, out_ref, acc_ref,
+                      pad_ref, *limb_refs, s: int, b: int, blk_t: int,
+                      blk_k: int, pack: bool):
+    """The 4U k-pass body.  Once per grid step it writes the per-id work
+    to VMEM: the pad word and the 16-bit limbs of x, x^2 mod p and x^3
+    mod p.  Each hash j then reads them strip by strip (``hash4u_limbs``:
+    three products, no 64-bit pair) and keeps its hashes, all below
+    2^31, unbiased in ``acc_ref``."""
+    _init_acc(acc_ref)
+    j0 = pl.program_id(1) * blk_k
+    t0 = pl.program_id(2) * blk_t
+    limb_pairs = list(zip(limb_refs[::2], limb_refs[1::2]))
+    x = idx_ref[...].astype(_U32)
+    x2 = mulmod_mersenne31(x, x)
+    for y, (lo_ref, hi_ref) in zip((x, x2, mulmod_mersenne31(x2, x)),
+                                   limb_pairs):
+        lo_ref[...], hi_ref[...] = limbs16(y)
+    pad_ref[...] = jnp.where(_valid_rows(counts_ref, x.shape, t0), _U32(0),
+                             _U32(_IMAX))
+    mask = (1 << min(s, 31)) - 1
+    strip = _STRIP if blk_t % _STRIP == 0 else blk_t
+
+    def one_hash(j, carry):
+        a1w = word16_mersenne31(coef_ref[0, j0 + j])
+        a_limbs = [limbs16(coef_ref[c, j0 + j]) for c in (1, 2, 3)]
+        m = None
+        for r in range(0, blk_t, strip):
+            rows = pl.ds(r, strip)
+            y_limbs = [(lo[rows, :], hi[rows, :]) for lo, hi in limb_pairs]
+            h = hash4u_limbs(a1w, a_limbs, y_limbs, mask) | pad_ref[rows, :]
+            h = jax.lax.bitcast_convert_type(h, jnp.int32)
+            m = h if m is None else jnp.minimum(m, h)
+        row = pack_row(j, blk_k, b) if pack else j
+        _min_into(acc_ref, row, m)
+        return carry
+
+    jax.lax.fori_loop(0, blk_k, one_hash, 0)
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _emit():
+        acc = acc_ref[...]
+        sig = jnp.where(acc == _IMAX, _U32(0xFFFFFFFF),
+                        jax.lax.bitcast_convert_type(acc, _U32))
+        if b > 0:
+            sig = sig & _U32((1 << b) - 1)
+        out_ref[...] = pack_block(sig, b) if pack else sig
+
+
 # ---------------------------------------------------------------------------
 # pallas_call builders
 # ---------------------------------------------------------------------------
@@ -158,8 +219,10 @@ def _row_major_specs(n, nnz, k, blk_n, blk_t, blk_k):
     return grid, counts_spec, idx_spec, out_spec
 
 
-def _minhash_call(indices, counts, coef, hash_fn, *, b, blk_n, blk_t, blk_k,
-                  pack, interpret):
+def _minhash_call(indices, counts, coef, body, *, b, blk_n, blk_t, blk_k,
+                  pack, interpret, tiles: int = 0):
+    """``body``: a kernel body with its hash bound; ``tiles`` extra
+    (blk_t, blk_n) VMEM scratch tiles it takes after the accumulator."""
     n, nnz = indices.shape
     k = coef.shape[1]
     grid, counts_spec, idx_spec, out_spec = _row_major_specs(
@@ -172,8 +235,7 @@ def _minhash_call(indices, counts, coef, hash_fn, *, b, blk_n, blk_t, blk_k,
         out_rows = k * b // 32
         out_spec = pl.BlockSpec((blk_k * b // 32, blk_n),
                                 lambda i, j, t: (j, i))
-    kern = functools.partial(_minhash_kernel, hash_fn=hash_fn, b=b,
-                             blk_t=blk_t, blk_k=blk_k, pack=pack)
+    kern = functools.partial(body, b=b, blk_t=blk_t, blk_k=blk_k, pack=pack)
     out = pl.pallas_call(
         kern,
         grid=grid,
@@ -181,7 +243,8 @@ def _minhash_call(indices, counts, coef, hash_fn, *, b, blk_n, blk_t, blk_k,
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((out_rows, n), _U32),
-        scratch_shapes=[pltpu.VMEM((blk_k, blk_n), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((blk_k, blk_n), jnp.int32)]
+        + [pltpu.VMEM((blk_t, blk_n), _U32)] * tiles,
         interpret=interpret,
         **_compiler_params("parallel", "parallel", "arbitrary"),
     )(counts.reshape(1, n), indices.T, coef)
@@ -206,7 +269,8 @@ def minhash2u_pallas(indices: jax.Array, counts: jax.Array, a1: jax.Array,
       interpret: run the Pallas interpreter (CPU) instead of Mosaic.
     """
     hash_fn = functools.partial(hash2u_apply, s=s, variant=variant)
-    return _minhash_call(indices, counts, jnp.stack([a1, a2]), hash_fn, b=b,
+    body = functools.partial(_minhash_kernel, hash_fn=hash_fn)
+    return _minhash_call(indices, counts, jnp.stack([a1, a2]), body, b=b,
                          blk_n=blk_n, blk_t=blk_t, blk_k=blk_k, pack=pack,
                          interpret=interpret)
 
@@ -216,8 +280,9 @@ def minhash4u_pallas(indices: jax.Array, counts: jax.Array, a: jax.Array, *,
                      blk_k: int = 128, pack: bool = False,
                      interpret: bool):
     """4U minhash signatures with in-kernel Mersenne BitMod (§3.4);
-    a: (4, k) uint32.  Same layout and options as ``minhash2u_pallas``."""
-    hash_fn = functools.partial(_hash4u, s=s)
-    return _minhash_call(indices, counts, a, hash_fn, b=b, blk_n=blk_n,
+    a: (4, k) uint32 coefficients below 2^31, ids below 2^31, s <= 31.
+    Same layout and options as ``minhash2u_pallas``."""
+    body = functools.partial(_minhash4u_kernel, s=s)
+    return _minhash_call(indices, counts, a, body, b=b, blk_n=blk_n,
                          blk_t=blk_t, blk_k=blk_k, pack=pack,
-                         interpret=interpret)
+                         interpret=interpret, tiles=7)

@@ -12,9 +12,12 @@ import numpy as np
 import pytest
 
 from repro.core.hashing import (Hash2U, Hash4U, MERSENNE_P, add64,
-                                hash2u_apply, hash4u_apply, mod_mersenne31,
+                                fold_words31, hash2u_apply, hash4u_apply,
+                                hash4u_limbs, limbs16, mod_mersenne31,
+                                mod_mersenne31_2p, mul31_words,
                                 mulmod_mersenne31, umul32_wide,
-                                PermutationFamily, family_storage_bytes)
+                                word16_mersenne31, PermutationFamily,
+                                family_storage_bytes)
 
 # boundary values every sweep mixes in (what hypothesis would find)
 _EDGES_U32 = np.array([0, 1, 2, 0xFFFF, 0x10000, 0x7FFFFFFF, 0x80000000,
@@ -79,6 +82,79 @@ def test_4u_polynomial_vs_bigint(s):
             val = (int(A[0, j]) + int(A[1, j]) * ti + int(A[2, j]) * ti**2
                    + int(A[3, j]) * ti**3) % p % (2**s)
             assert out[i, j] == val
+
+
+# the 4U kernel's arithmetic, checked against Python integers: ids at the
+# limb and Mersenne edges, coefficients at theirs
+_P = 2**31 - 1
+_IDS_4U = np.array([0, 1, 2**15 - 1, 2**15, 2**16 - 1, 2**16, 2**30 - 1,
+                    2**30, _P - 1, _P], np.uint32)
+_COEFS_4U = np.array([0, 1, 2**16 - 1, _P - 1], np.uint32)
+
+
+def _ints(x):
+    return np.asarray(x).astype(object)
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_mul31_words_exact(seed):
+    rng = np.random.default_rng(300 + seed)
+    ids = _draw(rng, 300, 2**31, _IDS_4U)
+    coefs = _draw(rng, 40, _P, _COEFS_4U)
+    a, y = (v.ravel() for v in np.meshgrid(coefs, ids))
+    al, ah = limbs16(jnp.asarray(a))
+    w32, w16, w0 = map(_ints, mul31_words(al, ah, *limbs16(jnp.asarray(y))))
+    assert (w32 * 2**32 + w16 * 2**16 + w0 == _ints(a) * _ints(y)).all()
+    assert (w32 < 2**30).all() and (w16 < 2**16).all() and (w0 < 2**16).all()
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_fold_words31_bound_and_residue(seed):
+    rng = np.random.default_rng(400 + seed)
+    w32 = _draw(rng, 500, 2**32, _EDGES_U32)
+    w16 = _draw(rng, 500, 2**32, _EDGES_U32)
+    w0 = _draw(rng, 500, 2**30, np.array([0, 1, 2**30 - 1], np.uint32))
+    x = _ints(fold_words31(*map(jnp.asarray, (w32, w16, w0))))
+    assert (x < 2**31 + 2**30 + 2**17).all()
+    want = (_ints(w32) * 2**32 + _ints(w16) * 2**16 + _ints(w0)) % _P
+    assert (x % _P == want).all()
+
+
+@pytest.mark.parametrize("mask", [_P, 2**30 - 1, 0xFF])
+def test_mod_mersenne31_2p(mask):
+    x = np.array([0, 1, _P - 1, _P, _P + 1, 2**31, 2**31 + 2**30,
+                  2 * _P - 1], np.uint32)
+    got = _ints(mod_mersenne31_2p(jnp.asarray(x), mask))
+    assert (got == [v % _P & mask for v in _ints(x)]).all()
+
+
+@pytest.mark.parametrize("s", [30, 31])
+def test_hash4u_limbs_matches_bigint(s):
+    """The kernel's hash of x from the limbs of x, x^2 and x^3 mod p and
+    the coefficients' limbs: the unreduced sum stays below 2p and has
+    the polynomial's residue, and the hash is the polynomial mod p, mod
+    2^s, for every coefficient edge in every position."""
+    rng = np.random.default_rng(s)
+    x = _draw(rng, 200, 2**31, _IDS_4U)
+    xi = _ints(x)
+    powers = [xi, xi * xi % _P, xi * xi * xi % _P]
+    y_limbs = [limbs16(jnp.asarray(np.array(v, np.uint32))) for v in powers]
+    edges = [[c] * 4 for c in _COEFS_4U] + [
+        np.roll([c, 0, 1, _P - 1], r) for c in _COEFS_4U for r in range(4)]
+    rand = rng.integers(0, _P, (8, 4))
+    for a in [np.array(v, np.uint32) for v in [*edges, *rand]]:
+        a_limbs = [limbs16(jnp.uint32(c)) for c in a[1:]]
+        words = [mul31_words(al, ah, yl, yh)
+                 for (al, ah), (yl, yh) in zip(a_limbs, y_limbs)]
+        w32, w16, w0 = (sum(w[i] for w in words) for i in range(3))
+        w16 = w16 + word16_mersenne31(jnp.uint32(a[0]))
+        x_sum = _ints(fold_words31(w32, w16, w0))
+        poly = (int(a[0]) + sum(int(c) * y for c, y in zip(a[1:], powers)))
+        assert (x_sum < 2 * _P).all()
+        assert (x_sum % _P == poly % _P).all()
+        got = _ints(hash4u_limbs(word16_mersenne31(jnp.uint32(a[0])),
+                                 a_limbs, y_limbs, (1 << s) - 1))
+        assert (got == poly % _P % 2**s).all(), a
 
 
 @pytest.mark.parametrize("variant", ["high", "low"])

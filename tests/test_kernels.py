@@ -52,15 +52,90 @@ def test_minhash2u_fused_bbit(b):
     assert int(jnp.max(got)) < (1 << b)
 
 
-@pytest.mark.parametrize("n,nnz,k,s", [
-    (4, 100, 16, 16),
-    pytest.param(9, 257, 40, 24, marks=pytest.mark.slow),
-    (8, 128, 128, 30)])
-def test_minhash4u_kernel_matches_ref(n, nnz, k, s):
-    indices, counts = _case(n, nnz, k, s)
-    fam = Hash4U.create(jax.random.PRNGKey(k), k, s)
-    got = minhash4u(indices, counts, fam.a, s=s)
-    want = kref.minhash4u_ref(indices, counts.reshape(-1, 1), fam.a, s=s)
+_P31 = 2**31 - 1
+# ids at the 4U kernel's limb and Mersenne edges
+_EDGE_IDS = [0, 1, 2**15 - 1, 2**15, 2**16 - 1, 2**16, 2**30 - 1, 2**30,
+             _P31 - 1, _P31]
+
+
+def _edge_rows(n, nnz, s, seed):
+    """Random rows, and rows of the edge ids alone, of p - 1 alone, of
+    2^30 - 1 alone, of nothing, and a full one."""
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, 2**s, (n, nnz))
+    counts = rng.integers(1, nnz + 1, n)
+    idx[1, :len(_EDGE_IDS)] = _EDGE_IDS
+    idx[2, 0], idx[3, 0] = _P31 - 1, 2**30 - 1
+    counts[:5] = [nnz, len(_EDGE_IDS), 1, 1, 0]
+    return idx.astype(np.int32), counts.astype(np.int32)
+
+
+def _edge_family(k, s):
+    """Random coefficients, but for whole functions at 0, 1, 2^16 - 1
+    and p - 1."""
+    a = np.array(Hash4U.create(jax.random.PRNGKey(k), k, s).a)
+    a[:, :4] = [0, 1, 2**16 - 1, _P31 - 1]
+    return jnp.asarray(a)
+
+
+def _as_segments(idx, counts, width):
+    """Rows cut into ``width``-wide segments (at least one a row), padded
+    with empty segments to a whole 128-segment block."""
+    segs, cts, rows = [], [], []
+    for r, (row, c) in enumerate(zip(idx, counts)):
+        for start in range(0, max(int(c), 1), width):
+            take = row[start:min(int(c), start + width)]
+            segs.append(np.pad(take, (0, width - len(take))))
+            cts.append(len(take))
+            rows.append(r)
+    pad = -len(segs) % 128
+    segs += [np.zeros(width, np.int32)] * pad
+    return (np.stack(segs).astype(np.int32), np.array(cts + [0] * pad),
+            np.array(rows + [len(idx)] * pad))
+
+
+# "rows": the seeded padded rows of ``_case``; "edges": ``_edge_rows``
+# through the engine's runner, b-bit and packed; "segments": the same rows
+# as 128-wide segments, reduced to rows through the ``rows`` map
+_4U_EDGE_CASES = [
+    pytest.param(8, 300 if layout == "segments" else 128, 128, s, layout, b,
+                 packed, id=f"{layout}-s{s}-b{b}{'-packed' if packed else ''}")
+    for s in (30, 31)
+    for layout, b, packed in (("edges", 0, False), ("edges", 8, False),
+                              ("edges", 8, True), ("segments", 0, False),
+                              ("segments", 8, True))]
+
+
+@pytest.mark.parametrize("n,nnz,k,s,layout,b,packed", [
+    pytest.param(4, 100, 16, 16, "rows", 0, False, id="4-100-16-16"),
+    pytest.param(9, 257, 40, 24, "rows", 0, False, id="9-257-40-24",
+                 marks=pytest.mark.slow),
+    pytest.param(8, 128, 128, 30, "rows", 0, False, id="8-128-128-30"),
+    *_4U_EDGE_CASES])
+def test_minhash4u_kernel_matches_ref(n, nnz, k, s, layout, b, packed):
+    if layout == "rows":
+        indices, counts = _case(n, nnz, k, s)
+        fam = Hash4U.create(jax.random.PRNGKey(k), k, s)
+        got = minhash4u(indices, counts, fam.a, s=s)
+        want = kref.minhash4u_ref(indices, counts.reshape(-1, 1), fam.a, s=s)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        return
+    from repro.core.bbit import pack_codes
+    from repro.kernels.engine import MINHASH_BLOCKS, _minhash4u_run
+    idx, counts = _edge_rows(n, nnz, s, seed=s)
+    a = _edge_family(k, s)
+    want = kref.minhash4u_ref(jnp.asarray(idx), jnp.asarray(counts)[:, None],
+                              a, s=s, b=b)
+    if packed:
+        want = pack_codes(want, b)
+    run = dict(s=s, b=b, backend="interpret", packed=packed, **MINHASH_BLOCKS)
+    if layout == "edges":
+        got = _minhash4u_run(jnp.asarray(idx), jnp.asarray(counts), a, **run)
+    else:
+        seg, cts, rows = _as_segments(idx, counts, 128)
+        assert (cts == 0).any() and len(set(rows)) < len(rows)
+        got = _minhash4u_run(jnp.asarray(seg), jnp.asarray(cts), a,
+                             jnp.asarray(rows, jnp.int32), n=n, **run)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
